@@ -115,6 +115,8 @@ def main() -> None:
     from benchmarks import (bench_accuracy, bench_compression, bench_cost,
                             bench_dnn_accuracy, bench_dot, bench_elementwise,
                             bench_serve, roofline)
+    from repro.launch import compile_cache
+    compile_cache.enable()
     suites = {
         "accuracy": bench_accuracy.run,        # paper §VI table
         "dnn": bench_dnn_accuracy.run,         # paper Figs 5/6

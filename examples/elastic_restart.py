@@ -35,13 +35,14 @@ def main():
     ckpt = Checkpointer("/tmp/repro_elastic_ckpt", keep=1)
 
     # ---- phase 1: 2-device mesh (data x model = 2 x 1)
-    mesh2 = jax.make_mesh((2, 1), ("data", "model"))
+    mesh2 = jax.make_mesh((2, 1), ("data", "model"),
+                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
     params = fam.init_params(jax.random.PRNGKey(0), cfg)
     opt = adamw.init(params, opt_cfg)
     p_sh2 = sharding.param_shardings(params, mesh2)
     params = jax.device_put(params, p_sh2)
     losses = []
-    with sharding.set_mesh(mesh2):
+    with jax.set_mesh(mesh2):
         for i in range(20):
             params, opt, m = step_fn(params, opt, pipe.batch_at(i),
                                      jnp.asarray(i, jnp.int32))
@@ -51,7 +52,8 @@ def main():
           f"checkpoint at step 20")
 
     # ---- phase 2: 'a device died' -> resume on a 1-device mesh
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh1 = jax.make_mesh((1, 1), ("data", "model"),
+                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
     template = {"params": params, "opt": opt}
     sh1 = {
         "params": sharding.param_shardings(params, mesh1),
@@ -59,7 +61,7 @@ def main():
     }
     state, step0 = ckpt.restore(20, template, shardings=sh1)
     params1, opt1 = state["params"], state["opt"]
-    with sharding.set_mesh(mesh1):
+    with jax.set_mesh(mesh1):
         resumed = []
         for i in range(step0, step0 + 10):
             params1, opt1, m = step_fn(params1, opt1, pipe.batch_at(i),

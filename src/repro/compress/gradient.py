@@ -21,6 +21,8 @@ intermediate sums in wire format with one rounding per op instead of two.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -73,7 +75,7 @@ def scalar_pattern(value: float, cfg: PositConfig):
                        cfg.storage_dtype)
 
 
-def combine_compressed(qa, qb, name: str, interpret: bool = True):
+def combine_compressed(qa, qb, name: str, interpret: Optional[bool] = None):
     """Elementwise posit add of two wire-format gradient trees.
 
     Single rounding per element (fused decode->add->encode); the
@@ -85,7 +87,8 @@ def combine_compressed(qa, qb, name: str, interpret: bool = True):
         lambda a, b: kops.vadd(a, b, cfg, interpret=interpret), qa, qb)
 
 
-def scale_compressed(q, scale: float, name: str, interpret: bool = True):
+def scale_compressed(q, scale: float, name: str,
+                     interpret: Optional[bool] = None):
     """Scale a wire-format tree by a scalar, staying in the posit domain."""
     cfg = pcfg_of(name)
     s = scalar_pattern(scale, cfg)
@@ -93,7 +96,7 @@ def scale_compressed(q, scale: float, name: str, interpret: bool = True):
         lambda p: kops.vmul(p, s, cfg, interpret=interpret), q)
 
 
-def mean_compressed(q_tiled, name: str, interpret: bool = True):
+def mean_compressed(q_tiled, name: str, interpret: Optional[bool] = None):
     """Mean over the leading (pod) axis, entirely in wire format.
 
     Pairwise vadd tree-reduction then one exact divide by the pod count

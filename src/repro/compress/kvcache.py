@@ -18,6 +18,8 @@ block-table surgery (``paged_adopt_row`` / ``paged_release_rows``).
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -319,7 +321,8 @@ def adopt_row(cache, row_cache, row):
 # Posit-domain cache maintenance (fused elementwise kernels)
 # ---------------------------------------------------------------------------
 
-def scale_cache(cache, factor: float, name: str, interpret: bool = True):
+def scale_cache(cache, factor: float, name: str,
+                interpret: Optional[bool] = None):
     """Multiply every quantized leaf by ``factor`` in the posit domain.
 
     Pattern leaves are identified by the explicit ``CONTENT_LEAVES``
@@ -338,7 +341,7 @@ def scale_cache(cache, factor: float, name: str, interpret: bool = True):
 
 
 def merge_caches(cache_a, cache_b, name: str, weight_a: float = 0.5,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """Blend two quantized caches: ``wa * a + (1 - wa) * b``, fused.
 
     Three posit-domain ops (two vmul, one vadd) — each exactly rounded —

@@ -100,8 +100,8 @@ def vpadd(a: PIR, b: PIR, cfg: PositConfig):
     exp = jnp.where(a.is_zero, b.exp, jnp.where(b.is_zero, a.exp, exp))
     sig = jnp.where(a.is_zero, b.sig, jnp.where(b.is_zero, a.sig, sig))
     sticky = jnp.where(a.is_zero | b.is_zero, u32(0), sticky)
-    is_zero = jnp.where(a.is_zero, b.is_zero,
-                        jnp.where(b.is_zero, a.is_zero, out_zero))
+    # boolean select as logic: Mosaic cannot lower a select of i1 vectors
+    is_zero = (a.is_zero & b.is_zero) | (~a.is_zero & ~b.is_zero & out_zero)
     is_nar = a.is_nar | b.is_nar
     return PIR(sign, exp, sig, is_zero, is_nar), sticky
 

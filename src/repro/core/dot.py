@@ -88,13 +88,27 @@ def _sub1_128(limbs, dec):
     return list(reversed(out))
 
 
+def _column_sum(half, axis):
+    """Sum 16-bit half-limbs along ``axis`` as uint32.  Summed as int32:
+    a tile holds at most MAX_DOT_LENGTH halves, so the column sum stays
+    below 2^28 and the two agree bit for bit — and Mosaic implements no
+    reduction over unsigned integers."""
+    return jnp.sum(half.astype(jnp.int32), axis=axis).astype(jnp.uint32)
+
+
+def _any(flags, axis):
+    """``jnp.any`` along ``axis`` as an int32 max (no bool reductions in
+    Mosaic)."""
+    return jnp.max(flags.astype(jnp.int32), axis=axis) != 0
+
+
 def _sum128(limbs, axis):
     """Sum 128-bit two's-complement limb vectors along ``axis`` (mod 2^128)."""
     halves = []
     for x in reversed(limbs):            # LSB-first halves
         halves.append(x & u32(0xFFFF))
         halves.append(x >> u32(16))
-    sums = [jnp.sum(x, axis=axis, dtype=jnp.uint32) for x in halves]
+    sums = [_column_sum(x, axis) for x in halves]
     carry = u32(0)
     out16 = []
     for s in sums:
@@ -137,8 +151,8 @@ def _top_and_rest(limbs, lz):
         # by the exhaustive posit8 conformance sweep).
         w = (nbits - 32) - (off + lz)
         mask = sll(u32(1), w) - u32(1)
-        nz = jnp.where(w >= 32, x != 0,
-                       jnp.where(w > 0, (x & mask) != 0, False))
+        nz = ((w >= 32) & (x != 0)) | (
+            (w > 0) & (w < 32) & ((x & mask) != 0))
         rest_nonzero = rest_nonzero | nz
     return top, rest_nonzero
 
@@ -182,7 +196,8 @@ def _asr128_sticky(limbs, s):
     for j in range(_NLIMB):              # bits of lsb[j] strictly below s
         t = s - 32 * j
         mask = sll(u32(1), jnp.clip(t, 0, 31)) - u32(1)
-        below = jnp.where(t >= 32, lsb[j] != 0, (lsb[j] & mask) != 0)
+        below = ((t >= 32) & (lsb[j] != 0)) | (
+            (t < 32) & ((lsb[j] & mask) != 0))
         sticky = sticky | jnp.where(below, u32(1), u32(0))
     return list(reversed(out_lsb)), sticky
 
@@ -227,7 +242,7 @@ def quire_partial(a: PIR, b: PIR, axis: int = -1) -> QuireState:
     psign = a.sign ^ b.sign
     pexp = a.exp + b.exp
     pzero = a.is_zero | b.is_zero
-    any_nar = jnp.any(a.is_nar | b.is_nar, axis=axis)
+    any_nar = _any(a.is_nar | b.is_nar, axis)
 
     prod = u64.mul_32x32(a.sig, b.sig)                   # Q2.62
     prod = u64.select(pzero, u64.zeros_like(prod), prod)
@@ -237,7 +252,7 @@ def quire_partial(a: PIR, b: PIR, axis: int = -1) -> QuireState:
     d = jnp.clip(m_exp - pexp, 0, 95)
     limbs, st = _place_product(prod, d)
     st = jnp.where(pzero, u32(0), st)
-    sticky = jnp.max(st, axis=axis)
+    sticky = _any(st, axis).astype(jnp.uint32)
 
     neg = psign == 1
     nlimbs = _neg128(limbs)
@@ -353,7 +368,7 @@ def _sum_n(limbs, axis):
     for x in reversed(limbs):
         halves.append(x & u32(0xFFFF))
         halves.append(x >> u32(16))
-    sums = [jnp.sum(x, axis=axis, dtype=jnp.uint32) for x in halves]
+    sums = [_column_sum(x, axis) for x in halves]
     carry = u32(0)
     out16 = []
     for s in sums:
@@ -379,7 +394,7 @@ def _quire_exact_partial(a: PIR, b: PIR, axis: int):
     psign = a.sign ^ b.sign
     pexp = a.exp + b.exp
     pzero = a.is_zero | b.is_zero
-    any_nar = jnp.any(a.is_nar | b.is_nar, axis=axis)
+    any_nar = _any(a.is_nar | b.is_nar, axis)
 
     prod = u64.mul_32x32(a.sig, b.sig)
     prod = u64.select(pzero, u64.zeros_like(prod), prod)

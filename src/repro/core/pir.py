@@ -134,8 +134,9 @@ def encode(sign, exp, sig, sticky, is_zero, is_nar, cfg: PositConfig):
     p = body + inc
 
     maxpos = u32(cfg.maxpos_pattern)
-    p = jnp.minimum(p, maxpos)                 # never round past maxpos
-    p = jnp.maximum(p, u32(1))                 # never round a nonzero to 0
+    # compare + select: Mosaic has no unsigned min/max (arith.minui)
+    p = jnp.where(p > maxpos, maxpos, p)       # never round past maxpos
+    p = jnp.where(p < u32(1), u32(1), p)       # never round a nonzero to 0
     p = jnp.where(too_big, maxpos, p)
     p = jnp.where(too_small, u32(1), p)        # nonzero tiny -> minpos
 

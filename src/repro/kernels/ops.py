@@ -2,11 +2,13 @@
 
 These are what the rest of the framework calls: they accept arbitrary
 array ranks, pad to tile boundaries, dispatch to the kernel, and undo the
-padding.  ``interpret`` defaults to True because this container is
-CPU-only; on a real TPU runtime pass ``interpret=False`` (the launcher
-flag ``--pallas=native`` does this).
+padding.  ``interpret=None`` (the default) lets the backend decide
+(``_compat.resolve_interpret``): the Pallas interpreter on the CPU,
+native Mosaic kernels on a TPU; an explicit bool overrides it.
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import functools
 import math
@@ -44,7 +46,7 @@ def _pad_to(x, bm, bn):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
-def quantize(x, cfg: PositConfig, interpret: bool = True):
+def quantize(x, cfg: PositConfig, interpret: Optional[bool] = None):
     """f32 array (any rank) -> posit patterns, via the codec kernel."""
     x2, shape = _as_2d(jnp.asarray(x, jnp.float32))
     bm, bn = posit_codec.DEFAULT_BLOCK
@@ -57,7 +59,7 @@ def quantize(x, cfg: PositConfig, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
-def dequantize(p, cfg: PositConfig, interpret: bool = True):
+def dequantize(p, cfg: PositConfig, interpret: Optional[bool] = None):
     """posit patterns (any rank) -> f32 array, via the codec kernel."""
     p2, shape = _as_2d(jnp.asarray(p))
     bm, bn = posit_codec.DEFAULT_BLOCK
@@ -70,7 +72,7 @@ def dequantize(p, cfg: PositConfig, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
-def gemm(a, w_patterns, cfg: PositConfig, interpret: bool = True):
+def gemm(a, w_patterns, cfg: PositConfig, interpret: Optional[bool] = None):
     """f32 (..., K) @ posit (K, N) -> f32 (..., N)."""
     a2, shape = _as_2d(jnp.asarray(a, jnp.float32))
     k, n = w_patterns.shape
@@ -86,7 +88,8 @@ def gemm(a, w_patterns, cfg: PositConfig, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
-def dot(a_patterns, b_patterns, cfg: PositConfig, interpret: bool = True):
+def dot(a_patterns, b_patterns, cfg: PositConfig,
+        interpret: Optional[bool] = None):
     """Bit-exact PVU dot product over the trailing axis, any rank.
 
     Operands broadcast like jnp (a rank-1 vector against a batched
@@ -111,7 +114,7 @@ def dot(a_patterns, b_patterns, cfg: PositConfig, interpret: bool = True):
 
 
 def dot_rows(a_patterns, b_patterns, cfg: PositConfig,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     """Bit-exact PVU dot product per row: (..., L) -> (...,).
 
     Historic name for :func:`dot` (originally (R, L)-only); now fully
@@ -122,7 +125,7 @@ def dot_rows(a_patterns, b_patterns, cfg: PositConfig,
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
 def pgemm(a_patterns, w_patterns, cfg: PositConfig,
-          interpret: bool = True):
+          interpret: Optional[bool] = None):
     """Bit-exact posit matmul: posit (..., K) @ posit (K, N) -> posit
     (..., N), one quire rounding per output element.
 
@@ -152,7 +155,7 @@ def pgemm(a_patterns, w_patterns, cfg: PositConfig,
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "op", "div_mode", "interpret"))
 def _elementwise(a, b, cfg: PositConfig, op: str, div_mode: str = "nr3",
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """Shared pad-to-block wrapper: broadcast, flatten to 2D, dispatch."""
     a = jnp.asarray(a)
     b = jnp.asarray(b)
@@ -171,23 +174,23 @@ def _elementwise(a, b, cfg: PositConfig, op: str, div_mode: str = "nr3",
     return out[:m, :n].reshape(shape)
 
 
-def vadd(a, b, cfg: PositConfig, interpret: bool = True):
+def vadd(a, b, cfg: PositConfig, interpret: Optional[bool] = None):
     """Fused posit add: patterns (any rank, broadcastable) -> patterns."""
     return _elementwise(a, b, cfg, "add", interpret=interpret)
 
 
-def vsub(a, b, cfg: PositConfig, interpret: bool = True):
+def vsub(a, b, cfg: PositConfig, interpret: Optional[bool] = None):
     """Fused posit subtract on patterns."""
     return _elementwise(a, b, cfg, "sub", interpret=interpret)
 
 
-def vmul(a, b, cfg: PositConfig, interpret: bool = True):
+def vmul(a, b, cfg: PositConfig, interpret: Optional[bool] = None):
     """Fused posit multiply on patterns."""
     return _elementwise(a, b, cfg, "mul", interpret=interpret)
 
 
 def vdiv(a, b, cfg: PositConfig, mode: str = "nr3",
-         interpret: bool = True):
+         interpret: Optional[bool] = None):
     """Fused posit divide on patterns.
 
     mode='nr3' is the paper-faithful Newton-Raphson divider;

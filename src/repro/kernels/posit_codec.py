@@ -7,7 +7,7 @@ on the VPU (8x128 lanes), which is exactly the "vector posit unit"
 adaptation of the paper (DESIGN.md §2).
 
 Target: TPU (compiled via pl.pallas_call with explicit BlockSpecs).
-Validation: interpret=True on CPU against ``ref.py`` / the golden model.
+Validation: interpret mode on the CPU against ``ref.py`` / the golden model.
 """
 from __future__ import annotations
 
@@ -19,6 +19,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.convert import f32_to_posit, posit_to_f32
 from repro.core.types import PositConfig
+
+from ._compat import resolve_interpret
 
 # VPU-aligned default tile: 8 sublanes x 128 lanes times a few registers.
 DEFAULT_BLOCK = (256, 512)
@@ -40,7 +42,7 @@ def _grid(shape, block):
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "block", "interpret"))
-def quantize_2d(x, cfg: PositConfig, block=DEFAULT_BLOCK, interpret=True):
+def quantize_2d(x, cfg: PositConfig, block=DEFAULT_BLOCK, interpret=None):
     """f32 (M, N) -> posit patterns (M, N) in cfg.storage_dtype."""
     grid, (bm, bn) = _grid(x.shape, block)
     return pl.pallas_call(
@@ -49,13 +51,13 @@ def quantize_2d(x, cfg: PositConfig, block=DEFAULT_BLOCK, interpret=True):
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(x.shape, cfg.storage_dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "block", "interpret"))
-def dequantize_2d(p, cfg: PositConfig, block=DEFAULT_BLOCK, interpret=True):
+def dequantize_2d(p, cfg: PositConfig, block=DEFAULT_BLOCK, interpret=None):
     """posit patterns (M, N) -> f32 (M, N)."""
     grid, (bm, bn) = _grid(p.shape, block)
     return pl.pallas_call(
@@ -64,5 +66,5 @@ def dequantize_2d(p, cfg: PositConfig, block=DEFAULT_BLOCK, interpret=True):
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(p.shape, jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(p)

@@ -28,9 +28,11 @@ from repro.core import dot as dot_mod
 from repro.core.pir import decode, encode_pir
 from repro.core.types import PositConfig
 
-from ._compat import CompilerParams as _CompilerParams
+from ._compat import resolve_interpret
 
-DEFAULT_ROWS = 128
+# rows x MAX_DOT_LENGTH keeps the quire working set (about 20 u32 planes
+# per element) inside the TPU's default 16 MiB of scoped VMEM
+DEFAULT_ROWS = 32
 DEFAULT_BLOCK_K = dot_mod.MAX_DOT_LENGTH
 
 
@@ -48,12 +50,23 @@ def _write_state(st, acc_ref, mexp_ref, sticky_ref, nar_ref):
     nar_ref[...] = st.nar.astype(jnp.uint32)[:, None]
 
 
-def _vpdot_kernel(a_ref, b_ref, o_ref, acc_ref, mexp_ref, sticky_ref,
-                  nar_ref, *, cfg: PositConfig, nk: int):
-    k = pl.program_id(1)
-    a = decode(a_ref[...].astype(jnp.uint32), cfg)
-    b = decode(b_ref[...].astype(jnp.uint32), cfg)
-    tile = dot_mod.quire_partial(a, b, axis=-1)
+def quire_scratch(rows: int):
+    """VMEM scratch carrying ``rows`` streamed quire states across K."""
+    return [
+        pltpu.VMEM((rows, dot_mod._NLIMB), jnp.uint32),   # quire limbs
+        pltpu.VMEM((rows, 1), jnp.int32),                 # m_exp
+        pltpu.VMEM((rows, 1), jnp.uint32),                # sticky
+        pltpu.VMEM((rows, 1), jnp.uint32),                # NaR flag
+    ]
+
+
+def vpdot_tile(a, b, o_ref, acc_ref, mexp_ref, sticky_ref, nar_ref, *,
+               cfg: PositConfig, k, nk: int):
+    """One K step of row-wise quire dot products, shared by ``vpdot_rows``
+    and ``posit_qgemm``: ``a``/``b`` (rows, bk) uint32 patterns; ``k`` is
+    the step's position on the sequential K grid axis; the last step
+    rounds once into ``o_ref`` (rows, 1)."""
+    tile = dot_mod.quire_partial(decode(a, cfg), decode(b, cfg), axis=-1)
 
     @pl.when(k == 0)
     def _init():
@@ -73,12 +86,17 @@ def _vpdot_kernel(a_ref, b_ref, o_ref, acc_ref, mexp_ref, sticky_ref,
         o_ref[...] = out[:, None]
 
 
+def _vpdot_kernel(a_ref, b_ref, o_ref, *scratch, cfg: PositConfig, nk: int):
+    vpdot_tile(a_ref[...].astype(jnp.uint32), b_ref[...].astype(jnp.uint32),
+               o_ref, *scratch, cfg=cfg, k=pl.program_id(1), nk=nk)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "block_rows", "block_k",
                                     "interpret"))
 def vpdot_rows(a_patterns, b_patterns, cfg: PositConfig,
                block_rows: int = DEFAULT_ROWS, block_k: int | None = None,
-               interpret=True):
+               interpret=None):
     """Row-wise posit dot product: (R, L) x (R, L) -> (R,) patterns.
 
     L is unbounded: the reduction runs as a sequential K grid dimension
@@ -115,14 +133,9 @@ def vpdot_rows(a_patterns, b_patterns, cfg: PositConfig,
         ],
         out_specs=pl.BlockSpec((bm, 1), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, 1), cfg.storage_dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bm, dot_mod._NLIMB), jnp.uint32),   # quire limbs
-            pltpu.VMEM((bm, 1), jnp.int32),                 # m_exp
-            pltpu.VMEM((bm, 1), jnp.uint32),                # sticky
-            pltpu.VMEM((bm, 1), jnp.uint32),                # NaR flag
-        ],
-        compiler_params=_CompilerParams(
+        scratch_shapes=quire_scratch(bm),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_patterns, b_patterns)
     return out[:, 0]

@@ -19,7 +19,7 @@ supports both the paper's 3-iteration Newton-Raphson (``mode='nr3'``,
 divider (``mode='exact'``).
 
 Target: TPU via pl.pallas_call (VPU elementwise, 8x128 lanes);
-``interpret=True`` validates on CPU against ``core.softposit_ref``.
+interpret mode (the CPU backend's) validates against ``core.softposit_ref``.
 """
 from __future__ import annotations
 
@@ -32,6 +32,8 @@ from jax.experimental import pallas as pl
 from repro.core import arith
 from repro.core.pir import decode, encode_pir
 from repro.core.types import PositConfig
+
+from ._compat import resolve_interpret
 
 # VPU-aligned default tile, matching the codec kernel: the PIR working set
 # is ~6 u32 planes per operand, so (256, 512) stays well under VMEM.
@@ -68,7 +70,7 @@ def _grid(shape, block):
                    static_argnames=("cfg", "op", "div_mode", "block",
                                     "interpret"))
 def elementwise_2d(a, b, cfg: PositConfig, op: str, div_mode: str = "nr3",
-                   block=DEFAULT_BLOCK, interpret=True):
+                   block=DEFAULT_BLOCK, interpret=None):
     """Fused posit elementwise op on (M, N) pattern arrays.
 
     a, b : posit patterns in ``cfg.storage_dtype``; same shape.
@@ -87,5 +89,5 @@ def elementwise_2d(a, b, cfg: PositConfig, op: str, div_mode: str = "nr3",
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(a.shape, cfg.storage_dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)

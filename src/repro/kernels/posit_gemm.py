@@ -17,11 +17,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.convert import posit_to_f32
 from repro.core.types import PositConfig
 
-from ._compat import CompilerParams as _CompilerParams
+from ._compat import resolve_interpret
 
 DEFAULT_BLOCKS = (256, 256, 256)  # bm, bk, bn
 
@@ -41,7 +42,7 @@ def _gemm_kernel(a_ref, w_ref, o_ref, *, cfg: PositConfig):
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "blocks", "interpret"))
 def posit_gemm(a, w_patterns, cfg: PositConfig, blocks=DEFAULT_BLOCKS,
-               interpret=True):
+               interpret=None):
     """a: f32 (M, K); w_patterns: posit (K, N) -> f32 (M, N)."""
     m, k = a.shape
     k2, n = w_patterns.shape
@@ -59,7 +60,7 @@ def posit_gemm(a, w_patterns, cfg: PositConfig, blocks=DEFAULT_BLOCKS,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, w_patterns)

@@ -33,9 +33,12 @@ Grid: ``(B, W)`` with the table-walk dimension sequential
 legal; block ``tables[b, w]`` of the arena is DMA'd per step via a
 scalar-prefetch BlockSpec index map — no gather copy ever exists.
 
-Target: TPU (compiled); validation: interpret=True on CPU (the
-container default), bit-for-bit against ``posit_codec.py``'s decode
-because both call the same ``core.convert.posit_to_f32``.
+Target: TPU (compiled natively there); validation: the Pallas
+interpreter on the CPU backend (``_compat.resolve_interpret``),
+bit-for-bit against ``posit_codec.py``'s decode because both call the
+same ``core.convert.posit_to_f32``.  ``apos`` enters as ``(B, W, 1, bs)``
+so each step's ``(1, bs)`` block satisfies the TPU tiling rule (the last
+two block dims equal the array's).
 """
 from __future__ import annotations
 
@@ -50,7 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.convert import posit_to_f32
 from repro.core.types import PositConfig
 
-from ._compat import CompilerParams as _CompilerParams
+from ._compat import resolve_interpret
 
 _NEG = -1e30
 
@@ -115,7 +118,7 @@ def _paged_attn_kernel(tables_ref, lens_ref, q_ref, apos_ref, k_ref, v_ref,
     s = jnp.einsum("grd,tgd->grt", q, k,
                    preferred_element_type=jnp.float32)
     valid = _slot_valid(tables_ref, lens_ref, apos_ref[0, 0],
-                        nb=nb, window=window)[None, None, :]
+                        nb=nb, window=window)[None]         # (1, 1, bs)
     _online_update(s, valid, v, m_ref, l_ref, acc_ref, "grt,tgv->grv")
 
     @pl.when(w == nw - 1)
@@ -149,7 +152,7 @@ def _paged_attn_mla_kernel(tables_ref, lens_ref, qc_ref, qr_ref, apos_ref,
          jnp.einsum("ghd,td->ght", qr_ref[...], r,
                     preferred_element_type=jnp.float32)) * scale
     valid = _slot_valid(tables_ref, lens_ref, apos_ref[0, 0],
-                        nb=nb, window=0)[None, None, :]
+                        nb=nb, window=0)[None]              # (1, 1, bs)
     _online_update(s, valid, c, m_ref, l_ref, acc_ref, "ght,tr->ghr")
 
     @pl.when(w == nw - 1)
@@ -185,7 +188,7 @@ def _block_index(nb):
                    static_argnames=("pcfg", "window", "interpret"))
 def paged_decode_attention(q, k_arena, v_arena, tables, apos, lens, *,
                            pcfg: Optional[PositConfig] = None,
-                           window: int = 0, interpret: bool = True):
+                           window: int = 0, interpret: Optional[bool] = None):
     """Fused paged decode attention (dense/GQA and sliding-window lanes).
 
     q: (B, G, R, D) f32, already scaled by ``D**-0.5``; arenas
@@ -203,7 +206,7 @@ def paged_decode_attention(q, k_arena, v_arena, tables, apos, lens, *,
         tables, apos,
         [
             pl.BlockSpec((1, g, r, d), lambda b, w, tab, ln: (b, 0, 0, 0)),
-            pl.BlockSpec((1, 1, bs), lambda b, w, tab, ln: (b, w, 0)),
+            pl.BlockSpec((1, 1, 1, bs), lambda b, w, tab, ln: (b, w, 0, 0)),
             pl.BlockSpec((1, bs, g, d), functools.partial(kidx, _nd=4)),
             pl.BlockSpec((1, bs, g, dv), functools.partial(kidx, _nd=4)),
         ],
@@ -218,11 +221,11 @@ def paged_decode_attention(q, k_arena, v_arena, tables, apos, lens, *,
                           window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, g, r, dv), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(tables, lens,
-      q.astype(jnp.float32), apos.reshape(b, w, bs), k_arena, v_arena)
+      q.astype(jnp.float32), apos.reshape(b, w, 1, bs), k_arena, v_arena)
 
 
 @functools.partial(jax.jit,
@@ -230,7 +233,8 @@ def paged_decode_attention(q, k_arena, v_arena, tables, apos, lens, *,
 def paged_decode_attention_mla(q_lat_eff, q_rope, c_arena, r_arena, tables,
                                apos, lens, *,
                                pcfg: Optional[PositConfig] = None,
-                               scale: float = 1.0, interpret: bool = True):
+                               scale: float = 1.0,
+                               interpret: Optional[bool] = None):
     """Fused paged MLA decode: latent-space scores and context straight
     off the block tables.
 
@@ -249,7 +253,7 @@ def paged_decode_attention_mla(q_lat_eff, q_rope, c_arena, r_arena, tables,
         [
             pl.BlockSpec((1, h, rank), lambda b, w, tab, ln: (b, 0, 0)),
             pl.BlockSpec((1, h, rope), lambda b, w, tab, ln: (b, 0, 0)),
-            pl.BlockSpec((1, 1, bs), lambda b, w, tab, ln: (b, w, 0)),
+            pl.BlockSpec((1, 1, 1, bs), lambda b, w, tab, ln: (b, w, 0, 0)),
             pl.BlockSpec((1, bs, rank), functools.partial(kidx, _nd=3)),
             pl.BlockSpec((1, bs, rope), functools.partial(kidx, _nd=3)),
         ],
@@ -264,12 +268,12 @@ def paged_decode_attention_mla(q_lat_eff, q_rope, c_arena, r_arena, tables,
                           scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, rank), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(tables, lens,
       q_lat_eff.astype(jnp.float32), q_rope.astype(jnp.float32),
-      apos.reshape(b, w, bs), c_arena, r_arena)
+      apos.reshape(b, w, 1, bs), c_arena, r_arena)
 
 
 # ---------------------------------------------------------------------------

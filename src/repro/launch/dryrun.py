@@ -156,7 +156,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str) -> dict:
                          out_shardings=(logits_sh, c_sh))
         args = (p_shape, cache_shape, tok_sds)
 
-    with sharding.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         record["lower_s"] = round(time.time() - t0, 2)
         t1 = time.time()

@@ -10,13 +10,15 @@ from __future__ import annotations
 import warnings
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; the multi-pod mesh is 2 pods = 512."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1):
@@ -35,4 +37,5 @@ def make_host_mesh(model_parallel: int = 1):
             f"model_parallel={model_parallel} does not factor the "
             f"{n}-device host platform; rounding down to "
             f"model_parallel={mp}", stacklevel=2)
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return jax.make_mesh((n // mp, mp), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -83,7 +83,9 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.compress.kvcache import cache_report
+from repro.launch import compile_cache
 from repro.models import get_family
+from repro.models.layers import cdtype
 from repro.runtime.engine import Engine
 from repro.runtime.scheduler import Scheduler
 
@@ -93,15 +95,20 @@ from repro.runtime.scheduler import Scheduler
 MS_PER_STEP = 10.0
 
 
-def poisson_trace(rng, n_requests, rate, vocab, prompt_len, gen):
+def poisson_trace(rng, n_requests, rate, vocab, prompt_len, gen, *,
+                  min_prompt_len=0, min_gen=0):
     """Ragged request trace: Poisson arrivals (``rate`` expected requests
-    per decode step), uniform prompt/generation lengths."""
+    per decode step), uniform prompt lengths in ``[min_prompt_len,
+    prompt_len]`` and generation lengths in ``[min_gen, gen]`` (a zero
+    minimum means half the prompt length and a quarter of ``gen``)."""
     arrivals = np.cumsum(rng.exponential(1.0 / max(rate, 1e-9),
                                          size=n_requests))
+    plo = max(2, min_prompt_len or prompt_len // 2)
+    glo = max(2, min_gen or gen // 4)
     out = []
     for t in arrivals:
-        plen = int(rng.integers(max(2, prompt_len // 2), prompt_len + 1))
-        g = int(rng.integers(max(2, gen // 4), gen + 1))
+        plen = int(rng.integers(plo, prompt_len + 1))
+        g = int(rng.integers(glo, gen + 1))
         out.append((float(t), rng.integers(1, vocab, plen).tolist(), g))
     return out
 
@@ -189,7 +196,9 @@ def run_continuous(args, cfg, params):
                                     share=args.prefix_share)
     else:
         trace = poisson_trace(rng, args.n_requests, args.arrival_rate,
-                              cfg.vocab, args.prompt_len, args.gen)
+                              cfg.vocab, args.prompt_len, args.gen,
+                              min_prompt_len=args.min_prompt_len,
+                              min_gen=args.min_gen)
     t0 = time.time()
     done, _ = drive_trace(sched, trace, deadline_steps=deadline_steps)
     dt = time.time() - t0
@@ -241,7 +250,24 @@ def run_continuous(args, cfg, params):
               f"{sched.n_evicted} evictions; peak committed "
               f"physical {sched.peak_committed} vs logical "
               f"{sched.peak_logical} blocks")
-    return done
+    return done, sched
+
+
+def init_serving_params(cfg, seed: int):
+    """Random weights from ``seed``, made on the device directly in
+    ``cfg.compute_dtype``: the jitted init casts each leaf as it is
+    made, so a float32 copy of the whole model never exists (a
+    published-width bf16 model may fit a chip whose memory its f32 copy
+    would overflow)."""
+    fam = get_family(cfg)
+    dt = cdtype(cfg)
+
+    def build(key):
+        return jax.tree.map(
+            lambda x: x.astype(dt) if jnp.issubdtype(x.dtype, jnp.floating)
+            else x, fam.init_params(key, cfg))
+
+    return jax.jit(build)(jax.random.PRNGKey(seed))
 
 
 def main(argv=None):
@@ -249,12 +275,22 @@ def main(argv=None):
     ap.add_argument("--arch", choices=configs.ARCH_IDS,
                     default="phi3-medium-14b")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="serve only the first N layers at the "
+                         "configuration's published widths (a depth cut "
+                         "to fit the devices; 0 = all layers)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--ragged", action="store_true",
                     help="vary prompt lengths across the batch "
                          "(transformer family only)")
     ap.add_argument("--gen", type=int, default=16)
+    ap.add_argument("--min-prompt-len", type=int, default=0,
+                    help="with --continuous: shortest prompt of the trace "
+                         "(0 = half of --prompt-len)")
+    ap.add_argument("--min-gen", type=int, default=0,
+                    help="with --continuous: fewest new tokens of a "
+                         "request (0 = a quarter of --gen)")
     ap.add_argument("--max-len", type=int, default=0,
                     help="preallocated cache length (default: "
                          "prompt-len + gen for one-shot, plus "
@@ -263,7 +299,8 @@ def main(argv=None):
                     default="none")
     ap.add_argument("--temperature", type=float, default=0.0,
                     help="0 = greedy; > 0 = softmax sampling")
-    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds the random weights and the trace")
     ap.add_argument("--continuous", action="store_true",
                     help="continuous batching: requests arrive on a "
                          "simulated Poisson trace and join/leave between "
@@ -340,12 +377,14 @@ def main(argv=None):
     cfg = configs.get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced(compute_dtype="float32")
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     if args.kv_posit != "none":
         cfg = dataclasses.replace(cfg, kv_posit=args.kv_posit)
 
-    fam = get_family(cfg)
+    compile_cache.enable()
     rng = np.random.default_rng(args.seed)
-    params = fam.init_params(jax.random.PRNGKey(0), cfg)
+    params = init_serving_params(cfg, args.seed)
 
     if args.continuous:
         return run_continuous(args, cfg, params)

@@ -54,7 +54,8 @@ def dense(p, x, cfg: ModelConfig):
     return y
 
 
-def dense_posit_exact(p, x, cfg: ModelConfig, interpret: bool = True):
+def dense_posit_exact(p, x, cfg: ModelConfig,
+                      interpret: Optional[bool] = None):
     """Bit-exact posit linear for numerics audits (cfg.posit_exact_linear).
 
     Runs the paper's §IV-E datapath end to end in the posit domain:
@@ -589,7 +590,7 @@ def paged_apos(tables, lens, block_size: int, n_blocks: int, *,
 def decode_attention_paged(q, k_arena, v_arena, tables, lens, *,
                            cfg: ModelConfig, kv_posit: Optional[str] = None,
                            window: int = 0, kernel: str = "gather",
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """Paged decode attention straight off the block tables.
 
     q: (B, 1, H, D); arenas (n_blocks, bs, G, D[v]) posit patterns or
@@ -627,7 +628,7 @@ def decode_attention_paged_mla(q_lat_eff, q_rope, c_arena, r_arena, tables,
                                lens, *, cfg: ModelConfig,
                                kv_posit: Optional[str] = None,
                                kernel: str = "gather",
-                               interpret: bool = True):
+                               interpret: Optional[bool] = None):
     """Absorbed-matrix MLA paged decode: latent-space attention off the
     block tables; returns the latent context (B, H, rank) f32 (the
     caller applies ``wuv``).

@@ -170,7 +170,7 @@ class Engine:
         model code resolve (they no-op without a mesh)."""
         if self.mesh is None:
             return fn(*args)
-        with shd.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return fn(*args)
 
     @property

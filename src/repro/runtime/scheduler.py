@@ -156,6 +156,9 @@ class Completion:
     arrival_step: int              # when the request was submitted
     admitted_step: int             # decode-step clock at admission
     finished_step: int             # decode-step clock when retired
+    # chunked-prefill mode: the (V,) f32 logits the first token was
+    # sampled from, what a one-shot prefill of the prompt is compared to
+    first_logits: Optional[np.ndarray] = None
 
     @property
     def latency_steps(self) -> int:
@@ -175,6 +178,7 @@ class _Slot:
     # chunked-prefill cursor: prompt positions already cached, or None
     # once the whole prompt is in (always None in unchunked mode)
     cursor: Optional[int] = None
+    first_logits: Optional[np.ndarray] = None
 
     @property
     def lens(self) -> int:
@@ -905,7 +909,8 @@ class Scheduler:
                 tokens=np.asarray(slot.emitted, np.int32),
                 arrival_step=req.arrival_step,
                 admitted_step=slot.admitted_step,
-                finished_step=self.steps_run))
+                finished_step=self.steps_run,
+                first_logits=slot.first_logits))
             self._slots[i] = None
             self.n_retired += 1
             if self.paged:
@@ -1012,6 +1017,7 @@ class Scheduler:
                         jnp.asarray(chunk_logits[i:i + 1]),
                         self.engine._key, self.engine.temperature)
                     tok0 = int(np.asarray(tok0)[0])
+                    s.first_logits = chunk_logits[i].copy()
                     s.emitted.append(tok0)
                     self._cur_tok[i] = tok0
                     if tok0 == req.eos_id or req.max_new_tokens == 1:

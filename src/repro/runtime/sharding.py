@@ -16,15 +16,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.models.config import ModelConfig
 
 
-def set_mesh(mesh: Mesh):
-    """Context manager activating ``mesh``, across jax versions.
-
-    jax >= 0.5 has ``jax.set_mesh``; on 0.4.x the Mesh object itself is
-    the context manager.
-    """
-    setter = getattr(jax, "set_mesh", None)
-    return setter(mesh) if setter is not None else mesh
-
 # first match wins; paths look like "layers/attn/wq/w" or "tok_embed"
 _TRANSFORMER_RULES = [
     (r"tok_embed$", P("model", None)),

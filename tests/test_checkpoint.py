@@ -76,7 +76,8 @@ def test_elastic_remesh_restore(tmp_path):
     t = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     ck.save(2, t, blocking=True)
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = jax.make_mesh((n,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = {"w": NamedSharding(mesh, P(None, None))}
     restored, _ = ck.restore(2, t, shardings=sh)
     np.testing.assert_array_equal(np.asarray(restored["w"]),

@@ -46,13 +46,14 @@ step_fn = train_loop.make_train_step(cfg, opt_cfg)
 p1, o1, m1 = jax.jit(step_fn)(params, opt, batch, jnp.asarray(0))
 
 # sharded 4x2 mesh
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 p_sh = sharding.param_shardings(params, mesh)
 b_sh = sharding.to_shardings(sharding.batch_specs(batch, mesh, cfg), mesh)
 params_s = jax.device_put(params, p_sh)
 opt_s = jax.device_put(opt, sharding.param_shardings(opt, mesh))
 batch_s = jax.device_put(batch, b_sh)
-with sharding.set_mesh(mesh):
+with jax.set_mesh(mesh):
     p2, o2, m2 = jax.jit(step_fn)(params_s, opt_s, batch_s,
                                   jnp.asarray(0))
 
@@ -84,7 +85,8 @@ cfg = dataclasses.replace(cfg, fsdp=False, seq_shard_activations=False,
                           grad_compress="posit16", n_visual_tokens=0)
 fam = get_family(cfg)
 opt_cfg = adamw.AdamWConfig(lr=1e-3)
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
 params = fam.init_params(jax.random.PRNGKey(0), cfg)
 opt = adamw.init(params, opt_cfg)
@@ -95,7 +97,7 @@ tiled = jax.tree.map(lambda x: x.reshape((2, 4) + x.shape[1:]), batch)
 
 step_fn = train_loop.make_train_step(cfg, opt_cfg, n_pods=2,
                                      compressed=True)
-with sharding.set_mesh(mesh):
+with jax.set_mesh(mesh):
     jitted = jax.jit(step_fn)
     lowered = jitted.lower(params, opt, ef, tiled, jnp.asarray(0))
     compiled = lowered.compile()

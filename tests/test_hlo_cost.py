@@ -29,7 +29,8 @@ results["scan_flops"] = r["flops"]
 results["scan_expected"] = 2 * 64 * 64 * 64 * 17
 
 # 2. sharded: per-chip flops ~ global/8; collectives trip-multiplied
-mesh = jax.make_mesh((8,), ("model",))
+mesh = jax.make_mesh((8,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 ws = NamedSharding(mesh, P(None, "model"))
 comp2 = jax.jit(f, in_shardings=(ws, ws)).lower(
     jax.ShapeDtypeStruct((64, 64), jnp.float32),

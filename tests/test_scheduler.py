@@ -101,21 +101,33 @@ def test_token_identity_under_forced_compaction():
 
 
 def test_eos_stops_early_and_frees_the_slot():
-    """Submitting with eos_id = the request's own 3rd greedy token must
-    truncate the stream there and retire the slot for the next request."""
+    """Submitting with eos_id = a token of the request's own greedy
+    stream must truncate the stream at that token's FIRST occurrence and
+    retire the slot for the next request.  The EOS is a token that first
+    appears at index >= 1, so the stream really stops early (a random-
+    weight stream may repeat one token from the start; such a prompt has
+    no usable EOS and the next one is drawn)."""
     cfg = _cfg("dense")
     params = _params(cfg)
     rng = np.random.default_rng(5)
-    prompt = rng.integers(1, cfg.vocab, 6).tolist()
-    ref = Engine(cfg, params, max_len=32, seed=0).generate([prompt], 8)
-    eos = int(ref.tokens[0][2])
+    ref_eng = Engine(cfg, params, max_len=32, seed=0)
+    for _ in range(8):
+        prompt = rng.integers(1, cfg.vocab, 6).tolist()
+        ref = ref_eng.generate([prompt], 8)
+        stream = [int(t) for t in ref.tokens[0]]
+        cut = next((i for i in range(1, len(stream))
+                    if stream[i] not in stream[:i]), None)
+        if cut is not None:
+            break
+    assert cut is not None, "no prompt with a usable EOS token"
+    eos = stream[cut]
 
     sched = Scheduler(Engine(cfg, params, max_len=32, seed=0),
                       n_slots=1, chunk_size=4)
     rid = sched.submit(prompt, 8, eos_id=eos)
     rid2 = sched.submit(prompt, 8)            # queued behind the 1-slot pool
     done = sched.run(max_rounds=50)
-    np.testing.assert_array_equal(done[rid].tokens, ref.tokens[0][:3])
+    np.testing.assert_array_equal(done[rid].tokens, ref.tokens[0][:cut + 1])
     np.testing.assert_array_equal(done[rid2].tokens, ref.tokens[0])
     assert done[rid2].admitted_step >= done[rid].finished_step
 
