@@ -1,0 +1,54 @@
+"""Helpers for the benchmark's own tests (run by hand, on the CPU):
+
+    JAX_PLATFORMS=cpu python -m pytest bench/tests -q
+"""
+from __future__ import annotations
+
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(BENCH))
+sys.path.insert(0, str(BENCH.parent / "src"))
+
+TINY = {
+    "mla": {
+        "name": "tiny-mla", "program_arch": "minicpm3-4b",
+        "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
+        "num_attention_heads": 4, "num_key_value_heads": 4,
+        "q_lora_rank": 32, "kv_lora_rank": 16, "qk_nope_head_dim": 16,
+        "qk_rope_head_dim": 8, "v_head_dim": 16, "vocab_size": 256,
+        "hidden_act": "silu", "rms_norm_eps": 1e-5, "rope_theta": 10000.0,
+        "tie_word_embeddings": False, "torch_dtype": "bfloat16",
+        "kv_cache_dtype": "posit16"},
+    "gqa": {
+        "name": "tiny-gqa", "program_arch": "phi3-medium-14b",
+        "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
+        "num_attention_heads": 4, "num_key_value_heads": 2,
+        "vocab_size": 256, "hidden_act": "silu", "rms_norm_eps": 1e-5,
+        "rope_theta": 10000.0, "tie_word_embeddings": False,
+        "torch_dtype": "bfloat16", "kv_cache_dtype": "posit16"},
+}
+
+TINY_CHAT = {"arrival": "open_loop", "size_seed": 5,
+             "prompt_tokens": {"median": 20, "sigma": 0.8, "min": 4,
+                               "max": 60},
+             "output_tokens": {"median": 12, "sigma": 0.5, "min": 4,
+                               "max": 30}}
+
+
+def tiny_cell(lane: str, **check) -> dict:
+    """A whole cell at toy sizes: what ``run.load_cell`` returns."""
+    limits = {"sample": 3, "max_logit_gap": 0.5, "min_tokens_compared": 8}
+    limits.update(check)
+    return dict(
+        name=f"tiny-{lane}.chat", chips=1, config=TINY[lane], mix=TINY_CHAT,
+        params={"n_slots": 4, "max_len": 128, "block_size": 8,
+                "chunk_size": 8, "rate_per_s": 4.0,
+                "trace": {"last_s": 2}, "check": limits},
+        end_to_end=[{"name": n, "unit": u} for n, u in
+                    (("ttft_p95_ms", "ms"), ("tpot_p95_ms", "ms"),
+                     ("output_tok_s", "tokens/s"), ("setup_s", "s"))],
+        per_layer=[{"name": n, "unit": u} for n, u in
+                   (("queue_wait_p95_ms.chat", "ms"),
+                    ("decode_slot_use.batch", "%"))])
