@@ -114,7 +114,7 @@ def _print_deltas(suite: str, rows, baselines_dir: str = None) -> None:
 def main() -> None:
     from benchmarks import (bench_accuracy, bench_compression, bench_cost,
                             bench_dnn_accuracy, bench_dot, bench_elementwise,
-                            bench_serve, roofline)
+                            bench_serve)
     from repro.launch import compile_cache
     compile_cache.enable()
     suites = {
@@ -125,7 +125,6 @@ def main() -> None:
         "elementwise": bench_elementwise.run,  # fused PVU ops vs round-trip
         "dot": bench_dot.run,                  # §IV-E tiled quire sweep
         "serve": bench_serve.run,              # engine tok/s + paged cache
-        "roofline": roofline.run,              # §Roofline summary
     }
     args = sys.argv[1:]
     as_json = "--json" in args

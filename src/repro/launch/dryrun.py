@@ -12,8 +12,8 @@ records:
   * compiled.cost_analysis()    -> HLO FLOPs / bytes for the roofline
   * collective bytes by op type -> parsed from the optimized HLO
 
-Results land in experiments/dryrun/<arch>__<shape>__<mesh>.json; the
-roofline table (benchmarks/roofline.py, EXPERIMENTS.md) reads them.
+Results land in experiments/dryrun/<arch>__<shape>__<mesh>.json, each
+with its roofline terms.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch phi3-medium-14b \

@@ -343,8 +343,9 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, cfg: ModelConfig,
     # disappears entirely; see kernels/posit_gemm.py.)
     ks, vs = k_cache, v_cache
     if kv_posit is not None:
-        ks = posit_to_f32(ks, pcfg(kv_posit))
-        vs = posit_to_f32(vs, pcfg(kv_posit))
+        with jax.named_scope("kv_decode"):
+            ks = posit_to_f32(ks, pcfg(kv_posit))
+            vs = posit_to_f32(vs, pcfg(kv_posit))
     ks = ks.astype(cdtype(cfg))
     vs = vs.astype(cdtype(cfg))
 
@@ -656,8 +657,9 @@ def decode_attention_paged_mla(q_lat_eff, q_rope, c_arena, r_arena, tables,
     c = paged_gather(c_arena, tables)                 # (B, W*bs, rank)
     r = paged_gather(r_arena, tables)
     if kv_posit:
-        c = posit_to_f32(c, pcfg(kv_posit))
-        r = posit_to_f32(r, pcfg(kv_posit))
+        with jax.named_scope("kv_decode"):
+            c = posit_to_f32(c, pcfg(kv_posit))
+            r = posit_to_f32(r, pcfg(kv_posit))
     c = c.astype(jnp.float32)
     r = r.astype(jnp.float32)
     scores = jnp.einsum("bhr,btr->bht", q_lat_eff.astype(jnp.float32), c)

@@ -562,7 +562,8 @@ def prefill_chunk(params, cache, tokens, cfg: ModelConfig, n_valid, *,
     def load(arena):
         g = L.paged_gather(arena, vtables)                # (B, T, ...)
         if cfg.kv_posit:
-            g = posit_to_f32(g, L.pcfg(cfg.kv_posit))
+            with jax.named_scope("kv_decode"):
+                g = posit_to_f32(g, L.pcfg(cfg.kv_posit))
         return _zero_invalid(g.astype(L.cdtype(cfg)), resident)
 
     def insert(ctx, fresh):
@@ -605,8 +606,9 @@ def prefill_chunk(params, cache, tokens, cfg: ModelConfig, n_valid, *,
             out = out.reshape(b, c, cfg.n_heads * cfg.v_head_dim)
             h = h + L.dense(lp["attn"]["wo"], out, cfg)
             hh = L.rms_norm(lp["ln2"], h, cfg)
-            f = L.moe(lp["moe"], hh, cfg) if cfg.is_moe else \
-                L.mlp(lp["mlp"], hh, cfg)
+            with jax.named_scope("mlp"):
+                f = L.moe(lp["moe"], hh, cfg) if cfg.is_moe else \
+                    L.mlp(lp["mlp"], hh, cfg)
             return h + f, (_maybe_quant_kv(c_suf, cfg),
                            _maybe_quant_kv(r_suf, cfg))
 
@@ -633,8 +635,9 @@ def prefill_chunk(params, cache, tokens, cfg: ModelConfig, n_valid, *,
             out = out.reshape(b, c, cfg.n_heads * cfg.head_dim)
             h = h + L.dense(lp["attn"]["wo"], out, cfg)
             hh = L.rms_norm(lp["ln2"], h, cfg)
-            f = L.moe(lp["moe"], hh, cfg) if cfg.is_moe else \
-                L.mlp(lp["mlp"], hh, cfg)
+            with jax.named_scope("mlp"):
+                f = L.moe(lp["moe"], hh, cfg) if cfg.is_moe else \
+                    L.mlp(lp["mlp"], hh, cfg)
             return h + f, (_maybe_quant_kv(k_suf, cfg),
                            _maybe_quant_kv(v_suf, cfg))
 
@@ -649,10 +652,11 @@ def prefill_chunk(params, cache, tokens, cfg: ModelConfig, n_valid, *,
         new_cache[key] = L.paged_pack_range(
             cache[key], kv, wt, lens, lens_after, window=window)
 
-    x = L.rms_norm(params["final_norm"], x, cfg)
-    last = jnp.take_along_axis(
-        x, jnp.clip(n_valid - 1, 0, c - 1)[:, None, None], axis=1)
-    logits = (last @ _unembed_weight(params, cfg).astype(x.dtype))
+    with jax.named_scope("lm_head"):
+        x = L.rms_norm(params["final_norm"], x, cfg)
+        last = jnp.take_along_axis(
+            x, jnp.clip(n_valid - 1, 0, c - 1)[:, None, None], axis=1)
+        logits = (last @ _unembed_weight(params, cfg).astype(x.dtype))
     return new_cache, logits[:, 0, :].astype(jnp.float32)
 
 
@@ -823,13 +827,15 @@ def _decode_step_paged(params, cache, token, cfg: ModelConfig, active):
     if cfg.mla:
         def body(h, layer):
             lp, c_a, r_a = layer
-            a, c_a, r_a = _decode_attn_mla_paged(
-                lp["attn"], L.rms_norm(lp["ln1"], h, cfg), c_a, r_a,
-                tables, lens, ok, cfg)
+            with jax.named_scope("decode_attn"):
+                a, c_a, r_a = _decode_attn_mla_paged(
+                    lp["attn"], L.rms_norm(lp["ln1"], h, cfg), c_a, r_a,
+                    tables, lens, ok, cfg)
             h = h + a
             hh = L.rms_norm(lp["ln2"], h, cfg)
-            f = L.moe(lp["moe"], hh, cfg) if cfg.is_moe else \
-                L.mlp(lp["mlp"], hh, cfg)
+            with jax.named_scope("mlp"):
+                f = L.moe(lp["moe"], hh, cfg) if cfg.is_moe else \
+                    L.mlp(lp["mlp"], hh, cfg)
             return h + f, (c_a, r_a)
 
         x, (c_new, r_new) = lax.scan(
@@ -838,21 +844,24 @@ def _decode_step_paged(params, cache, token, cfg: ModelConfig, active):
     else:
         def body(h, layer):
             lp, k_a, v_a = layer
-            a, k_a, v_a = _decode_attn_dense_paged(
-                lp["attn"], L.rms_norm(lp["ln1"], h, cfg), k_a, v_a,
-                tables, lens, ok, cfg)
+            with jax.named_scope("decode_attn"):
+                a, k_a, v_a = _decode_attn_dense_paged(
+                    lp["attn"], L.rms_norm(lp["ln1"], h, cfg), k_a, v_a,
+                    tables, lens, ok, cfg)
             h = h + a
             hh = L.rms_norm(lp["ln2"], h, cfg)
-            f = L.moe(lp["moe"], hh, cfg) if cfg.is_moe else \
-                L.mlp(lp["mlp"], hh, cfg)
+            with jax.named_scope("mlp"):
+                f = L.moe(lp["moe"], hh, cfg) if cfg.is_moe else \
+                    L.mlp(lp["mlp"], hh, cfg)
             return h + f, (k_a, v_a)
 
         x, (k_new, v_new) = lax.scan(
             body, x, (params["layers"], cache["k"], cache["v"]))
         new_cache = dict(cache, k=k_new, v=v_new, lens=lens + adv)
 
-    x = L.rms_norm(params["final_norm"], x, cfg)
-    logits = (x[:, 0, :] @ _unembed_weight(params, cfg).astype(x.dtype))
+    with jax.named_scope("lm_head"):
+        x = L.rms_norm(params["final_norm"], x, cfg)
+        logits = (x[:, 0, :] @ _unembed_weight(params, cfg).astype(x.dtype))
     return logits.astype(jnp.float32), new_cache
 
 

@@ -52,6 +52,7 @@ from repro.compress import kvcache as kvc
 from repro.models import get_family
 from repro.models.config import ModelConfig
 from repro.runtime import sharding as shd
+from repro.runtime import spans
 
 
 def sample_token(logits, key, temperature: float):
@@ -227,7 +228,7 @@ class Engine:
         cfg, fam, ml, bs = self.cfg, self.fam, self.max_len, \
             self.block_size
 
-        def run(params, tokens, lens, *kw_vals):
+        def prefill_batch(params, tokens, lens, *kw_vals):
             kw = dict(zip(kw_names, kw_vals))    # tables ride past the zip
             if n_blocks:
                 kw.update(block_tables=kw_vals[-1], block_size=bs,
@@ -237,7 +238,7 @@ class Engine:
                                    prompt_lens=lens, **kw)
             return fam.prefill(params, tokens, cfg, max_len=ml, **kw)
 
-        return jax.jit(run)
+        return jax.jit(prefill_batch)
 
     def _row_blocks_needed(self, prompt_len: int, reserve: int) -> int:
         """Blocks covering a row's prompt plus ``reserve`` decode
@@ -325,22 +326,30 @@ class Engine:
         ``n_steps`` masked decode steps.  The shapes depend only on
         (batch, chunk width, n_steps) — never on any prompt length — so
         a scheduler running in chunked mode compiles this ONCE and
-        serves every request with it."""
+        serves every request with it.
+
+        Its module is ``jit_run``, a name no other engine program takes,
+        so a profile finds the serving round by it; ``jax.named_scope``
+        names its phases (``prefill_chunk``, ``sample``; the model code
+        adds ``decode_attn``, ``kv_decode``, ``mlp``, ``lm_head``) in the
+        ops' metadata, which leaves the compiled program unchanged."""
         from repro.models import transformer as T
         cfg, fam, temp = self.cfg, self.fam, self.temperature
         vw = -(-self.max_len // self.block_size)
 
         def run(params, cache, chunk_tokens, n_valid, tok, key,
                 decode_active, write_tables):
-            cache, chunk_logits = T.prefill_chunk(
-                params, cache, chunk_tokens, cfg, n_valid,
-                virtual_width=vw, write_tables=write_tables)
+            with jax.named_scope("prefill_chunk"):
+                cache, chunk_logits = T.prefill_chunk(
+                    params, cache, chunk_tokens, cfg, n_valid,
+                    virtual_width=vw, write_tables=write_tables)
 
             def step(carry, _):
                 cache, tok, key = carry
                 logits, cache = fam.decode_step(params, cache, tok, cfg,
                                                 active=decode_active)
-                nxt, key = sample_token(logits, key, temp)
+                with jax.named_scope("sample"):
+                    nxt, key = sample_token(logits, key, temp)
                 return (cache, nxt, key), nxt
 
             (cache, _, key), toks = lax.scan(
@@ -376,37 +385,40 @@ class Engine:
         if not self.paged:
             raise ValueError("mixed_step needs Engine(paged=True)")
         from repro.core.tracing import is_tracer
-        chunk_tokens = jnp.asarray(chunk_tokens, jnp.int32)
-        b, c = chunk_tokens.shape
-        nv = np.asarray(n_valid, np.int32)
-        act = np.zeros((b,), bool) if decode_active is None \
-            else np.asarray(decode_active, bool)
-        lens = cache["lens"]
-        if not is_tracer(lens):
-            lens_np = np.asarray(lens)
-            if (nv > 0).any():
-                hi = int((lens_np + nv)[nv > 0].max())
-                if hi > self.max_len:
-                    raise ValueError(
-                        f"mixed_step: prefill chunk frontier {hi} "
-                        f"exceeds engine max_len {self.max_len}")
-            if act.any():
-                hi = int(lens_np[act].max())
-                if hi + int(n_steps) > self.max_len:
-                    raise ValueError(
-                        f"mixed_step: decode frontier {hi} + "
-                        f"{int(n_steps)} steps exceeds engine max_len "
-                        f"{self.max_len}; retire rows first")
-        wt = cache["block_tables"] if write_tables is None \
-            else jnp.asarray(write_tables, jnp.int32)
-        key = ("mixed", int(c), int(n_steps))
-        fn = self._get_jit(self._decode_jit, key,
-                           lambda: self._mixed_fn(int(n_steps)))
-        cache, chunk_logits, toks, self._key = self._dispatch(
-            fn, self.params, cache, chunk_tokens, jnp.asarray(nv),
-            jnp.asarray(tokens, jnp.int32), self._key,
-            jnp.asarray(act), wt)
-        return self.shard_cache(cache), chunk_logits, toks
+        # the span holds the lens guard: a device->host sync before the
+        # enqueue
+        with spans.span("engine.dispatch"):
+            chunk_tokens = jnp.asarray(chunk_tokens, jnp.int32)
+            b, c = chunk_tokens.shape
+            nv = np.asarray(n_valid, np.int32)
+            act = np.zeros((b,), bool) if decode_active is None \
+                else np.asarray(decode_active, bool)
+            lens = cache["lens"]
+            if not is_tracer(lens):
+                lens_np = np.asarray(lens)
+                if (nv > 0).any():
+                    hi = int((lens_np + nv)[nv > 0].max())
+                    if hi > self.max_len:
+                        raise ValueError(
+                            f"mixed_step: prefill chunk frontier {hi} "
+                            f"exceeds engine max_len {self.max_len}")
+                if act.any():
+                    hi = int(lens_np[act].max())
+                    if hi + int(n_steps) > self.max_len:
+                        raise ValueError(
+                            f"mixed_step: decode frontier {hi} + "
+                            f"{int(n_steps)} steps exceeds engine max_len "
+                            f"{self.max_len}; retire rows first")
+            wt = cache["block_tables"] if write_tables is None \
+                else jnp.asarray(write_tables, jnp.int32)
+            key = ("mixed", int(c), int(n_steps))
+            fn = self._get_jit(self._decode_jit, key,
+                               lambda: self._mixed_fn(int(n_steps)))
+            cache, chunk_logits, toks, self._key = self._dispatch(
+                fn, self.params, cache, chunk_tokens, jnp.asarray(nv),
+                jnp.asarray(tokens, jnp.int32), self._key,
+                jnp.asarray(act), wt)
+            return self.shard_cache(cache), chunk_logits, toks
 
     # ------------------------------------------------------------------
     # prefix sharing: COW block copies + sanitizer poison (paged only)
@@ -420,12 +432,12 @@ class Engine:
         keys = ("c_kv", "k_rope") if self.cfg.mla else ("k", "v")
 
         def build():
-            def run(cache, src, dst):
+            def arena_copy(cache, src, dst):
                 out = dict(cache)
                 for k in keys:
                     out[k] = L.paged_copy_blocks(cache[k], src, dst)
                 return out
-            return jax.jit(run)
+            return jax.jit(arena_copy)
 
         fn = self._get_jit(self._decode_jit, ("copy", len(src_ids)),
                            build)
@@ -445,12 +457,12 @@ class Engine:
         keys = ("c_kv", "k_rope") if self.cfg.mla else ("k", "v")
 
         def build():
-            def run(cache, ids):
+            def arena_poison(cache, ids):
                 out = dict(cache)
                 for k in keys:
                     out[k] = L.paged_poison_blocks(cache[k], ids)
                 return out
-            return jax.jit(run)
+            return jax.jit(arena_poison)
 
         fn = self._get_jit(self._decode_jit, ("poison", len(ids)), build)
         return self.shard_cache(self._dispatch(
@@ -463,7 +475,7 @@ class Engine:
     def _decode_fn(self, n_steps: int):
         cfg, fam, temp = self.cfg, self.fam, self.temperature
 
-        def run(params, cache, logits, key):
+        def decode_scan(params, cache, logits, key):
             tok0, key = sample_token(logits, key, temp)
 
             def step(carry, _):
@@ -477,7 +489,7 @@ class Engine:
             out = jnp.concatenate([tok0[None], toks], axis=0)  # (n,B)
             return cache, out.T, key
 
-        return jax.jit(run)
+        return jax.jit(decode_scan)
 
     def _chunk_fn(self, n_steps: int):
         """Fixed-size decode chunk: ``n_steps`` masked decode steps in ONE
@@ -487,7 +499,7 @@ class Engine:
         cfg, fam, temp = self.cfg, self.fam, self.temperature
         masked = cfg.family in ("transformer", "hymba")
 
-        def run(params, cache, tok, key, active):
+        def chunk_scan(params, cache, tok, key, active):
             def step(carry, _):
                 cache, tok, key = carry
                 if masked:
@@ -502,7 +514,7 @@ class Engine:
                 step, (cache, tok, key), length=n_steps)
             return cache, toks.T, key                     # (B, n_steps)
 
-        return jax.jit(run)
+        return jax.jit(chunk_scan)
 
     def decode_chunk(self, cache, tokens, n_steps: int, *, active=None):
         """Advance every slot by ``n_steps`` decode steps in one compiled

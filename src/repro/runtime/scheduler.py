@@ -124,7 +124,7 @@ ratios transfer to hardware.
 from __future__ import annotations
 
 import dataclasses
-import time
+import itertools
 from collections import deque
 from typing import Optional
 
@@ -135,7 +135,10 @@ import jax.numpy as jnp
 
 from repro.compress import kvcache as kvc
 from repro.models import get_family
+from . import spans
 from .engine import Engine, sample_token
+
+_SCHED_IDS = itertools.count(1)   # the ``sched`` id of each instance
 
 
 @dataclasses.dataclass
@@ -302,11 +305,11 @@ class Scheduler:
         self._frontier = 0             # host mirror of cache["len"]
         self._next_rid = 0
         self.steps_run = 0             # decode steps executed (sim clock)
-        # real wall time per scheduling round (ms), measured around
-        # step(): the first half of the wall-clock-SLO roadmap item.
-        # Observability only — EDF/preemption still run on the
-        # decode-step sim clock (serve.MS_PER_STEP)
-        self._step_wall_ms: list = []
+        # spans (runtime/spans.py): this instance's id, the round
+        # count, and each request's open lifecycle span (rid -> span)
+        self.sched_id = next(_SCHED_IDS)
+        self._round = 0
+        self._life: dict = {}
         self.n_chunks = 0
         self.n_admitted = 0
         self.n_retired = 0
@@ -363,6 +366,10 @@ class Scheduler:
                     f"{self.block_size})")
         rid = self._next_rid
         self._next_rid += 1
+        t = spans.event("request.submit", sched=self.sched_id,
+                        rid=rid).start_ns
+        self._life[rid] = spans.begin("request.queued", start_ns=t,
+                                      sched=self.sched_id, rid=rid)
         self._queue.append(Request(rid=rid, prompt=prompt,
                                    max_new_tokens=max_new_tokens,
                                    eos_id=self.eos_id if eos_id is None
@@ -381,15 +388,28 @@ class Scheduler:
     def n_active(self) -> int:
         return sum(1 for s in self._slots if s is not None and not s.done)
 
+    def progress(self) -> dict:
+        """``rid -> (tokens delivered, cache positions written)`` for
+        every request holding a slot, as of the last round's end: the
+        tokens its slot has emitted, and its cache occupancy (the chunk
+        cursor while it prefills).  A request that finished is in the
+        completions of the round that retired it instead."""
+        return {s.req.rid: (len(s.emitted), s.lens)
+                for s in self._slots if s is not None}
+
     @property
     def stats(self) -> dict:
         """Counters for one serving run — notably ``n_compiles``, the
         engine's distinct-lowered-program count: flat after warmup in
         chunked mode, growing with every new prompt length otherwise.
         ``step_wall_p50_ms``/``step_wall_p99_ms`` are REAL per-round
-        wall times (0.0 before the first round); the sim clock
-        (``steps_run``) stays the scheduling time base."""
-        wall = np.asarray(self._step_wall_ms, np.float64)
+        wall times (0.0 before the first round), read from this
+        scheduler's ``sched.round`` spans still in the recorder's ring;
+        the sim clock (``steps_run``) stays the scheduling time base."""
+        wall = np.asarray(
+            [s.end_ns - s.start_ns for s in spans.snapshot()
+             if s.name == "sched.round"
+             and s.ids["sched"] == self.sched_id], np.float64) * 1e-6
         d = dict(
             n_admitted=self.n_admitted, n_retired=self.n_retired,
             n_preempted=self.n_preempted, n_chunks=self.n_chunks,
@@ -778,6 +798,7 @@ class Scheduler:
         slot = self._slots[i]
         self._slots[i] = None
         self.n_preempted += 1
+        self._phase(slot.req.rid, "request.queued", preempted=1)
         self._outstanding -= self._row_debt(i)
         reclaimed = self.pool.free(self._row_blocks[i])
         if self._row_borrowed[i]:
@@ -822,6 +843,17 @@ class Scheduler:
         self._preempt_row(victim)
         return True
 
+    def _phase(self, rid: int, name: Optional[str], *, at=None,
+               **counters):
+        """Close request ``rid``'s open lifecycle span at ``at`` (default
+        now) with ``counters`` set, and open ``name`` (``request.*``) at
+        the same instant, so a request's spans tile its life with no
+        gap; ``name=None`` closes the last one."""
+        t = self._life.pop(rid).set(**counters).end(at)
+        if name is not None:
+            self._life[rid] = spans.begin(name, start_ns=t,
+                                          sched=self.sched_id, rid=rid)
+
     def _admit(self):
         self._order_queue()
         free = [i for i, s in enumerate(self._slots) if s is None]
@@ -843,7 +875,9 @@ class Scheduler:
                     req=req, emitted=[],
                     admitted_step=self.steps_run, cursor=cursor)
                 self.n_admitted += 1
+                self._phase(req.rid, "request.prefill")
                 continue
+            t_admit = spans.now_ns()
             if self.paged:
                 tok0 = self._admit_paged(req, row)
                 if tok0 is False:      # pool cannot cover the request yet
@@ -868,6 +902,9 @@ class Scheduler:
                                          jnp.int32(row))
             self._queue.popleft()
             free.remove(row)
+            # the whole prompt prefilled and tok0 sampled just now
+            self._phase(req.rid, "request.prefill", at=t_admit)
+            self._phase(req.rid, "request.decode", prefill_rounds=1)
             slot = _Slot(req=req, emitted=[tok0],
                          admitted_step=self.steps_run)
             # a request can finish on its very first (prefill) token
@@ -913,6 +950,7 @@ class Scheduler:
                 first_logits=slot.first_logits))
             self._slots[i] = None
             self.n_retired += 1
+            self._phase(req.rid, None, tokens=len(slot.emitted))
             if self.paged:
                 # drop the row's references: owned blocks physically
                 # reclaim unless the prefix index still holds them;
@@ -947,82 +985,106 @@ class Scheduler:
                                          jnp.asarray(done_mask))
         return completions
 
-    def _step_chunked(self):
+    def _step_chunked(self, rnd):
         """One chunked scheduling round: admit (allocation only) ->
         extend/COW/sanitize for the combined prefill+decode write spans
         -> ONE ``mixed_step`` dispatch (a prefill chunk for every
         prefilling row, the decode quantum for every decoding row —
-        compiled once, for every prompt length) -> advance cursors,
-        sample first tokens for rows that completed their prompt, emit
-        decode tokens -> retire."""
-        self._admit()
-        decode_active = np.array(
-            [s is not None and not s.done and s.cursor is None
-             for s in self._slots], bool)
-        nv = np.zeros((self.n_slots,), np.int32)
-        chunk = np.full((self.n_slots, self.chunk_size),
-                        self.engine.pad_id, np.int32)
-        for i, s in enumerate(self._slots):
-            if s is None or s.done or s.cursor is None:
-                continue
-            n = min(self.chunk_size, len(s.req.prompt) - s.cursor)
-            nv[i] = n
-            chunk[i, :n] = s.req.prompt[s.cursor:s.cursor + n]
-        if not decode_active.any() and not nv.any():
+        compiled once, for every prompt length) -> advance cursors and
+        emit decode tokens -> sample first tokens for rows that
+        completed their prompt -> retire."""
+        with spans.span("sched.admit"):
+            self._admit()
+        with spans.span("sched.prepare"):
+            decode_active = np.array(
+                [s is not None and not s.done and s.cursor is None
+                 for s in self._slots], bool)
+            nv = np.zeros((self.n_slots,), np.int32)
+            chunk = np.full((self.n_slots, self.chunk_size),
+                            self.engine.pad_id, np.int32)
+            for i, s in enumerate(self._slots):
+                if s is None or s.done or s.cursor is None:
+                    continue
+                n = min(self.chunk_size, len(s.req.prompt) - s.cursor)
+                nv[i] = n
+                chunk[i, :n] = s.req.prompt[s.cursor:s.cursor + n]
+            dispatch = decode_active.any() or nv.any()
+            if dispatch:
+                self._ensure_blocks()
+                if self.sanitize:
+                    self._sanitize_check_chunk()
+                # per-round write tables: every still-borrowed entry
+                # hidden behind the sentinel so shared blocks never take
+                # a write (not even a byte-identical write-back from
+                # pack_range)
+                wt = self._tables.copy()
+                for i, borrowed in enumerate(self._row_borrowed):
+                    for s in borrowed:
+                        wt[i, s] = self.n_blocks
+        if not dispatch:
             # admissions can complete instantly only via retirement of
             # already-done slots; surface those without a dispatch
-            return self._retire()
-        self._ensure_blocks()
-        if self.sanitize:
-            self._sanitize_check_chunk()
-        # per-round write tables: every still-borrowed entry hidden
-        # behind the sentinel so shared blocks never take a write (not
-        # even a byte-identical write-back from pack_range)
-        wt = self._tables.copy()
-        for i, borrowed in enumerate(self._row_borrowed):
-            for s in borrowed:
-                wt[i, s] = self.n_blocks
+            with spans.span("sched.retire"):
+                return self._retire()
         self.cache, chunk_logits, toks = self.engine.mixed_step(
             self.cache, chunk, nv, self._cur_tok, self.chunk_size,
             decode_active=decode_active, write_tables=wt)
-        toks = np.asarray(toks)
-        chunk_logits = np.asarray(chunk_logits)
+        with spans.span("sched.device_wait"):
+            toks = np.asarray(toks)
+            chunk_logits = np.asarray(chunk_logits)
         self.steps_run += self.chunk_size
         self.n_chunks += 1
 
-        for i, s in enumerate(self._slots):
-            if s is None or s.done:
-                continue
-            req = s.req
-            if decode_active[i]:
-                for t in toks[i]:
-                    s.emitted.append(int(t))
-                    if int(t) == req.eos_id or \
-                            len(s.emitted) >= req.max_new_tokens:
-                        s.done = True
-                        break
-                self._cur_tok[i] = toks[i, -1]
-            elif nv[i]:
-                s.cursor += int(nv[i])
-                self.prefill_tokens += int(nv[i])
-                if s.cursor >= len(req.prompt):
-                    # prompt complete: first token comes from the
-                    # chunk's last-valid-position logits, exactly where
-                    # a whole-prompt prefill would have sampled it
-                    s.cursor = None
-                    if self.prefix_cache:
-                        self._register_row(req.prompt, i)
-                        self._note_peaks()
-                    tok0, self.engine._key = sample_token(
-                        jnp.asarray(chunk_logits[i:i + 1]),
-                        self.engine._key, self.engine.temperature)
-                    tok0 = int(np.asarray(tok0)[0])
-                    s.first_logits = chunk_logits[i].copy()
-                    s.emitted.append(tok0)
-                    self._cur_tok[i] = tok0
-                    if tok0 == req.eos_id or req.max_new_tokens == 1:
-                        s.done = True
-        return self._retire()
+        completed, emitted = [], 0
+        with spans.span("sched.emit"):
+            for i, s in enumerate(self._slots):
+                if s is None or s.done:
+                    continue
+                req = s.req
+                if decode_active[i]:
+                    n0 = len(s.emitted)
+                    for t in toks[i]:
+                        s.emitted.append(int(t))
+                        if int(t) == req.eos_id or \
+                                len(s.emitted) >= req.max_new_tokens:
+                            s.done = True
+                            break
+                    emitted += len(s.emitted) - n0
+                    self._cur_tok[i] = toks[i, -1]
+                elif nv[i]:
+                    s.cursor += int(nv[i])
+                    self.prefill_tokens += int(nv[i])
+                    if s.cursor >= len(req.prompt):
+                        s.cursor = None
+                        if self.prefix_cache:
+                            self._register_row(req.prompt, i)
+                            self._note_peaks()
+                        completed.append(i)
+        with spans.span("sched.first_tokens") as ft:
+            # prompt complete: the first token comes from the chunk's
+            # last-valid-position logits, exactly where a whole-prompt
+            # prefill would have sampled it (one device round trip each)
+            for i in completed:
+                s = self._slots[i]
+                req = s.req
+                tok0, self.engine._key = sample_token(
+                    jnp.asarray(chunk_logits[i:i + 1]),
+                    self.engine._key, self.engine.temperature)
+                tok0 = int(np.asarray(tok0)[0])
+                s.first_logits = chunk_logits[i].copy()
+                s.emitted.append(tok0)
+                self._cur_tok[i] = tok0
+                if tok0 == req.eos_id or req.max_new_tokens == 1:
+                    s.done = True
+                self._phase(req.rid, "request.decode", prefill_rounds=(
+                    self.steps_run - s.admitted_step) // self.chunk_size)
+            ft.set(syncs=len(completed))
+        rnd.set(prefill_rows=int((nv > 0).sum()),
+                decode_rows=int(decode_active.sum()),
+                tokens_emitted=emitted + len(completed),
+                first_tokens=len(completed))
+        with spans.span("sched.retire"):
+            return self._retire()
 
     def step(self):
         """One scheduling round; returns the requests completed in it.
@@ -1043,56 +1105,85 @@ class Scheduler:
         refcount is 1; reservation never lets extension or COW find
         the pool empty.
 
-        Every round is wall-timed (``time.perf_counter``); ``stats``
-        surfaces the p50/p99 in milliseconds next to the sim clock."""
-        t0 = time.perf_counter()
-        try:
-            if self.chunked:
-                return self._step_chunked()
-            return self._step_unchunked()
-        finally:
-            self._step_wall_ms.append((time.perf_counter() - t0) * 1e3)
+        Every round is a ``sched.round`` span (``runtime/spans.py``)
+        whose children are its phases (``sched.admit``,
+        ``sched.prepare``, ``engine.dispatch``, ``sched.device_wait``,
+        ``sched.emit``, ``sched.first_tokens``, ``sched.retire``) and
+        whose counters say what it did; ``compiled`` is the engine's
+        new compiles in the round, non-zero only on a round that
+        compiled.  ``stats`` reads the round spans' p50/p99."""
+        with spans.span("sched.round", sched=self.sched_id,
+                        round=self._round) as rnd:
+            before = (self.n_admitted, self.n_retired, self.prefill_tokens,
+                      self.engine.n_compiles)
+            rnd.set(round=self._round, prefill_rows=0, decode_rows=0,
+                    tokens_emitted=0, first_tokens=0)
+            done = self._step_chunked(rnd) if self.chunked \
+                else self._step_unchunked(rnd)
+            rnd.set(admitted=self.n_admitted - before[0],
+                    retired=self.n_retired - before[1],
+                    prefill_tokens=self.prefill_tokens - before[2],
+                    queue_depth=len(self._queue),
+                    compiled=self.engine.n_compiles - before[3])
+        self._round += 1
+        return done
 
-    def _step_unchunked(self):
-        self._admit()
+    def _step_unchunked(self, rnd):
+        n0 = self.n_admitted
+        with spans.span("sched.admit"):
+            self._admit()
+        # every admission prefilled its whole prompt and sampled tok0
+        admitted = self.n_admitted - n0
+        rnd.set(prefill_rows=admitted, first_tokens=admitted,
+                tokens_emitted=admitted)
         active = np.array(
             [s is not None and not s.done for s in self._slots], bool)
         if not active.any():
             # admissions can complete instantly (EOS on the prefill
             # token); surface those without burning a decode chunk
-            return self._retire()
+            with spans.span("sched.retire"):
+                return self._retire()
 
-        if self.paged:
-            # no shared frontier: rows extend their own block tables
-            self._ensure_blocks()
-            if self.sanitize:
-                self._sanitize_check_chunk()
-        elif self._frontier + self.chunk_size > self.engine.max_len:
-            # reclaim headroom freed by retirements / short rows
-            target = max(s.lens for s in self._slots
-                         if s is not None and not s.done)
-            self._set_frontier(target)
-
-        self.cache, toks = self.engine.decode_chunk(
-            self.cache, self._cur_tok, self.chunk_size,
-            active=jnp.asarray(active))
-        toks = np.asarray(toks)
+        with spans.span("sched.prepare"):
+            if self.paged:
+                # no shared frontier: rows extend their own block tables
+                self._ensure_blocks()
+                if self.sanitize:
+                    self._sanitize_check_chunk()
+            elif self._frontier + self.chunk_size > self.engine.max_len:
+                # reclaim headroom freed by retirements / short rows
+                target = max(s.lens for s in self._slots
+                             if s is not None and not s.done)
+                self._set_frontier(target)
+        with spans.span("engine.dispatch"):
+            self.cache, toks = self.engine.decode_chunk(
+                self.cache, self._cur_tok, self.chunk_size,
+                active=jnp.asarray(active))
+        with spans.span("sched.device_wait"):
+            toks = np.asarray(toks)
         if not self.paged:
             self._frontier += self.chunk_size  # mirror of cache["len"]
         self.steps_run += self.chunk_size
         self.n_chunks += 1
 
-        for i in np.nonzero(active)[0]:
-            slot = self._slots[i]
-            req = slot.req
-            for t in toks[i]:
-                slot.emitted.append(int(t))
-                if int(t) == req.eos_id or \
-                        len(slot.emitted) >= req.max_new_tokens:
-                    slot.done = True
-                    break
-            self._cur_tok[i] = toks[i, -1]
-        return self._retire()
+        emitted = 0
+        with spans.span("sched.emit"):
+            for i in np.nonzero(active)[0]:
+                slot = self._slots[i]
+                req = slot.req
+                n = len(slot.emitted)
+                for t in toks[i]:
+                    slot.emitted.append(int(t))
+                    if int(t) == req.eos_id or \
+                            len(slot.emitted) >= req.max_new_tokens:
+                        slot.done = True
+                        break
+                emitted += len(slot.emitted) - n
+                self._cur_tok[i] = toks[i, -1]
+        rnd.set(decode_rows=int(active.sum()),
+                tokens_emitted=admitted + emitted)
+        with spans.span("sched.retire"):
+            return self._retire()
 
     def run(self, max_rounds: Optional[int] = None):
         """Drain queue + slots; returns ``{rid: Completion}``."""
