@@ -533,28 +533,31 @@ def paged_positions(frontier, table_width: int, block_size: int, *,
     return apos.reshape(b, w * bs)
 
 
-def _arena_head_constraint(x):
+def _arena_head_constraint(x, stacked: bool = False):
     """Pin the head axis of dense paged-KV tensors to the 'model' mesh
     axis: the arena is device_put with heads on 'model'
     (``runtime/sharding.py::paged_cache_specs``), and this constraint on
     the gathered/updated views keeps every paged read and write
-    shard-local — decode never all-gathers KV.  MLA latents (no head
-    axis, rank-3 views) pass through untouched, as does everything
-    outside a mesh context (same no-op contract as
-    ``_attn_context_parallel``).  When 'model' does not divide the head
-    count the arena itself fell back to replicated
-    (``paged_cache_specs``' filter), so the constraint is skipped too —
-    a mismatched pin would force GSPMD into full rematerializations."""
-    if x.ndim != 4:
+    shard-local — decode never all-gathers KV.  A dense view is rank 4
+    (``(n_blocks, bs, G, D)`` or gathered ``(B, T, G, D)``), the
+    ``stacked`` arena rank 5 (``(L, n_blocks, bs, G, D)``); the head
+    axis is second to last in both.  MLA latents (no head axis, one
+    rank lower) pass through untouched, as does everything outside a
+    mesh context (same no-op contract as ``_attn_context_parallel``).
+    When 'model' does not divide the head count the arena itself fell
+    back to replicated (``paged_cache_specs``' filter), so the
+    constraint is skipped too — a mismatched pin would force GSPMD into
+    full rematerializations."""
+    if x.ndim != 4 + stacked:
         return x
     try:
         from jax.sharding import PartitionSpec as P, get_abstract_mesh
         mesh = get_abstract_mesh()
         n_model = dict(zip(mesh.axis_names, mesh.axis_sizes)).get("model", 1)
-        if n_model <= 1 or x.shape[2] % n_model:
+        if n_model <= 1 or x.shape[-2] % n_model:
             return x
         return lax.with_sharding_constraint(
-            x, P(None, None, "model", None))
+            x, P(*([None] * (x.ndim - 2)), "model", None))
     except (ValueError, RuntimeError, TypeError, NameError,
             AttributeError, ImportError):
         return x
@@ -672,7 +675,8 @@ def decode_attention_paged_mla(q_lat_eff, q_rope, c_arena, r_arena, tables,
     return jnp.einsum("bht,btr->bhr", probs, c)       # (B, H, rank)
 
 
-def paged_cache_update(arena, upd, tables, pos, ok, *, window: int = 0):
+def paged_cache_update(arena, upd, tables, pos, ok, *, window: int = 0,
+                       layer=None):
     """Scatter one new KV vector per row into its block: row b writes
     ``upd[b]`` at logical position ``pos[b]``.
 
@@ -680,8 +684,14 @@ def paged_cache_update(arena, upd, tables, pos, ok, *, window: int = 0):
     slots, out-of-capacity positions) and writes through sentinel table
     entries are DROPPED — never clamped onto someone else's block.
     ``upd``: (B, ...) matching the arena's per-slot trailing dims.
+
+    With ``layer`` (a scalar) ``arena`` is the stacked
+    ``(L, n_blocks, bs, ...)`` arena and the write lands at
+    ``(layer, block, offset)``: one scatter into the whole arena, which
+    a layer scan carries and XLA updates in place.
     """
-    nb, bs = arena.shape[0], arena.shape[1]
+    lead = 0 if layer is None else 1
+    nb, bs = arena.shape[lead], arena.shape[lead + 1]
     w = tables.shape[1]
     pos = jnp.asarray(pos, jnp.int32)
     blk = pos // bs
@@ -693,8 +703,11 @@ def paged_cache_update(arena, upd, tables, pos, ok, *, window: int = 0):
     phys = jnp.take_along_axis(
         tables, jnp.clip(slot, 0, w - 1)[:, None], axis=1)[:, 0]
     phys = jnp.where(ok, phys, nb)              # sentinel: scatter drops
+    idx = (phys, lax.rem(pos, bs))
+    if layer is not None:
+        idx = (layer,) + idx
     return _arena_head_constraint(
-        arena.at[phys, lax.rem(pos, bs)].set(upd, mode="drop"))
+        arena.at[idx].set(upd, mode="drop"), stacked=bool(lead))
 
 
 def paged_pack(arena, kvs, tables, lens, *, window: int = 0,

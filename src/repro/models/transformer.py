@@ -733,15 +733,28 @@ def _decode_attn_mla(p, x, c_cache, r_cache, pos, lens, cfg: ModelConfig):
     return L.dense(p["wo"], out, cfg), c_cache, r_cache
 
 
-def _decode_attn_dense_paged(p, x, k_arena, v_arena, tables, lens, ok,
-                             cfg: ModelConfig):
+def _layer_arena(arena, layer):
+    """Layer ``layer``'s arena, sliced out of the stacked arena to be
+    read (``arena`` itself when ``layer`` is None).  Decode reads it so,
+    not by a gather at ``(layer, table)`` off the stacked arena: on a
+    v5e that gather ran 1.8x (latent arena) to 20x (RoPE arena, whose
+    default device layout puts the block axis minor) slower than this
+    slice and a one-index gather from it."""
+    if layer is None:
+        return arena
+    return lax.dynamic_index_in_dim(arena, layer, keepdims=False)
+
+
+def _decode_attn_dense_paged(p, x, k_arena, v_arena, layer, tables, lens,
+                             ok, cfg: ModelConfig):
     """Paged dense/GQA decode: per-row write position ``lens[b]`` into the
     row's block, then attention straight off the block tables
     (``cfg.paged_attn_kernel`` picks the fused Pallas table walk or the
     gather+jnp reference).  The same projections, RoPE positions
     (content-relative ``lens``) and softmax math as the linear lane —
     only the storage addressing differs, so the scores over valid
-    positions are identical."""
+    positions are identical.  ``layer`` indexes the stacked
+    ``(L, n_blocks, ...)`` arenas; None takes one layer's arenas."""
     b = x.shape[0]
     window = _paged_window(cfg)
     q = L.dense(p["wq"], x, cfg).reshape(b, 1, cfg.n_heads, cfg.head_dim)
@@ -752,23 +765,24 @@ def _decode_attn_dense_paged(p, x, k_arena, v_arena, tables, lens, ok,
 
     k_arena = L.paged_cache_update(
         k_arena, _maybe_quant_kv(k, cfg)[:, 0], tables, lens, ok,
-        window=window)
+        window=window, layer=layer)
     v_arena = L.paged_cache_update(
         v_arena, _maybe_quant_kv(v, cfg)[:, 0], tables, lens, ok,
-        window=window)
+        window=window, layer=layer)
     out = L.decode_attention_paged(
-        q, k_arena, v_arena, tables, lens, cfg=cfg,
-        kv_posit=cfg.kv_posit, window=window,
+        q, _layer_arena(k_arena, layer), _layer_arena(v_arena, layer),
+        tables, lens, cfg=cfg, kv_posit=cfg.kv_posit, window=window,
         kernel=cfg.paged_attn_kernel)
     out = out.reshape(b, 1, cfg.n_heads * cfg.head_dim)
     return L.dense(p["wo"], out, cfg), k_arena, v_arena
 
 
-def _decode_attn_mla_paged(p, x, c_arena, r_arena, tables, lens, ok,
+def _decode_attn_mla_paged(p, x, c_arena, r_arena, layer, tables, lens, ok,
                            cfg: ModelConfig):
     """Paged absorbed-matrix MLA decode (row-local positions);
     ``cfg.paged_attn_kernel`` picks the fused latent-space table walk
-    or the gather+jnp reference."""
+    or the gather+jnp reference.  ``layer`` as in
+    :func:`_decode_attn_dense_paged`."""
     b = x.shape[0]
     q_lat = L.rms_norm(p["q_norm"], L.dense(p["wdq"], x, cfg), cfg)
     q = L.dense(p["wuq"], q_lat, cfg).reshape(
@@ -783,15 +797,18 @@ def _decode_attn_mla_paged(p, x, c_arena, r_arena, tables, lens, ok,
     r_new = L.apply_rope(r_new[:, :, None, :], lens[:, None],
                          cfg.rope_theta)[:, :, 0, :]
     c_arena = L.paged_cache_update(
-        c_arena, _maybe_quant_kv(c_new, cfg)[:, 0], tables, lens, ok)
+        c_arena, _maybe_quant_kv(c_new, cfg)[:, 0], tables, lens, ok,
+        layer=layer)
     r_arena = L.paged_cache_update(
-        r_arena, _maybe_quant_kv(r_new, cfg)[:, 0], tables, lens, ok)
+        r_arena, _maybe_quant_kv(r_new, cfg)[:, 0], tables, lens, ok,
+        layer=layer)
 
     wuk = L.maybe_dequant(p["wuk"]["w"], cfg).reshape(
         cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_dim)
     q_lat_eff = jnp.einsum("bhd,rhd->bhr", q_nope.astype(jnp.float32), wuk)
     ctx_lat = L.decode_attention_paged_mla(
-        q_lat_eff, q_rope, c_arena, r_arena, tables, lens, cfg=cfg,
+        q_lat_eff, q_rope, _layer_arena(c_arena, layer),
+        _layer_arena(r_arena, layer), tables, lens, cfg=cfg,
         kv_posit=cfg.kv_posit, kernel=cfg.paged_attn_kernel)
     wuv = L.maybe_dequant(p["wuv"]["w"], cfg).reshape(
         cfg.kv_lora_rank, cfg.n_heads, cfg.v_head_dim)
@@ -804,7 +821,16 @@ def _decode_step_paged(params, cache, token, cfg: ModelConfig, active):
     """Paged decode: every row writes at its OWN position ``lens[b]`` (no
     shared frontier), inactive rows' writes are dropped and their
     ``lens`` frozen.  Out-of-capacity positions drop too (the no-clamp
-    guarantee); concrete frontiers raise eagerly like the linear lane."""
+    guarantee); concrete frontiers raise eagerly like the linear lane.
+
+    The layer scan carries the two stacked arenas whole: each layer
+    writes its new KV with one scatter at ``(layer, block, offset)``,
+    so the arenas are updated in place, and reads its blocks from its
+    own arena slice (``_layer_arena``).  Passing the arenas through the
+    scan as ``xs``/``ys`` instead rewrites each layer's slice around
+    the scatter and restacks it into a fresh arena, which the caller's
+    step scan then copies back: a whole-arena copy every decode
+    step."""
     from repro.core.tracing import is_tracer
 
     b = token.shape[0]
@@ -824,40 +850,29 @@ def _decode_step_paged(params, cache, token, cfg: ModelConfig, active):
     if cfg.scale_embed:
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
 
-    if cfg.mla:
-        def body(h, layer):
-            lp, c_a, r_a = layer
-            with jax.named_scope("decode_attn"):
-                a, c_a, r_a = _decode_attn_mla_paged(
-                    lp["attn"], L.rms_norm(lp["ln1"], h, cfg), c_a, r_a,
-                    tables, lens, ok, cfg)
-            h = h + a
-            hh = L.rms_norm(lp["ln2"], h, cfg)
-            with jax.named_scope("mlp"):
-                f = L.moe(lp["moe"], hh, cfg) if cfg.is_moe else \
-                    L.mlp(lp["mlp"], hh, cfg)
-            return h + f, (c_a, r_a)
+    keys = ("c_kv", "k_rope") if cfg.mla else ("k", "v")
+    attn = _decode_attn_mla_paged if cfg.mla else _decode_attn_dense_paged
 
-        x, (c_new, r_new) = lax.scan(
-            body, x, (params["layers"], cache["c_kv"], cache["k_rope"]))
-        new_cache = dict(cache, c_kv=c_new, k_rope=r_new, lens=lens + adv)
-    else:
-        def body(h, layer):
-            lp, k_a, v_a = layer
-            with jax.named_scope("decode_attn"):
-                a, k_a, v_a = _decode_attn_dense_paged(
-                    lp["attn"], L.rms_norm(lp["ln1"], h, cfg), k_a, v_a,
-                    tables, lens, ok, cfg)
-            h = h + a
-            hh = L.rms_norm(lp["ln2"], h, cfg)
-            with jax.named_scope("mlp"):
-                f = L.moe(lp["moe"], hh, cfg) if cfg.is_moe else \
-                    L.mlp(lp["mlp"], hh, cfg)
-            return h + f, (k_a, v_a)
+    def body(carry, layer):
+        h, a_arena, b_arena = carry
+        lp, li = layer
+        with jax.named_scope("decode_attn"):
+            a, a_arena, b_arena = attn(
+                lp["attn"], L.rms_norm(lp["ln1"], h, cfg), a_arena, b_arena,
+                li, tables, lens, ok, cfg)
+        h = h + a
+        hh = L.rms_norm(lp["ln2"], h, cfg)
+        with jax.named_scope("mlp"):
+            f = L.moe(lp["moe"], hh, cfg) if cfg.is_moe else \
+                L.mlp(lp["mlp"], hh, cfg)
+        return (h + f, a_arena, b_arena), None
 
-        x, (k_new, v_new) = lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
-        new_cache = dict(cache, k=k_new, v=v_new, lens=lens + adv)
+    n_layers = cache[keys[0]].shape[0]
+    (x, a_new, b_new), _ = lax.scan(
+        body, (x, cache[keys[0]], cache[keys[1]]),
+        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
+    new_cache = dict(cache, lens=lens + adv)
+    new_cache.update(zip(keys, (a_new, b_new)))
 
     with jax.named_scope("lm_head"):
         x = L.rms_norm(params["final_norm"], x, cfg)

@@ -194,6 +194,62 @@ def test_paged_generate_token_identity(lane):
     assert 0 < pag.pool.peak_in_use <= pag.pool.n_blocks
 
 
+@pytest.mark.parametrize("lane", LANES)
+def test_paged_decode_in_place_matches_per_layer_slices(lane):
+    """The decode step's layer scan carries the stacked arenas and
+    writes/reads them at ``(layer, ...)``; it must equal, bit for bit,
+    the per-layer ``xs``/``ys`` formulation (``paged_reference``) in
+    logits, both arenas and ``lens``, with every dropped write still
+    dropped: an inactive row, a sentinel table entry under a row's
+    write position, and a row at ``max_len``."""
+    from paged_reference import decode_step_xs_ys
+    cfg = _cfg(lane, kv_posit="posit16")
+    params = _params(cfg)
+    rng = np.random.default_rng(14)
+    eng = Engine(cfg, params, max_len=16, seed=0, paged=True, block_size=4)
+    cache, _, _ = eng.prefill(
+        [rng.integers(1, cfg.vocab, n).tolist() for n in (5, 7, 6, 9)],
+        reserve_tokens=4)
+    nb = cache["k_rope" if cfg.mla else "k"].shape[1]
+    lens = np.asarray(cache["lens"]).copy()
+    tables = np.asarray(cache["block_tables"]).copy()
+    w = tables.shape[1]
+    tables[2, (lens[2] // 4) % w] = nb       # row 2 writes through a sentinel
+    lens[3] = 16                             # row 3 sits at max_len
+    cache = dict(cache, lens=jnp.asarray(lens),
+                 block_tables=jnp.asarray(tables))
+    active = jnp.asarray([True, False, True, True])   # row 1 inactive
+
+    step = jax.jit(lambda c, t: T.decode_step(params, c, t, cfg,
+                                              active=active))
+    ref_step = jax.jit(lambda c, t: decode_step_xs_ys(params, c, t, cfg,
+                                                      active))
+    got, ref = cache, cache
+    for tok in rng.integers(1, cfg.vocab, (3, 4)):
+        tok = jnp.asarray(tok, jnp.int32)
+        logits, got = step(got, tok)
+        ref_logits, ref = ref_step(ref, tok)
+        np.testing.assert_array_equal(np.asarray(logits),
+                                      np.asarray(ref_logits))
+    assert got.keys() == ref.keys()
+    for key in got:
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(ref[key]), err_msg=key)
+    # the dropped writes: rows 1 and 3 left the arena as it was where
+    # their next write would have landed, and row 2's sentinel block
+    # took nothing
+    keys = ("c_kv", "k_rope") if cfg.mla else ("k", "v")
+    for key in keys:
+        before = np.asarray(cache[key])
+        after = np.asarray(got[key])
+        live = np.unique(tables[[0, 2]][tables[[0, 2]] < nb])
+        untouched = np.setdiff1d(np.arange(nb), live)
+        np.testing.assert_array_equal(after[:, untouched],
+                                      before[:, untouched], err_msg=key)
+    np.testing.assert_array_equal(np.asarray(got["lens"]),
+                                  lens + np.asarray(active, np.int32) * 3)
+
+
 def test_paged_generate_token_identity_posit_kv():
     """The paged layout must compose with the posit KV codec: patterns
     round-trip through arena blocks bit-identically."""

@@ -7,15 +7,22 @@ Mosaic cannot lower, or a tile that overflows VMEM fails here instead of
 on the chip.  The topology is described inside a fixture (never at
 import): only the worker that runs this file loads the TPU library.
 """
+import dataclasses
+import re
+
 import pytest
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro import configs
 from repro.core.types import POSIT16
 from repro.kernels import ops
 from repro.kernels import posit_paged_attn as ppa
+from repro.models import get_family
+from repro.models import transformer as T
+from repro.runtime.engine import Engine
 
 
 @pytest.fixture(scope="module")
@@ -115,3 +122,95 @@ def test_kernel_body_does_not_depend_on_the_call_path(one_chip, monkeypatch,
         jax.config.update("jax_include_full_tracebacks_in_locations", was)
     assert "tpu_custom_call" in first
     assert first == second
+
+
+def _loop_ops(hlo: str):
+    """(opcode, dtype, dims, first operand's dims) of every instruction
+    that a while loop's body runs, in it or in a computation it calls
+    (fusions, nested loops), from optimized HLO text; size-1 dims
+    dropped."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.strip() == "}":
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    inst = re.compile(
+        r"%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(%?([\w.\-]*)")
+    calls = re.compile(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)")
+    todo = [m.group(1) for lines in comps.values() for line in lines
+            for m in re.finditer(r"body=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        dims = {}
+        for line in comps[name]:
+            todo += calls.findall(line)
+            op = inst.search(line)
+            if op:
+                dims[op.group(1)] = tuple(
+                    int(d) for d in op.group(3).split(",") if d and d != "1")
+                yield (op.group(4), op.group(2), dims[op.group(1)],
+                       dims.get(op.group(5)))
+
+
+@pytest.mark.parametrize("lane", ["mla", "dense"])
+def test_paged_decode_scan_keeps_the_arena_in_place(one_chip, monkeypatch,
+                                                    lane):
+    """A decode scan at the served arena (16 rows x 2048, block 16,
+    posit16) and published widths, two layers.  No loop copies, slices
+    or update-slices the stacked arena, nor update-slices one layer of
+    it: the writes scatter into the carried arena in place.  No loop
+    gathers straight off the stacked arena either: on a v5e that
+    two-index gather ran up to 20x slower than slicing the layer's
+    arena out (read only) and gathering from the slice.  The compiled
+    temporaries are at least one arena below the per-layer
+    ``xs``/``ys`` formulation's (``paged_reference``), which copies the
+    stacked arena every step and restacks every layer."""
+    from paged_reference import decode_step_xs_ys
+    arch = "minicpm3-4b" if lane == "mla" else "phi3-medium-14b"
+    cfg = dataclasses.replace(configs.get_config(arch), n_layers=2,
+                              vocab=4096, kv_posit="posit16",
+                              compute_dtype="bfloat16")
+    b, max_len, bs = 16, 2048, 16
+    eng = Engine(cfg, None, max_len=max_len, paged=True, block_size=bs)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: get_family(cfg).init_params(
+        jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(lambda: T.init_paged_cache(
+        cfg, b, max_len, bs, b * eng.table_width)))
+    args = on_chip((jax.ShapeDtypeStruct((b,), jnp.int32),
+                    jax.ShapeDtypeStruct((2,), jnp.uint32),
+                    jax.ShapeDtypeStruct((b,), jnp.bool_)))
+    leaves = [pool[k] for k in (("c_kv", "k_rope") if cfg.mla
+                                else ("k", "v"))]
+    stacked = {leaf.shape for leaf in leaves}
+    layer = {leaf.shape[1:] for leaf in leaves}
+
+    def compile_scan():
+        compiled = eng._chunk_fn(4).lower(params, pool, *args).compile()
+        bad = [(op, dims) for op, dt, dims, src in
+               _loop_ops(compiled.as_text()) if dt == "u16" and (
+                   op in ("copy", "dynamic-slice", "dynamic-update-slice")
+                   and dims in stacked
+                   or op == "dynamic-update-slice" and dims in layer
+                   or op == "gather" and src in stacked)]
+        return compiled.memory_analysis().temp_size_in_bytes, bad
+
+    temp, bad = compile_scan()
+    monkeypatch.setattr(T, "_decode_step_paged", decode_step_xs_ys)
+    ref_temp, ref_bad = compile_scan()
+    assert bad == [], bad
+    assert ref_bad, "the per-layer formulation's arena moves went unseen"
+    one_arena = max(leaf.size * leaf.dtype.itemsize for leaf in leaves)
+    assert ref_temp - temp >= one_arena, (ref_temp, temp, one_arena)
