@@ -1,6 +1,8 @@
-"""phi3-medium-14b [dense]: RoPE SwiGLU GQA [arXiv:2404.14219; unverified].
+"""phi3-medium-14b [dense]: RoPE SwiGLU GQA [arXiv:2404.14219].
 
-40L d_model=5120 40H (GQA kv=10) d_ff=17920 vocab=100352.
+40L d_model=5120 40H (GQA kv=10) d_ff=17920 vocab=32064, as published in
+https://huggingface.co/microsoft/Phi-3-medium-4k-instruct/blob/main/config.json
+(its ``sliding_window`` is not applied: attention is full).
 """
 from repro.models.config import ModelConfig
 
@@ -13,7 +15,7 @@ CONFIG = ModelConfig(
     n_kv_heads=10,
     head_dim=128,
     d_ff=17920,
-    vocab=100352,
+    vocab=32064,
     act="silu",
     rope_theta=10000.0,
     compute_dtype="bfloat16",

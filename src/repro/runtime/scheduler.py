@@ -985,6 +985,26 @@ class Scheduler:
                                          jnp.asarray(done_mask))
         return completions
 
+    def _decode_reads(self, decode_active) -> dict:
+        """The round's decode-attention counters, from host state alone:
+        ``decode_steps`` the dispatch runs (0 when no row decodes);
+        ``kv_live_positions``, the positions those steps attend summed
+        over the decoding rows (``lens + 1``, one more each step, for
+        every step the device runs, a row that finishes early included;
+        at most the window where one is set); ``kv_read_positions``, the
+        positions the decode attention reads, which is every row's whole
+        table (``n_slots x table_width x block_size``) each step on the
+        gather path and the fused table walk alike."""
+        n = self.chunk_size if decode_active.any() else 0
+        lens = np.array([s.lens for s, a in zip(self._slots, decode_active)
+                         if a], np.int64)
+        live = lens[:, None] + 1 + np.arange(n)
+        if self._window:
+            live = np.minimum(live, self._window)
+        return dict(decode_steps=n, kv_live_positions=int(live.sum()),
+                    kv_read_positions=n * self.n_slots * self.table_width
+                    * self.block_size)
+
     def _step_chunked(self, rnd):
         """One chunked scheduling round: admit (allocation only) ->
         extend/COW/sanitize for the combined prefill+decode write spans
@@ -1009,6 +1029,7 @@ class Scheduler:
                 nv[i] = n
                 chunk[i, :n] = s.req.prompt[s.cursor:s.cursor + n]
             dispatch = decode_active.any() or nv.any()
+            rnd.set(**self._decode_reads(decode_active))
             if dispatch:
                 self._ensure_blocks()
                 if self.sanitize:
@@ -1111,7 +1132,10 @@ class Scheduler:
         ``sched.emit``, ``sched.first_tokens``, ``sched.retire``) and
         whose counters say what it did; ``compiled`` is the engine's
         new compiles in the round, non-zero only on a round that
-        compiled.  ``stats`` reads the round spans' p50/p99."""
+        compiled.  A chunked round also counts its decode attention's
+        work (``decode_steps``, ``kv_live_positions``,
+        ``kv_read_positions``; :meth:`_decode_reads`).  ``stats`` reads
+        the round spans' p50/p99."""
         with spans.span("sched.round", sched=self.sched_id,
                         round=self._round) as rnd:
             before = (self.n_admitted, self.n_retired, self.prefill_tokens,

@@ -8,7 +8,8 @@ with ``Scheduler.stats`` and with the tokens returned, ``compiled``
 must mark exactly the rounds that compiled, the ring must stay bounded,
 and under ``jax.profiler`` the same spans must land in the trace's host
 plane with the same nesting.  ``Scheduler.progress`` must agree with
-the benchmark's stopgap reading of the slot state.
+the benchmark's stopgap reading of the slot state.  A chunked round's
+decode-read counters must equal a hand count on each attention lane.
 """
 import importlib.util
 import types
@@ -222,3 +223,51 @@ def test_progress_agrees_with_the_slot_reading(model):
         assert sched.progress() == \
             stopgap(types.SimpleNamespace(sched=sched))
     assert rounds > 3
+
+
+# per round: (decode_steps, kv_live_positions, kv_read_positions) for
+# requests A (prompt 3, 2 tokens), B (prompt 5, 9 tokens) and C (prompt
+# 4, 3 tokens) on 2 slots of 64 positions, chunk 4:
+#   0: A and B admitted; A's prompt completes, B's first 4 -> no decode
+#   1: A decodes from lens 3 (attends 4, 5, 6, 7) and finishes after
+#      its first step; B's last prompt token
+#   2: C admitted to A's slot, prefills; B decodes from lens 5 (6..9)
+#   3: B from lens 9 (10..13) and C from lens 4 (5..8), C finishing
+#      mid-round
+# Each decode round reads 4 steps x 2 rows x 64 positions; with a
+# window of 8 a row attends at most 8 and reads its ring of 3 blocks.
+FULL = [(0, 0, 0), (4, 22, 512), (4, 30, 512), (4, 46 + 26, 512)]
+DECODE_READS = {
+    "gqa": ("phi3-medium-14b", 0, FULL),
+    "mla": ("minicpm3-4b", 0, FULL),
+    "window": ("phi3-medium-14b", 8,
+               [(0, 0, 0), (4, 22, 96), (4, 6 + 7 + 8 + 8, 96),
+                (4, 4 * 8 + 26, 96)]),
+}
+
+
+@pytest.mark.parametrize("lane", sorted(DECODE_READS))
+def test_decode_read_counters_match_a_hand_count(lane):
+    """``decode_steps``, ``kv_live_positions`` and ``kv_read_positions``
+    on a chunked round, on each attention lane: every step the device
+    runs counts, a row's finished steps included, and the gather reads
+    every row's whole table each step."""
+    arch, window, want = DECODE_READS[lane]
+    cfg = configs.get_config(arch).reduced(compute_dtype="float32",
+                                           sliding_window=window or None)
+    params = get_family(cfg).init_params(jax.random.PRNGKey(0), cfg)
+    eng = Engine(cfg, params, max_len=64, paged=True, block_size=4)
+    sched = Scheduler(eng, n_slots=2, chunk_size=4, chunked_prefill=True)
+    rng = np.random.default_rng(0)
+    for plen, gen in ((3, 2), (5, 9), (4, 3)):
+        sched.submit(rng.integers(1, 256, plen).tolist(), gen)
+    done = sched.run(max_rounds=20)
+    assert sorted(len(c.tokens) for c in done.values()) == [2, 3, 9]
+    rounds = sorted((s for s in _mine(sched) if s.name == "sched.round"),
+                    key=lambda s: s.ids["round"])
+    got = [tuple(r.counters[k] for k in ("decode_steps", "kv_live_positions",
+                                         "kv_read_positions"))
+           for r in rounds]
+    assert got == want
+    assert [r.counters["admitted"] for r in rounds] == [2, 0, 1, 0]
+    assert [r.counters["retired"] for r in rounds] == [0, 1, 0, 2]
