@@ -20,6 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.core.convert import posit_to_f32
@@ -591,10 +592,64 @@ def paged_apos(tables, lens, block_size: int, n_blocks: int, *,
     return jnp.where(live, apos, -1)
 
 
+# The bounded decode gather: a decode step reads only the table columns
+# that cover its decoding rows' longest content, rounded up to whole
+# width steps of PAGED_READ_STEP positions.  One ``lax.switch`` branch per
+# width step, all in the one compiled program, picks the width on the
+# device each step: no host sync, no compile per width.
+PAGED_READ_STEP = 512
+
+
+def paged_read_step(table_width: int, block_size: int, *,
+                    window: int = 0) -> int:
+    """Positions per width step of the bounded decode gather:
+    ``PAGED_READ_STEP`` in whole blocks, and the whole table on the
+    window lane, whose ring is always read whole."""
+    if paged_is_window_lane(window, block_size, table_width):
+        return table_width * block_size
+    return max(1, PAGED_READ_STEP // block_size) * block_size
+
+
+def paged_read_positions(extent, table_width: int, block_size: int, *,
+                         window: int = 0):
+    """Positions per row the gather path reads in a decode step whose
+    decoding rows reach ``extent`` positions (their largest ``lens + 1``;
+    0 when none decodes): ``extent`` rounded up to whole width steps, at
+    least one, at most the whole table.  The one rule for the device's
+    branch choice and the scheduler's ``kv_read_positions``: it takes
+    host integers and NumPy arrays as well as traced values."""
+    s = paged_read_step(table_width, block_size, window=window)
+    xp = jnp if isinstance(extent, jax.Array) else np
+    return xp.minimum(xp.maximum(-(-extent // s), 1) * s,
+                      table_width * block_size)
+
+
+def _paged_read_switch(attend, tables, apos, read_steps, block_size: int,
+                       window: int):
+    """``attend(tables, apos)`` over the first ``read_steps`` width steps
+    of the table (:func:`paged_read_positions`): one ``lax.switch``
+    branch per width step, each the same math on a narrower slice of
+    ``tables`` and ``apos``.  ``read_steps`` None, or a table of one
+    width step, reads the whole table."""
+    w = tables.shape[1]
+    step = paged_read_step(w, block_size, window=window) // block_size
+    n = -(-w // step)
+    if read_steps is None or n == 1:
+        return attend(tables, apos)
+
+    def branch(j):
+        c = min(j * step, w)
+        return lambda t, a: attend(t[:, :c], a[:, :c * block_size])
+
+    return lax.switch(read_steps - 1, [branch(j) for j in range(1, n + 1)],
+                      tables, apos)
+
+
 def decode_attention_paged(q, k_arena, v_arena, tables, lens, *,
                            cfg: ModelConfig, kv_posit: Optional[str] = None,
                            window: int = 0, kernel: str = "gather",
-                           interpret: Optional[bool] = None):
+                           interpret: Optional[bool] = None,
+                           read_steps=None):
     """Paged decode attention straight off the block tables.
 
     q: (B, 1, H, D); arenas (n_blocks, bs, G, D[v]) posit patterns or
@@ -609,6 +664,12 @@ def decode_attention_paged(q, k_arena, v_arena, tables, lens, *,
     paths consume :func:`paged_apos`, so sentinel-backed slots are
     masked identically and a fully-sentinel row (preempted slot)
     returns exact zeros on either path.
+
+    ``read_steps`` (traced scalar, gather path only) bounds the gather
+    to the table's first width steps (:func:`paged_read_positions` of
+    the step's extent); rows longer than that read a truncated cache,
+    so the caller gives a bound that covers every row it keeps.  None
+    reads the whole table; the fused kernel always walks it whole.
     """
     b, _, h, d = q.shape
     nb, bs, g = k_arena.shape[0], k_arena.shape[1], k_arena.shape[2]
@@ -623,26 +684,32 @@ def decode_attention_paged(q, k_arena, v_arena, tables, lens, *,
         return out.reshape(b, 1, h, -1).astype(q.dtype)
     if kernel != "gather":
         raise ValueError(f"unknown paged decode kernel {kernel!r}")
-    return decode_attention(
-        q, paged_gather(k_arena, tables), paged_gather(v_arena, tables),
-        lens + 1, cfg=cfg, kv_posit=kv_posit, window=window, apos=apos)
+
+    def attend(t, a):
+        return decode_attention(
+            q, paged_gather(k_arena, t), paged_gather(v_arena, t),
+            lens + 1, cfg=cfg, kv_posit=kv_posit, window=window, apos=a)
+
+    return _paged_read_switch(attend, tables, apos, read_steps, bs, window)
 
 
 def decode_attention_paged_mla(q_lat_eff, q_rope, c_arena, r_arena, tables,
                                lens, *, cfg: ModelConfig,
                                kv_posit: Optional[str] = None,
                                kernel: str = "gather",
-                               interpret: Optional[bool] = None):
+                               interpret: Optional[bool] = None,
+                               read_steps=None):
     """Absorbed-matrix MLA paged decode: latent-space attention off the
     block tables; returns the latent context (B, H, rank) f32 (the
     caller applies ``wuv``).
 
-    Same kernel dispatch contract as :func:`decode_attention_paged`;
-    the fused kernel concatenates the latent (``c``) and decoupled-RoPE
-    (``r``) blocks in VMEM and uses the latent block as V.  The gather
-    fallback carries the same all-masked guard as
-    :func:`decode_attention`: a fully-masked row yields zeros, not the
-    uniform garbage average ``jax.nn.softmax`` would produce.
+    Same kernel dispatch contract, and the same ``read_steps`` bound on
+    the gather path, as :func:`decode_attention_paged`; the fused kernel
+    concatenates the latent (``c``) and decoupled-RoPE (``r``) blocks in
+    VMEM and uses the latent block as V.  The gather fallback carries
+    the same all-masked guard as :func:`decode_attention`: a
+    fully-masked row yields zeros, not the uniform garbage average
+    ``jax.nn.softmax`` would produce.
     """
     b, h, _ = q_lat_eff.shape
     nb, bs = c_arena.shape[0], c_arena.shape[1]
@@ -657,22 +724,26 @@ def decode_attention_paged_mla(q_lat_eff, q_rope, c_arena, r_arena, tables,
             scale=scale, interpret=interpret)
     if kernel != "gather":
         raise ValueError(f"unknown paged decode kernel {kernel!r}")
-    c = paged_gather(c_arena, tables)                 # (B, W*bs, rank)
-    r = paged_gather(r_arena, tables)
-    if kv_posit:
-        with jax.named_scope("kv_decode"):
-            c = posit_to_f32(c, pcfg(kv_posit))
-            r = posit_to_f32(r, pcfg(kv_posit))
-    c = c.astype(jnp.float32)
-    r = r.astype(jnp.float32)
-    scores = jnp.einsum("bhr,btr->bht", q_lat_eff.astype(jnp.float32), c)
-    scores += jnp.einsum("bhd,btd->bht", q_rope.astype(jnp.float32), r)
-    valid = (apos >= 0) & (apos <= lens[:, None])     # content [0, lens]
-    scores = jnp.where(valid[:, None, :], scores * scale, _NEG)
-    m = scores.max(-1, keepdims=True)
-    p = jnp.where(valid[:, None, :], jnp.exp(scores - m), 0.0)
-    probs = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
-    return jnp.einsum("bht,btr->bhr", probs, c)       # (B, H, rank)
+
+    def attend(t, a):
+        c = paged_gather(c_arena, t)                  # (B, W*bs, rank)
+        r = paged_gather(r_arena, t)
+        if kv_posit:
+            with jax.named_scope("kv_decode"):
+                c = posit_to_f32(c, pcfg(kv_posit))
+                r = posit_to_f32(r, pcfg(kv_posit))
+        c = c.astype(jnp.float32)
+        r = r.astype(jnp.float32)
+        scores = jnp.einsum("bhr,btr->bht", q_lat_eff.astype(jnp.float32), c)
+        scores += jnp.einsum("bhd,btd->bht", q_rope.astype(jnp.float32), r)
+        valid = (a >= 0) & (a <= lens[:, None])       # content [0, lens]
+        scores = jnp.where(valid[:, None, :], scores * scale, _NEG)
+        m = scores.max(-1, keepdims=True)
+        p = jnp.where(valid[:, None, :], jnp.exp(scores - m), 0.0)
+        probs = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+        return jnp.einsum("bht,btr->bhr", probs, c)   # (B, H, rank)
+
+    return _paged_read_switch(attend, tables, apos, read_steps, bs, 0)
 
 
 def paged_cache_update(arena, upd, tables, pos, ok, *, window: int = 0,

@@ -746,7 +746,7 @@ def _layer_arena(arena, layer):
 
 
 def _decode_attn_dense_paged(p, x, k_arena, v_arena, layer, tables, lens,
-                             ok, cfg: ModelConfig):
+                             ok, cfg: ModelConfig, read_steps=None):
     """Paged dense/GQA decode: per-row write position ``lens[b]`` into the
     row's block, then attention straight off the block tables
     (``cfg.paged_attn_kernel`` picks the fused Pallas table walk or the
@@ -754,7 +754,9 @@ def _decode_attn_dense_paged(p, x, k_arena, v_arena, layer, tables, lens,
     (content-relative ``lens``) and softmax math as the linear lane —
     only the storage addressing differs, so the scores over valid
     positions are identical.  ``layer`` indexes the stacked
-    ``(L, n_blocks, ...)`` arenas; None takes one layer's arenas."""
+    ``(L, n_blocks, ...)`` arenas; None takes one layer's arenas.
+    ``read_steps`` bounds the gather to the step's extent
+    (``layers.decode_attention_paged``)."""
     b = x.shape[0]
     window = _paged_window(cfg)
     q = L.dense(p["wq"], x, cfg).reshape(b, 1, cfg.n_heads, cfg.head_dim)
@@ -772,16 +774,16 @@ def _decode_attn_dense_paged(p, x, k_arena, v_arena, layer, tables, lens,
     out = L.decode_attention_paged(
         q, _layer_arena(k_arena, layer), _layer_arena(v_arena, layer),
         tables, lens, cfg=cfg, kv_posit=cfg.kv_posit, window=window,
-        kernel=cfg.paged_attn_kernel)
+        kernel=cfg.paged_attn_kernel, read_steps=read_steps)
     out = out.reshape(b, 1, cfg.n_heads * cfg.head_dim)
     return L.dense(p["wo"], out, cfg), k_arena, v_arena
 
 
 def _decode_attn_mla_paged(p, x, c_arena, r_arena, layer, tables, lens, ok,
-                           cfg: ModelConfig):
+                           cfg: ModelConfig, read_steps=None):
     """Paged absorbed-matrix MLA decode (row-local positions);
     ``cfg.paged_attn_kernel`` picks the fused latent-space table walk
-    or the gather+jnp reference.  ``layer`` as in
+    or the gather+jnp reference.  ``layer`` and ``read_steps`` as in
     :func:`_decode_attn_dense_paged`."""
     b = x.shape[0]
     q_lat = L.rms_norm(p["q_norm"], L.dense(p["wdq"], x, cfg), cfg)
@@ -809,7 +811,8 @@ def _decode_attn_mla_paged(p, x, c_arena, r_arena, layer, tables, lens, ok,
     ctx_lat = L.decode_attention_paged_mla(
         q_lat_eff, q_rope, _layer_arena(c_arena, layer),
         _layer_arena(r_arena, layer), tables, lens, cfg=cfg,
-        kv_posit=cfg.kv_posit, kernel=cfg.paged_attn_kernel)
+        kv_posit=cfg.kv_posit, kernel=cfg.paged_attn_kernel,
+        read_steps=read_steps)
     wuv = L.maybe_dequant(p["wuv"]["w"], cfg).reshape(
         cfg.kv_lora_rank, cfg.n_heads, cfg.v_head_dim)
     out = jnp.einsum("bhr,rhv->bhv", ctx_lat, wuv)
@@ -830,7 +833,13 @@ def _decode_step_paged(params, cache, token, cfg: ModelConfig, active):
     scan as ``xs``/``ys`` instead rewrites each layer's slice around
     the scatter and restacks it into a fresh arena, which the caller's
     step scan then copies back: a whole-arena copy every decode
-    step."""
+    step.
+
+    Every layer's gather reads only the width steps that cover the
+    decoding rows' longest content (``layers.paged_read_positions`` of
+    their largest ``lens + 1``), chosen once per step on the device.
+    Rows that do not decode (prefilling, idle) do not widen the read;
+    their outputs are discarded."""
     from repro.core.tracing import is_tracer
 
     b = token.shape[0]
@@ -852,6 +861,11 @@ def _decode_step_paged(params, cache, token, cfg: ModelConfig, active):
 
     keys = ("c_kv", "k_rope") if cfg.mla else ("k", "v")
     attn = _decode_attn_mla_paged if cfg.mla else _decode_attn_dense_paged
+    w, bs = tables.shape[1], cache[keys[0]].shape[2]
+    window = _paged_window(cfg)
+    extent = jnp.max(jnp.where(ok, lens + 1, 0))
+    read_steps = -(-L.paged_read_positions(extent, w, bs, window=window)
+                   // L.paged_read_step(w, bs, window=window))
 
     def body(carry, layer):
         h, a_arena, b_arena = carry
@@ -859,7 +873,7 @@ def _decode_step_paged(params, cache, token, cfg: ModelConfig, active):
         with jax.named_scope("decode_attn"):
             a, a_arena, b_arena = attn(
                 lp["attn"], L.rms_norm(lp["ln1"], h, cfg), a_arena, b_arena,
-                li, tables, lens, ok, cfg)
+                li, tables, lens, ok, cfg, read_steps)
         h = h + a
         hh = L.rms_norm(lp["ln2"], h, cfg)
         with jax.named_scope("mlp"):

@@ -135,6 +135,7 @@ import jax.numpy as jnp
 
 from repro.compress import kvcache as kvc
 from repro.models import get_family
+from repro.models import layers as L
 from . import spans
 from .engine import Engine, sample_token
 
@@ -992,18 +993,27 @@ class Scheduler:
         over the decoding rows (``lens + 1``, one more each step, for
         every step the device runs, a row that finishes early included;
         at most the window where one is set); ``kv_read_positions``, the
-        positions the decode attention reads, which is every row's whole
-        table (``n_slots x table_width x block_size``) each step on the
-        gather path and the fused table walk alike."""
+        positions the decode attention reads.  On the gather path a step
+        reads, for each of the ``n_slots`` rows, the width that
+        ``layers.paged_read_positions`` gives for the step's extent (the
+        decoding rows' largest ``lens + 1``): the rule the device
+        branches on.  The fused table walk reads every row's whole table
+        (``table_width x block_size``) each step."""
         n = self.chunk_size if decode_active.any() else 0
         lens = np.array([s.lens for s, a in zip(self._slots, decode_active)
                          if a], np.int64)
-        live = lens[:, None] + 1 + np.arange(n)
+        steps = np.arange(n)
+        live = lens[:, None] + 1 + steps
         if self._window:
             live = np.minimum(live, self._window)
+        w, bs = self.table_width, self.block_size
+        if self.engine.cfg.paged_attn_kernel == "fused" or not n:
+            width = np.full(n, w * bs)
+        else:
+            width = L.paged_read_positions(lens.max() + 1 + steps, w, bs,
+                                           window=self._window)
         return dict(decode_steps=n, kv_live_positions=int(live.sum()),
-                    kv_read_positions=n * self.n_slots * self.table_width
-                    * self.block_size)
+                    kv_read_positions=self.n_slots * int(width.sum()))
 
     def _step_chunked(self, rnd):
         """One chunked scheduling round: admit (allocation only) ->

@@ -18,6 +18,11 @@ must be invisible to the numbers.  Pinned three ways:
   ``exp(_NEG - _NEG) == 1`` path returned a uniform average of garbage
   — on the linear path, the paged gather path and the fused kernel.
 
+The bounded gather path (a decode step reads only the width steps of
+``layers.PAGED_READ_STEP`` positions that its decoding rows reach) must
+match the whole-table form for every decoding row, on the dense and MLA
+lanes, and the model's step must take the narrowest covering branch.
+
 Everything runs the kernel in Pallas interpret mode (CPU container);
 the CI fast lane executes this file explicitly.
 """
@@ -34,6 +39,7 @@ from repro.kernels import ops as kops
 from repro.kernels.posit_paged_attn import paged_decode_kv_bytes
 from repro.models import get_family
 from repro.models import layers as L
+from repro.models import transformer as T
 from repro.runtime.engine import Engine
 from repro.runtime.scheduler import Scheduler
 
@@ -304,3 +310,169 @@ def test_fused_moves_strictly_fewer_bytes(lane, kv):
             dataclasses.replace(cfg, kv_posit=None), table_width=8,
             block_size=4, kernel="fused") // 2
         assert fused * 2 == f16_read
+
+
+# ---------------------------------------------------------------------------
+# bounded decode gather: a step reads the width steps its decoding rows reach
+# ---------------------------------------------------------------------------
+
+WIDE_LEN, WIDE_BS = 2048, 16          # 128 table columns: 4 width steps
+STEP_COLS = L.PAGED_READ_STEP // WIDE_BS
+# rows 0-2 decode with lens + 1 at 511, 512 and 513; row 3 is dead (all
+# sentinel); row 4 does not decode and is longer than every decoding row
+WIDE_LENS = [510, 511, 512, 0, 1500]
+DECODING = [0, 1, 2]
+
+
+def _wide_tables():
+    used = [0 if i == 3 else -(-(n + 1) // WIDE_BS)
+            for i, n in enumerate(WIDE_LENS)]
+    nb = sum(used)
+    tables = np.full((len(used), WIDE_LEN // WIDE_BS), nb, np.int32)
+    for i, u in enumerate(used):
+        tables[i, :u] = sum(used[:i]) + np.arange(u)
+    return jnp.asarray(tables), nb
+
+
+def _wide_layer(lane, rng, kv="posit16"):
+    """One layer's attention at the wide table: ``run(read_steps)`` is
+    the paged gather path, ``ref`` today's whole-table form."""
+    cfg = dataclasses.replace(_cfg(lane), kv_posit=kv)
+    tables, nb = _wide_tables()
+    lens = jnp.asarray(WIDE_LENS, jnp.int32)
+    b, bs = len(WIDE_LENS), WIDE_BS
+    if lane == "mla":
+        c_arena = _arena(rng, (nb, bs, cfg.kv_lora_rank), kv)
+        r_arena = _arena(rng, (nb, bs, cfg.qk_rope_dim), kv)
+        qe = jnp.asarray(rng.normal(size=(b, cfg.n_heads, cfg.kv_lora_rank)),
+                         jnp.float32)
+        qr = jnp.asarray(rng.normal(size=(b, cfg.n_heads, cfg.qk_rope_dim)),
+                         jnp.float32)
+
+        def run(read_steps):
+            return L.decode_attention_paged_mla(
+                qe, qr, c_arena, r_arena, tables, lens, cfg=cfg,
+                kv_posit=kv, read_steps=read_steps)
+        return run, run(None)
+    g, h, d = cfg.n_kv_heads, cfg.n_heads, cfg.head_dim
+    k_arena = _arena(rng, (nb, bs, g, d), kv)
+    v_arena = _arena(rng, (nb, bs, g, d), kv)
+    q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
+
+    def run(read_steps):
+        return L.decode_attention_paged(
+            q, k_arena, v_arena, tables, lens, cfg=cfg, kv_posit=kv,
+            read_steps=read_steps)
+    ref = L.decode_attention(
+        q, L.paged_gather(k_arena, tables), L.paged_gather(v_arena, tables),
+        lens + 1, cfg=cfg, kv_posit=kv,
+        apos=L.paged_apos(tables, lens, bs, nb))
+    return run, ref
+
+
+@pytest.mark.parametrize("lane", ["dense", "mla"])
+def test_bounded_gather_matches_the_whole_table(lane):
+    """At the covering width (two steps for an extent of 513) every
+    decoding row equals today's whole-table form to f32 rounding, and
+    the dead row stays exact zeros.  One step short, the 513-position
+    row loses its last position while the rows that fit are unchanged:
+    the bound really narrows the read."""
+    run, ref = _wide_layer(lane, np.random.default_rng(20))
+    k = -(-max(WIDE_LENS[i] + 1 for i in DECODING) // L.PAGED_READ_STEP)
+    assert k == 2
+    got = run(jnp.int32(k))
+    np.testing.assert_allclose(got[np.array(DECODING)],
+                               ref[np.array(DECODING)],
+                               atol=2e-6, rtol=1e-5)
+    assert float(jnp.abs(got[3]).max()) == 0.0
+    short = run(jnp.int32(k - 1))
+    np.testing.assert_allclose(short[:2], ref[:2], atol=2e-6, rtol=1e-5)
+    assert float(jnp.abs(short[2] - ref[2]).max()) > 1e-3
+    np.testing.assert_array_equal(run(jnp.int32(4)), run(None))
+
+
+@pytest.mark.parametrize("extent, cols", [(0, 1), (1, 1), (511, 1),
+                                          (512, 1), (513, 2), (1501, 3),
+                                          (2048, 4), (5000, 4)])
+def test_read_positions_round_up_to_width_steps(extent, cols):
+    """The one rule: ``ceil(extent / step)`` steps, at least one, at most
+    the table, the same on the host and traced."""
+    w, bs = WIDE_LEN // WIDE_BS, WIDE_BS
+    want = cols * L.PAGED_READ_STEP
+    assert L.paged_read_positions(extent, w, bs) == want
+    assert int(jax.jit(lambda e: L.paged_read_positions(e, w, bs))(
+        jnp.int32(extent))) == want
+    # the window lane's ring, and a table of one step, are read whole
+    ring = L.paged_window_blocks(600, bs)
+    assert L.paged_read_positions(extent, ring, bs, window=600) == ring * bs
+    assert L.paged_read_positions(extent, 8, bs) == 8 * bs
+
+
+def _wide_cache(cfg, rng):
+    tables, nb = _wide_tables()
+    cache = T.init_paged_cache(cfg, len(WIDE_LENS), WIDE_LEN, WIDE_BS, nb)
+    keys = ("c_kv", "k_rope") if cfg.mla else ("k", "v")
+    for key in keys:
+        cache[key] = _arena(rng, cache[key].shape, cfg.kv_posit)
+    return dict(cache, block_tables=tables,
+                lens=jnp.asarray(WIDE_LENS, jnp.int32))
+
+
+@pytest.mark.parametrize("lane", ["dense", "mla"])
+def test_decode_step_reads_the_covering_width_steps(lane, monkeypatch):
+    """Through the model's paged decode step: every gather of the step
+    reads ``ceil(extent / step)`` width steps, ``extent`` being the
+    largest ``lens + 1`` of the decoding rows alone (the longer
+    non-decoding row and the dead row do not widen it), and the
+    decoding rows' logits equal the whole-table reference step's.  One
+    compiled step serves every width."""
+    from paged_reference import decode_step_xs_ys
+    cfg = _cfg(lane, kv_posit="posit16")
+    params = _params(cfg)
+    rng = np.random.default_rng(21)
+    cache = _wide_cache(cfg, rng)
+    tok = jnp.asarray(rng.integers(1, cfg.vocab, len(WIDE_LENS)), jnp.int32)
+    seen = []
+    real = L.paged_gather
+
+    def spy(arena, tables):
+        w = tables.shape[1]
+        jax.debug.callback(lambda: seen.append(w))
+        return real(arena, tables)
+
+    monkeypatch.setattr(L, "paged_gather", spy)
+    step = jax.jit(lambda c, t, a: T.decode_step(params, c, t, cfg,
+                                                 active=a))
+    ref = jax.jit(lambda c, t, a: decode_step_xs_ys(params, c, t, cfg, a))
+    for rows, steps in (([], 1), ([0], 1), ([0, 1], 1), ([0, 1, 2], 2),
+                        ([0, 4], 3)):
+        active = jnp.asarray(np.isin(np.arange(len(WIDE_LENS)), rows))
+        seen.clear()
+        got, _ = step(cache, tok, active)
+        jax.block_until_ready(got)
+        jax.effects_barrier()
+        assert seen and set(seen) == {steps * STEP_COLS}, (rows, seen)
+        if rows:
+            want, _ = ref(cache, tok, active)
+            np.testing.assert_allclose(got[np.array(rows)],
+                                       want[np.array(rows)],
+                                       atol=1e-4, rtol=1e-5)
+    assert step._cache_size() == 1
+
+
+@pytest.mark.parametrize("lane", ["dense", "mla"])
+def test_bounded_gather_token_identity_across_a_width_step(lane):
+    """Paged gather decode == the LINEAR cache, token for token, on
+    ragged rows whose generation crosses the first width step (512
+    positions) at different steps, at a table of two steps, the second
+    partial."""
+    cfg = _cfg(lane, kv_posit="posit16")
+    params = _params(cfg)
+    rng = np.random.default_rng(22)
+    prompts = [list(rng.integers(1, cfg.vocab, size=n))
+               for n in (505, 300, 498)]
+    linear = Engine(cfg, params, max_len=640, seed=0).generate(
+        prompts, 16).tokens
+    paged = Engine(cfg, params, max_len=640, seed=0, paged=True,
+                   block_size=16).generate(prompts, 16).tokens
+    np.testing.assert_array_equal(paged, linear)

@@ -9,7 +9,9 @@ must mark exactly the rounds that compiled, the ring must stay bounded,
 and under ``jax.profiler`` the same spans must land in the trace's host
 plane with the same nesting.  ``Scheduler.progress`` must agree with
 the benchmark's stopgap reading of the slot state.  A chunked round's
-decode-read counters must equal a hand count on each attention lane.
+decode-read counters must equal a hand count on each attention lane,
+and rows crossing a width step of the bounded decode gather must not
+compile anything.
 """
 import importlib.util
 import types
@@ -153,6 +155,30 @@ def test_compiled_marks_only_the_round_that_compiles(model):
     assert later and all(r.counters["compiled"] == 0 for r in later)
 
 
+def test_one_program_serves_every_read_width(model):
+    """Chunked mode at a table of two width steps (1024 positions of
+    blocks of 16): rows cross the first step (512 positions) in the
+    middle of a round, and rounds read one step and two, yet no round
+    after the first compiles: the gather's width is chosen on the
+    device, inside the one ``mixed_step`` program."""
+    cfg, params = model
+    eng = Engine(cfg, params, max_len=1024, paged=True, block_size=16)
+    sched = Scheduler(eng, n_slots=2, chunk_size=64, chunked_prefill=True)
+    rng = np.random.default_rng(3)
+    for plen, gen in ((480, 40), (40, 100), (500, 30)):
+        sched.submit(rng.integers(1, 256, plen).tolist(), gen)
+    done = sched.run(max_rounds=40)
+    assert sorted(len(c.tokens) for c in done.values()) == [30, 40, 100]
+    rounds = sorted((s for s in _mine(sched) if s.name == "sched.round"),
+                    key=lambda s: s.ids["round"])
+    per_step = {r.counters["kv_read_positions"] // 64 // 2 for r in rounds
+                if r.counters["decode_steps"]}
+    assert min(per_step) == 512 and max(per_step) > 512
+    assert rounds[0].counters["compiled"] > 0
+    assert [r.counters["compiled"] for r in rounds[1:]] == \
+        [0] * (len(rounds) - 1)
+
+
 def test_ring_stays_bounded():
     rec = spans.Recorder(capacity=8)
     for i in range(20):
@@ -237,12 +263,31 @@ def test_progress_agrees_with_the_slot_reading(model):
 # Each decode round reads 4 steps x 2 rows x 64 positions; with a
 # window of 8 a row attends at most 8 and reads its ring of 3 blocks.
 FULL = [(0, 0, 0), (4, 22, 512), (4, 30, 512), (4, 46 + 26, 512)]
+# A table of two width steps (1024 positions of blocks of 16; a step
+# reads 512 or 1024 positions a row), chunk 64: A (prompt 480, 40
+# tokens) and B (prompt 40, 100 tokens) on 2 slots:
+#   0: both admitted; B's prompt completes -> no decode
+#   1, 2: B decodes from lens 40 (41..104), then 104 (105..168) and
+#      retires; every step reads the first width step
+#   3-7: A prefills to 480 -> no decode
+#   8: A decodes from lens 480 (481..544) and retires: 32 steps read
+#      one width step, the 32 past 512 read two
+BOUNDED = ([(0, 0, 0), (64, 64 * 41 + 2016, 2 * 64 * 512),
+            (64, 64 * 105 + 2016, 2 * 64 * 512)] + [(0, 0, 0)] * 5
+           + [(64, 64 * 481 + 2016, 2 * (32 * 512 + 32 * 1024))])
+# requests (prompt, tokens), max_len, block_size, chunk, and each
+# round's admitted and retired counts
+ONE_STEP = (((3, 2), (5, 9), (4, 3)), 64, 4, 4,
+            [2, 0, 1, 0], [0, 1, 0, 2])
+TWO_STEPS = (((480, 40), (40, 100)), 1024, 16, 64,
+             [2] + [0] * 8, [0, 0, 1] + [0] * 5 + [1])
 DECODE_READS = {
-    "gqa": ("phi3-medium-14b", 0, FULL),
-    "mla": ("minicpm3-4b", 0, FULL),
-    "window": ("phi3-medium-14b", 8,
+    "gqa": ("phi3-medium-14b", 0, ONE_STEP, FULL),
+    "mla": ("minicpm3-4b", 0, ONE_STEP, FULL),
+    "window": ("phi3-medium-14b", 8, ONE_STEP,
                [(0, 0, 0), (4, 22, 96), (4, 6 + 7 + 8 + 8, 96),
                 (4, 4 * 8 + 26, 96)]),
+    "gqa-bounded": ("phi3-medium-14b", 0, TWO_STEPS, BOUNDED),
 }
 
 
@@ -251,23 +296,26 @@ def test_decode_read_counters_match_a_hand_count(lane):
     """``decode_steps``, ``kv_live_positions`` and ``kv_read_positions``
     on a chunked round, on each attention lane: every step the device
     runs counts, a row's finished steps included, and the gather reads
-    every row's whole table each step."""
-    arch, window, want = DECODE_READS[lane]
+    every row's table up to the width steps that the step's longest
+    decoding row reaches (the whole table when it is one step)."""
+    arch, window, setup, want = DECODE_READS[lane]
+    reqs, max_len, bs, chunk, admitted, retired = setup
     cfg = configs.get_config(arch).reduced(compute_dtype="float32",
                                            sliding_window=window or None)
     params = get_family(cfg).init_params(jax.random.PRNGKey(0), cfg)
-    eng = Engine(cfg, params, max_len=64, paged=True, block_size=4)
-    sched = Scheduler(eng, n_slots=2, chunk_size=4, chunked_prefill=True)
+    eng = Engine(cfg, params, max_len=max_len, paged=True, block_size=bs)
+    sched = Scheduler(eng, n_slots=2, chunk_size=chunk, chunked_prefill=True)
     rng = np.random.default_rng(0)
-    for plen, gen in ((3, 2), (5, 9), (4, 3)):
+    for plen, gen in reqs:
         sched.submit(rng.integers(1, 256, plen).tolist(), gen)
     done = sched.run(max_rounds=20)
-    assert sorted(len(c.tokens) for c in done.values()) == [2, 3, 9]
+    assert sorted(len(c.tokens) for c in done.values()) == \
+        sorted(gen for _, gen in reqs)
     rounds = sorted((s for s in _mine(sched) if s.name == "sched.round"),
                     key=lambda s: s.ids["round"])
     got = [tuple(r.counters[k] for k in ("decode_steps", "kv_live_positions",
                                          "kv_read_positions"))
            for r in rounds]
     assert got == want
-    assert [r.counters["admitted"] for r in rounds] == [2, 0, 1, 0]
-    assert [r.counters["retired"] for r in rounds] == [0, 1, 0, 2]
+    assert [r.counters["admitted"] for r in rounds] == admitted
+    assert [r.counters["retired"] for r in rounds] == retired
