@@ -10,10 +10,13 @@ The continuous-batching section replays the SAME Poisson trace (ragged
 prompt and generation lengths) through (a) static batching — groups of
 ``n_slots`` requests that prefill together once the whole group has
 arrived and decode ``max(gen)`` steps for everyone — and (b) the
-iteration-level scheduler, which retires rows at EOS/max-tokens and
-admits queued prompts between fixed-size decode chunks.  Both serve the
+iteration-level scheduler over a paged engine, which retires rows at
+EOS/max-tokens and admits queued prompts between rounds, each request's
+stream checked against its isolated ``Engine.generate``.  Both serve the
 same useful-token demand on the same simulated clock (1 tick = 1 decode
-step; batch-formation waits and arrival gaps tick too), so goodput =
+step; batch-formation waits, arrival gaps and prefill tick too, prefill
+at the scheduler's rate of ``chunk`` prompt tokens per ``chunk``-step
+round on both sides), so goodput =
 useful tokens / makespan compares what a user actually sees; the run
 asserts continuous wins.  Executed-step utilization is also reported —
 static can look "efficient" there precisely because its requests sit in
@@ -24,16 +27,16 @@ carries decode tok/s, the cache compression ratio, the scan-vs-stepwise
 token agreement (expected 1.0), and for the batching comparison the
 goodput and p50/p99 request latency in decode steps.
 
-The paged-cache section replays the same trace through the compaction
-scheduler and the paged (block-table) scheduler: pass 1 sizes the block
-arena from the trace's committed-blocks high-water mark, pass 2 reruns
-on that right-sized arena and asserts token/schedule identity with
-strictly fewer peak cache bytes than the dense ``slots x max_len`` pool.
+The paged-cache section replays the same trace through the paged
+(block-table) scheduler: pass 1 sizes the block arena from the trace's
+committed-blocks high-water mark, pass 2 reruns on that right-sized
+arena and asserts every stream matches its isolated generation, with
+strictly fewer cache bytes than a dense ``slots x max_len`` pool.
 Pass 3 reruns the right-sized arena decoding through the FUSED Pallas
 paged-attention kernel (``kernels/posit_paged_attn.py``) and asserts
-token/schedule identity again, plus — the ROADMAP's decode-bytes ask —
-reports analytic decode KV bytes/token for both paths and asserts the
-fused kernel moves strictly fewer bytes than gather+dequant.
+token/schedule identity with pass 2, plus — the ROADMAP's decode-bytes
+ask — reports analytic decode KV bytes/token for both paths and asserts
+the fused kernel moves strictly fewer bytes than gather+dequant.
 
 The prefix-caching section replays a SHARED-prefix trace (every prompt
 opens with the same system prefix) through the paged scheduler with and
@@ -42,18 +45,14 @@ fewer prefill tokens and strictly fewer peak physical blocks — the
 dedup win, dropping roughly with the share ratio.
 
 The chunked-prefill section replays an OVERLOAD Poisson trace (arrival
-rate far above drain capacity, ragged prompt lengths) through the paged
-scheduler with and without ``chunked_prefill=True`` on all three
-attention lanes (dense, MLA, sliding-window).  The unchunked path
-jit-specializes admission prefill per prompt length, so every novel
-length stalls the whole pool behind a compile; chunked mode serves
-every request through ONE compiled ``mixed_step`` shape.  Both passes
-must be token-identical per request; the chunked pass must hold a FLAT
-engine compile count after warmup and beat the unchunked pass on p99
-WALL-CLOCK request latency — the tail a recompile stall actually
-inflates (simulation-clock latency alone cannot see it).
+rate far above drain capacity, ragged prompt lengths) through the
+scheduler on all three attention lanes (dense, MLA, sliding-window),
+which serves every request through ONE compiled ``mixed_step`` shape.
+Every stream must match its isolated generation and the engine
+compile count must stay FLAT after warmup, whatever the novel prompt
+lengths; the row reports the p99 WALL-CLOCK request latency.
 
-The sharded section replays one chunked-prefill paged trace through a
+The sharded section replays one paged trace through a
 single-device engine and a tensor-parallel engine over a host device
 mesh (weights by the ``runtime/sharding.py`` rule table, the KV block
 arena head-sharded over 'model') and asserts per-request token AND
@@ -65,9 +64,9 @@ them with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 ``--smoke`` shrinks the sweep for the CI fast lane (exercises prefill
 headroom, ring-free dense decode, both posit codecs, and the
 continuous-batching scheduler end to end); ``--paged`` runs ONLY the
-paged-vs-compaction comparison (the fast lane's paged smoke),
+paged-arena section (the fast lane's paged smoke),
 ``--prefix-share`` adds (or alone, runs only) the prefix-caching
-comparison, ``--chunked`` runs ONLY the chunked-prefill comparison,
+comparison, ``--chunked`` runs ONLY the chunked-prefill section,
 and ``--sharded`` runs ONLY the tensor-parallel comparison.
 ``--sanitize`` arms the arena sanitizer on the paged, prefix, chunked
 and sharded passes (``BlockPool(sanitize=True)`` misuse checks,
@@ -97,6 +96,18 @@ from repro.runtime.scheduler import Scheduler
 ARCH = "phi3-medium-14b"
 KV_FORMATS = (None, "posit16", "posit8")
 REPEATS = 3
+
+
+def _check_isolated(cfg, params, max_len, trace, done, order, what):
+    """Every completion of a ``drive_trace`` run must equal its request's
+    isolated greedy ``Engine.generate`` stream."""
+    ref = Engine(cfg, params, max_len=max_len, seed=0)
+    assert len(done) == len(trace)
+    for rid, i in order.items():
+        _, prompt, gen = trace[i]
+        want = ref.generate([prompt], gen).tokens[0]
+        assert (done[rid].tokens == want).all(), \
+            f"{what} diverged from isolated generation on request {rid}"
 
 
 def _time(fn):
@@ -157,11 +168,14 @@ def run(smoke: bool = False, paged: bool = True):
     return rows
 
 
-def _static_batching(cfg, params, trace, n_slots, max_len):
+def _static_batching(cfg, params, trace, n_slots, max_len, chunk):
     """Static batching baseline: requests group in arrival order, a group
     prefills only once its LAST member has arrived, and every row decodes
     ``max(gen)`` steps — the padding/idle waste continuous batching
-    removes.  Returns (useful_tokens, executed_steps, latencies,
+    removes.  The group's prefill is charged on the clock at the rate
+    the scheduler pays for its own (a prompt goes through ``chunk``
+    tokens per ``chunk``-step round), so both sides count prefill the
+    same way.  Returns (useful_tokens, executed_steps, latencies,
     makespan_steps, wall_s).
     """
     eng = Engine(cfg, params, max_len=max_len, seed=0)
@@ -172,9 +186,10 @@ def _static_batching(cfg, params, trace, n_slots, max_len):
         group = trace[i:i + n_slots]
         start = max(clock, max(t for t, _, _ in group))
         gen_max = max(g for _, _, g in group)
+        prefill = -(-max(len(p) for _, p, _ in group) // chunk) * chunk
         eng.generate([p for _, p, _ in group], gen_max)
-        steps += gen_max
-        clock = start + gen_max
+        steps += prefill + gen_max
+        clock = start + prefill + gen_max
         for t, _, g in group:
             useful += g              # only the requested tokens count
             lats.append(clock - t)
@@ -200,14 +215,17 @@ def run_batching_comparison(smoke: bool = False):
                           cfg.vocab, plen, gen)
 
     s_useful, s_steps, s_lat, s_makespan, s_wall = _static_batching(
-        cfg, params, trace, n_slots, max_len)
+        cfg, params, trace, n_slots, max_len, chunk)
     s_goodput = s_useful / max(s_makespan, 1e-9)
 
-    eng = Engine(cfg, params, max_len=max_len, seed=0)
+    eng = Engine(cfg, params, max_len=max_len, seed=0, paged=True,
+                 block_size=4)
     sched = Scheduler(eng, n_slots=n_slots, chunk_size=chunk)
     t0 = time.perf_counter()
-    done, _ = drive_trace(sched, trace)
+    done, order = drive_trace(sched, trace)
     c_wall = time.perf_counter() - t0
+    _check_isolated(cfg, params, max_len, trace, done, order,
+                    "continuous batching")
     c_useful = sum(len(c.tokens) for c in done.values())
     c_steps = sched.n_chunks * sched.chunk_size
     c_makespan = max(c.finished_step for c in done.values())
@@ -238,14 +256,14 @@ def run_batching_comparison(smoke: bool = False):
 
 
 def run_paged_comparison(smoke: bool = False, sanitize: bool = False):
-    """Paged (block-table) vs compaction scheduler on one ragged trace.
+    """The paged (block-table) scheduler on one ragged trace.
 
-    Two paged passes: the first (worst-case arena, no deferrals
+    Two gather passes: the first (worst-case arena, no deferrals
     possible) measures the trace's committed-blocks high-water mark;
     the second replays on an arena of exactly that size — reservations
-    still never defer, so scheduling is identical — and must match the
-    compaction scheduler's completions token for token and step for
-    step on strictly fewer cache bytes than ``slots x max_len``.
+    still never defer, so scheduling is identical — and every stream
+    must match its isolated generation, on strictly fewer cache bytes
+    than a dense ``slots x max_len`` pool.
     """
     if smoke:
         n_req, n_slots, plen, gen, chunk, rate = 8, 2, 8, 8, 4, 1.0
@@ -262,18 +280,14 @@ def run_paged_comparison(smoke: bool = False, sanitize: bool = False):
     trace = poisson_trace(np.random.default_rng(11), n_req, rate,
                           cfg.vocab, plen, gen)
 
-    lin = Scheduler(Engine(cfg, params, max_len=max_len, seed=0),
-                    n_slots=n_slots, chunk_size=chunk)
-    t0 = time.perf_counter()
-    done_l, _ = drive_trace(lin, trace)
-    l_wall = time.perf_counter() - t0
-    l_bytes = cache_report(lin.cache)["bytes"]
+    l_bytes = cache_report(get_family(cfg).init_cache(
+        cfg, n_slots, max_len))["bytes"]  # the dense slots x max_len pool
 
     # pass 1: worst-case arena -> the trace's committed-block peak
     probe = Scheduler(Engine(cfg, params, max_len=max_len, seed=0,
                              paged=True, block_size=block),
                       n_slots=n_slots, chunk_size=chunk)
-    drive_trace(probe, trace)
+    done_1, _ = drive_trace(probe, trace)
     n_blocks = probe.peak_committed
 
     # pass 2: right-sized arena (identical scheduling, fewer bytes);
@@ -284,9 +298,11 @@ def run_paged_comparison(smoke: bool = False, sanitize: bool = False):
                            n_blocks=n_blocks, sanitize=sanitize),
                     n_slots=n_slots, chunk_size=chunk)
     t0 = time.perf_counter()
-    done_p, _ = drive_trace(pag, trace)
+    done_p, order = drive_trace(pag, trace)
     p_wall = time.perf_counter() - t0
     p_bytes = cache_report(pag.cache)["bytes"]
+    _check_isolated(cfg, params, max_len, trace, done_p, order,
+                    "paged scheduler")
     if sanitize:
         assert pag.n_leaked == 0 and not pag.leak_report(), \
             f"sanitizer found leaked arena blocks: {pag.leak_report()}"
@@ -304,11 +320,9 @@ def run_paged_comparison(smoke: bool = False, sanitize: bool = False):
     done_f, _ = drive_trace(fus, trace)
     f_wall = time.perf_counter() - t0
 
-    assert done_l.keys() == done_p.keys() == done_f.keys()
-    for rid in done_l:
-        assert (done_p[rid].tokens == done_l[rid].tokens).all(), \
-            f"paged scheduler diverged from compaction on request {rid}"
-        assert done_p[rid].finished_step == done_l[rid].finished_step
+    assert done_1.keys() == done_p.keys() == done_f.keys()
+    for rid in done_p:
+        assert done_p[rid].finished_step == done_1[rid].finished_step
         assert (done_f[rid].tokens == done_p[rid].tokens).all(), \
             f"fused paged decode diverged from gather on request {rid}"
         assert done_f[rid].finished_step == done_p[rid].finished_step
@@ -350,8 +364,7 @@ def run_paged_comparison(smoke: bool = False, sanitize: bool = False):
          f"peak_cache_bytes={p_bytes} dense_cache_bytes={l_bytes} "
          f"bytes_saved={1 - p_bytes / l_bytes:.2f} "
          f"arena_blocks={n_blocks} worst_case_blocks={dense_blocks} "
-         f"peak_blocks_in_use={pag.pool.peak_in_use} "
-         f"wall_vs_compaction={p_wall / max(l_wall, 1e-9):.2f}x"),
+         f"peak_blocks_in_use={pag.pool.peak_in_use}"),
         (f"serve_paged_fused_b{n_slots}_n{n_req}_c{chunk}_blk{block}",
          f_wall * 1e6,
          f"tokens_match_gather=1.0 " + " ".join(bytes_parts) + " "
@@ -481,17 +494,17 @@ def _drive_wall(sched, trace):
 
 
 def run_chunked_comparison(smoke: bool = False, sanitize: bool = False):
-    """Chunked vs whole-prompt prefill under an overload Poisson trace,
-    on all three attention lanes.
+    """Chunked prefill under an overload Poisson trace, on all three
+    attention lanes.
 
     The arrival rate is far above drain capacity, so the pool is
     saturated and every admission stall lands on someone's tail
-    latency.  The trace's ragged prompt lengths make the unchunked
-    admission path compile one prefill per novel length; the chunked
-    pass serves them all through the warm ``mixed_step`` program.
-    Asserts per-request token identity, a flat post-warmup compile
-    count (without the sanitizer, whose poison dispatches legitimately
-    jit per reclaim size), and a chunked p99 wall-latency win.
+    latency.  The trace's ragged prompt lengths all go through the warm
+    ``mixed_step`` program.  Asserts per-request identity with isolated
+    generation, a flat post-warmup compile count (without the
+    sanitizer, whose poison dispatches legitimately jit per reclaim
+    size), and, under the sanitizer, a leak-free trace; reports the p99
+    wall latency.
     """
     if smoke:
         n_req, n_slots, plen, gen, chunk = 8, 2, 12, 6, 4
@@ -507,46 +520,27 @@ def run_chunked_comparison(smoke: bool = False, sanitize: bool = False):
                               cfg.vocab, plen, gen)
         warm = [(0.0, list(range(1, chunk + 2)), 2)]  # compile warmup
 
-        results = {}
-        for mode in ("unchunked", "chunked"):
-            eng = Engine(cfg, params, max_len=max_len, seed=0,
-                         paged=True, block_size=block,
-                         sanitize=sanitize and mode == "chunked")
-            sched = Scheduler(eng, n_slots=n_slots, chunk_size=chunk,
-                              chunked_prefill=mode == "chunked")
-            _drive_wall(sched, warm)   # exclude warmup compiles
-            warm_compiles = eng.n_compiles
-            done, lat = _drive_wall(sched, trace)
-            results[mode] = (done, lat, warm_compiles, eng, sched)
-
-        done_u, lat_u, _, eng_u, _ = results["unchunked"]
-        done_c, lat_c, warm_c, eng_c, sched_c = results["chunked"]
-        ids = [r for r in done_u if r in lat_u]
-        assert done_u.keys() == done_c.keys()
-        for rid in done_u:
-            assert (done_u[rid].tokens == done_c[rid].tokens).all(), \
-                f"chunked prefill changed the tokens of request {rid}"
+        eng = Engine(cfg, params, max_len=max_len, seed=0, paged=True,
+                     block_size=block, sanitize=sanitize)
+        sched = Scheduler(eng, n_slots=n_slots, chunk_size=chunk)
+        _drive_wall(sched, warm)       # exclude warmup compiles
+        warm_compiles = eng.n_compiles
+        done, lat = _drive_wall(sched, trace)
+        # rids follow the trace's submission order
+        _check_isolated(cfg, params, max_len, trace, done,
+                        dict(zip(sorted(done), range(len(trace)))),
+                        f"chunked prefill ({lane} lane)")
         if not sanitize:
-            assert eng_c.n_compiles == warm_c, (
-                f"chunked engine compiled "
-                f"{eng_c.n_compiles - warm_c} new programs after "
-                f"warmup on the {lane} lane")
+            assert eng.n_compiles == warm_compiles, (
+                f"engine compiled {eng.n_compiles - warm_compiles} new "
+                f"programs after warmup on the {lane} lane")
         if sanitize:
-            assert sched_c.n_leaked == 0 and not sched_c.leak_report()
-        p99_u = float(np.percentile([lat_u[r] for r in ids], 99))
-        p99_c = float(np.percentile([lat_c[r] for r in ids], 99))
-        assert p99_c < p99_u, (
-            f"chunked prefill p99 wall latency {p99_c * 1e3:.0f} ms did "
-            f"not beat unchunked {p99_u * 1e3:.0f} ms on the {lane} "
-            f"lane (overload trace)")
+            assert sched.n_leaked == 0 and not sched.leak_report()
+        p99 = float(np.percentile(list(lat.values()), 99))
         rows.append((
             f"serve_chunked_{lane}_b{n_slots}_n{n_req}_c{chunk}",
-            p99_c * 1e6,
-            f"p99_wall_ms={p99_c * 1e3:.1f} "
-            f"unchunked_p99_wall_ms={p99_u * 1e3:.1f} "
-            f"p99_speedup={p99_u / max(p99_c, 1e-9):.2f}x "
-            f"compiles={eng_c.n_compiles} "
-            f"unchunked_compiles={eng_u.n_compiles}"))
+            p99 * 1e6,
+            f"p99_wall_ms={p99 * 1e3:.1f} compiles={eng.n_compiles}"))
     return rows
 
 
@@ -555,7 +549,7 @@ def run_sharded_comparison(smoke: bool = False, sanitize: bool = False):
 
     Builds a host mesh over all local devices with the largest 'model'
     degree dividing both the device count and the config's KV heads,
-    replays the SAME chunked-prefill paged trace through a
+    replays the SAME paged trace through a
     single-device engine and a mesh engine (weights by the
     ``runtime/sharding.py`` rule table, arena heads on 'model'), and
     asserts per-request token AND schedule identity — sharding the
@@ -591,8 +585,7 @@ def run_sharded_comparison(smoke: bool = False, sanitize: bool = False):
     def _pass(mesh):
         eng = Engine(cfg, params, max_len=max_len, seed=0, paged=True,
                      block_size=block, sanitize=sanitize, mesh=mesh)
-        sched = Scheduler(eng, n_slots=n_slots, chunk_size=chunk,
-                          chunked_prefill=True)
+        sched = Scheduler(eng, n_slots=n_slots, chunk_size=chunk)
         t0 = time.perf_counter()
         done, _ = drive_trace(sched, trace)
         return done, sched, time.perf_counter() - t0

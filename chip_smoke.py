@@ -15,9 +15,8 @@ One process holds the chip(s) for the whole run.  Phases:
   on the same chip (plus ``ops.pgemm`` on a small product).
 * Serving: minicpm3-4b at its published widths, all 62 layers, bf16
   weights from ``--seed``, posit16 KV, through ``launch/serve.py``'s
-  ``main()`` with ``--continuous --paged --chunked-prefill``: 8 slots, 16
-  requests, prompts of 512-2048 tokens, 64-128 new tokens, ``max_len``
-  4096, blocks of 16.  Once with the fused decode kernel and once with
+  ``main()`` with ``--continuous``: 8 slots, 16 requests, prompts of
+  512-2048 tokens, 64-128 new tokens, ``max_len`` 4096, blocks of 16.  Once with the fused decode kernel and once with
   the gather path; the runs are compared with each other and each
   request's first-token logits with a one-shot ``Engine.prefill`` at
   ``default_matmul_precision("highest")``.
@@ -213,8 +212,7 @@ def phase_posit16(fail, n_pairs: int, seed: int):
 
 def serve_argv(spec, kernel: str, seed: int, model_parallel: int = 1):
     """``launch/serve.py`` arguments for one continuous chunked run."""
-    argv = ["--arch", spec["arch"], "--continuous", "--paged",
-            "--chunked-prefill", "--kv-posit", "posit16",
+    argv = ["--arch", spec["arch"], "--continuous", "--kv-posit", "posit16",
             "--decode-kernel", kernel, "--seed", str(seed),
             "--batch", str(spec["slots"]),
             "--n-requests", str(spec["n_requests"]),

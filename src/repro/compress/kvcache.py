@@ -11,10 +11,9 @@ instead of the dequantize -> f32 op -> requantize round-trip, so a cache
 rescale (attention-sink discounting, temperature folding) or a
 speculative-decoding cache merge rounds once, not twice.
 
-This module also owns the serving cache MEMORY model: the per-slot
-surgery ops for the linear/ring layouts (``reset_slots`` / ``compact`` /
-``adopt_row``) and the paged layout's ``BlockPool`` free list plus
-block-table surgery (``paged_adopt_row`` / ``paged_release_rows``).
+This module also owns the serving cache MEMORY model: the paged
+layout's ``BlockPool`` free list, the prefix index, and block-table
+surgery (``paged_release_rows``).
 """
 from __future__ import annotations
 
@@ -23,7 +22,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from jax import lax, tree_util
+from jax import tree_util
 
 from repro.core.convert import f32_to_posit, posit_to_f32
 from repro.core.tracing import is_tracer as _is_tracer
@@ -45,10 +44,10 @@ from .gradient import pcfg_of, scalar_pattern
 # instead of guessing.
 # ---------------------------------------------------------------------------
 
-# Time-axis / row-state content (also the cache-surgery move set below).
+# Time-axis content (the paged arenas' leaves).
 _TIME_LEAVES = frozenset(
     {"k", "v", "c_kv", "k_rope", "k_swa", "v_swa", "k_glb", "v_glb"})
-# Per-row state without a time axis (cleared on reset, copied on adopt).
+# Per-row state without a time axis.
 _ROW_LEAVES = frozenset({"ssm"})
 # All content: time leaves + row state + whisper cross-attention KV +
 # rwkv recurrent state.
@@ -186,135 +185,10 @@ def cache_report(cache, pool=None) -> dict:
     return out
 
 
-# ---------------------------------------------------------------------------
-# Per-slot cache surgery (continuous-batching scheduler support)
-#
-# Engine-shaped caches carry metadata leaves ``len`` (scalar padded-write
-# frontier), ``lens`` ((B,) per-row valid counts) and ``max_len``.  The
-# scheduler treats the batch dimension as a SLOT POOL: retired rows are
-# wiped (``reset_slots``), the shared frontier is moved so freed headroom
-# is reclaimed or a long admitted prompt fits (``compact``), and a
-# freshly prefilled single-prompt cache is grafted into a free row
-# (``adopt_row``).  All three are jit-safe (shifts/rows may be traced) and
-# layout-agnostic: time-axis leaves roll circularly, which is exact for
-# linear caches (stale slots stay masked by ``lens``) and IS the frontier
-# relabelling for ring buffers (slot = pos % T).
-# ---------------------------------------------------------------------------
-
-# ``_TIME_LEAVES`` (defined with the leaf schema above) is the move set:
-# leaves with a (stack, batch, time, ...) layout that must travel with the
-# write frontier; ``_ROW_LEAVES`` is per-row state without a time axis.
-
-
 def is_paged(cache) -> bool:
-    """True for block-table (paged) caches; their batch rows address the
-    shared block arena through per-row tables, so the linear/ring
-    surgery ops below do not apply (see the paged section)."""
+    """True for block-table (paged) caches: their batch rows address the
+    shared block arena through per-row tables (see the paged section)."""
     return isinstance(cache, dict) and "block_tables" in cache
-
-
-def _reject_paged(cache, what: str):
-    if is_paged(cache):
-        raise ValueError(
-            f"{what}: paged (block-table) caches have no shared linear "
-            "frontier to move; use paged_adopt_row / paged_release_rows "
-            "and the BlockPool instead")
-
-
-def reset_slots(cache, rows):
-    """Retire the given batch rows: ``lens -> 0`` and their cache content
-    zeroed.  ``rows``: (B,) bool, True = free this slot.
-
-    The zeroing is hygiene (attention already masks retired rows via
-    ``lens``); the load-bearing part is the metadata reset, which lets
-    ``compact`` reclaim the headroom the retired rows were pinning.
-    """
-    from repro.models import layers as L
-
-    _reject_paged(cache, "reset_slots")
-    rows = jnp.asarray(rows, bool)
-    out = dict(cache)
-    for key, leaf in cache.items():
-        if key in _TIME_LEAVES or key in _ROW_LEAVES:
-            out[key] = L.reset_cache_rows(leaf, rows)
-    out["lens"] = jnp.where(rows, 0, jnp.asarray(cache["lens"], jnp.int32))
-    return out
-
-
-def compact(cache, target_len=None):
-    """Move the shared write frontier to ``target_len`` (default: the
-    tightest frontier, ``max(lens)``), rolling every time-axis leaf so
-    row content still ends at the frontier.
-
-    Shrinking (the common case after retirements) reclaims headroom so
-    decode chunks keep fitting in ``max_len``; growing makes room for an
-    admitted prompt longer than the current frontier.  ``lens`` and
-    ``max_len`` are unchanged — per-row content is only relabelled.
-    """
-    from repro.models import layers as L
-
-    _reject_paged(cache, "compact")
-    cur = jnp.asarray(cache["len"], jnp.int32)
-    target = (jnp.max(jnp.asarray(cache["lens"], jnp.int32))
-              if target_len is None else jnp.asarray(target_len, jnp.int32))
-    if not _is_tracer(target) and not _is_tracer(cache["max_len"]):
-        if int(target) > int(cache["max_len"]):
-            raise ValueError(
-                f"compact: target frontier {int(target)} exceeds cache "
-                f"max_len {int(cache['max_len'])}")
-    shift = target - cur
-    out = dict(cache)
-    for key, leaf in cache.items():
-        if key in _TIME_LEAVES:
-            out[key] = L.roll_cache_time(leaf, shift)
-    out["len"] = target
-    return out
-
-
-def adopt_row(cache, row_cache, row):
-    """Graft a batch-1 prefilled cache into slot ``row`` of a pool cache.
-
-    ``row_cache`` must come from the same model/config (leaf shapes match
-    except batch = 1) with frontier ``row_cache['len'] <= cache['len']``:
-    its content is rolled up so the prompt ends at the pool's shared
-    frontier (per-row RoPE positions are content-relative, so relabelling
-    padded slots is free), then scattered into batch row ``row``; the
-    row's ``lens`` entry takes the prompt length.
-    """
-    _reject_paged(cache, "adopt_row")
-    cur = cache["len"]
-    src = row_cache["len"]
-    if not _is_tracer(cur) and not _is_tracer(src) \
-            and int(src) > int(cur):
-        raise ValueError(
-            f"adopt_row: admitted prompt frontier {int(src)} exceeds the "
-            f"pool frontier {int(cur)}; compact(cache, target_len="
-            f"{int(src)}) first")
-    from repro.models import layers as L
-
-    shift = jnp.asarray(cur, jnp.int32) - jnp.asarray(src, jnp.int32)
-    row = jnp.asarray(row, jnp.int32)
-    out = dict(cache)
-    # Clamping is impossible in these grafts, so the guarded helpers are
-    # not needed (and would not fit: multi-axis starts, row axis 1): every
-    # start is 0 except `row`, each update spans the full extent of its
-    # axis (so start 0 never clamps), and `row` comes from the scheduler's
-    # slot pool (0 <= row < n_slots); a bad frontier is rejected by the
-    # eager ValueError above before any device write.
-    for key, leaf in cache.items():
-        if key in _TIME_LEAVES and key in row_cache:
-            upd = L.roll_cache_time(row_cache[key], shift)
-            starts = (jnp.zeros((), jnp.int32), row) + \
-                tuple(jnp.zeros((), jnp.int32) for _ in range(leaf.ndim - 2))
-            out[key] = lax.dynamic_update_slice(leaf, upd, starts)  # positcheck: disable=PVU001
-        elif key in _ROW_LEAVES and key in row_cache:
-            starts = (jnp.zeros((), jnp.int32), row) + \
-                tuple(jnp.zeros((), jnp.int32) for _ in range(leaf.ndim - 2))
-            out[key] = lax.dynamic_update_slice(leaf, row_cache[key], starts)  # positcheck: disable=PVU001
-    out["lens"] = lax.dynamic_update_slice(  # positcheck: disable=PVU001 (int32 metadata row, same bound)
-        jnp.asarray(cache["lens"], jnp.int32),
-        jnp.asarray(row_cache["lens"], jnp.int32), (row,))
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -397,13 +271,11 @@ def merge_caches(cache_a, cache_b, name: str, weight_a: float = 0.5,
 #     garbage;
 #   * addressing is ROW-LOCAL: row b's token p occupies logical block
 #     ``p // block_size`` at offset ``p % block_size``.  There is no
-#     shared padded frontier (no ``len`` leaf) and therefore nothing to
-#     ``compact`` — admission just packs a prompt's KV into freshly
+#     shared padded frontier (no ``len`` leaf): a row grows into freshly
 #     allocated blocks, and retirement frees them back to the pool.
 #
-# The ``BlockPool`` itself is HOST state (a free list), like the
-# scheduler's frontier mirror: block ids only cross to the device inside
-# ``block_tables``.
+# The ``BlockPool`` itself is HOST state (a free list): block ids only
+# cross to the device inside ``block_tables``.
 # ---------------------------------------------------------------------------
 
 
@@ -659,40 +531,6 @@ class PrefixIndex:
     def blocks_lru(self) -> list:
         """Registered block ids, least-recently-matched first."""
         return list(self._by_hash.values())
-
-
-def paged_adopt_row(cache, row_cache, row, block_ids, *, window: int = 0,
-                    src_ring: bool = False):
-    """Graft a batch-1 LINEAR prefilled cache into row ``row`` of a paged
-    pool cache: the prompt's KV is scattered into the arena blocks named
-    by ``block_ids`` and the row's table/``lens`` entries take over.
-
-    ``block_ids``: (W,) int32 physical ids, unassigned entries = the
-    ``n_blocks`` sentinel (their scatter is dropped).  ``src_ring`` marks
-    a ``row_cache`` whose K/V time axis is in ring layout (a
-    sliding-window prefill longer than the window); out-of-window slots
-    the block layout covers but the ring never stored arrive as garbage
-    and stay masked, exactly as they are in the ring itself.  Unlike
-    ``adopt_row`` there is no frontier precondition: row-local
-    addressing needs no compaction.
-    """
-    from repro.models import layers as L
-
-    if not is_paged(cache):
-        raise ValueError("paged_adopt_row: pool cache is not paged "
-                         "(no block_tables leaf)")
-    row = jnp.asarray(row, jnp.int32)
-    block_ids = jnp.asarray(block_ids, jnp.int32)
-    plen = jnp.asarray(row_cache["lens"], jnp.int32)[0]
-    out = dict(cache)
-    for key, leaf in cache.items():
-        if key in _TIME_LEAVES and key in row_cache:
-            out[key] = L.paged_pack(
-                leaf, row_cache[key], block_ids[None, :], plen[None],
-                window=window, src_shift=None, src_ring=src_ring)
-    out["block_tables"] = cache["block_tables"].at[row].set(block_ids)
-    out["lens"] = jnp.asarray(cache["lens"], jnp.int32).at[row].set(plen)
-    return out
 
 
 def paged_release_rows(cache, rows):

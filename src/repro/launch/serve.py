@@ -12,25 +12,28 @@ actual vs f32-equivalent cache bytes.
       --max-len 64 --temperature 0.7 --seed 0
 
 ``--continuous`` switches to the iteration-level scheduler
-(``repro.runtime.scheduler``): ``--n-requests`` requests arrive on a
-simulated Poisson trace (``--arrival-rate`` expected arrivals per decode
-step), prompts/generation lengths are ragged, and ``--batch`` becomes
-the slot-pool width.  Requests join and leave between fixed
-``--chunk-size`` decode chunks (each one compiled dispatch); the report
-prints goodput and p50/p99 request latency in decode steps.
+(``repro.runtime.scheduler``) over a paged engine: ``--n-requests``
+requests arrive on a simulated Poisson trace (``--arrival-rate``
+expected arrivals per decode step), prompts/generation lengths are
+ragged, and ``--batch`` becomes the slot-pool width.  Prompts flow
+through the decode lane in ``--chunk-size``-token chunks and requests
+join and leave between rounds; every round is ONE compiled dispatch
+whose shape never depends on a prompt length, so the engine's compile
+count stays flat.  The report prints goodput and p50/p99 request
+latency in decode steps.
 
   PYTHONPATH=src python -m repro.launch.serve --arch phi3-medium-14b \
       --reduced --continuous --batch 4 --n-requests 16 \
       --arrival-rate 0.2 --chunk-size 8 --max-len 64
 
-``--paged`` swaps the dense ``slots x max_len`` cache for the paged
-block-table layout (``--block-size`` slots per block, ``--n-blocks``
-arena size; 0 = worst case): rows allocate blocks as they grow and free
-them at retirement, so peak cache memory tracks the tokens actually
-resident instead of the worst case, and admission never compacts.  The
-dense path stays selectable (omit ``--paged``) for A/B comparison.
+The continuous scheduler always serves from the paged block-table
+layout (``--block-size`` slots per block, ``--n-blocks`` arena size;
+0 = worst case): rows allocate blocks as they grow and free them at
+retirement, so peak cache memory tracks the tokens actually resident
+instead of the worst case.  For the one-shot path ``--paged`` selects
+that layout; omit it for the dense ``batch x max_len`` cache.
 
-``--prefix-cache`` (with ``--continuous --paged``) deduplicates shared
+``--prefix-cache`` (with ``--continuous``) deduplicates shared
 prompt prefixes: admission matches each prompt's leading full blocks
 against a content-addressed index of resident blocks, borrows the hits
 via refcounts and skips their prefill chunks; writes into borrowed
@@ -39,14 +42,10 @@ blocks copy-on-write first, so greedy token streams are unchanged.
 with the same system prefix of that fractional length:
 
   PYTHONPATH=src python -m repro.launch.serve --arch phi3-medium-14b \
-      --reduced --continuous --paged --prefix-cache --batch 4 \
+      --reduced --continuous --prefix-cache --batch 4 \
       --n-requests 16 --prompt-len 32 --prefix-share 0.75 --block-size 4
 
-``--chunked-prefill`` (with ``--continuous --paged``) routes prompts
-through the decode lane in fixed ``--chunk-size``-token chunks, so ONE
-compiled dispatch shape serves every request and the engine's compile
-count stays flat no matter how ragged the prompt lengths are (implied
-by ``--prefix-cache``).  ``--deadline-ms`` attaches a completion
+``--deadline-ms`` attaches a completion
 deadline to every request — admission turns earliest-deadline-first
 and, when the pool is full, the scheduler preempts the latest-deadline
 row (releasing its blocks) to admit a more urgent one.  Deadlines are
@@ -56,7 +55,7 @@ schedule is what the deadline shapes, wall time per step varies by
 host):
 
   PYTHONPATH=src python -m repro.launch.serve --arch phi3-medium-14b \
-      --reduced --continuous --paged --chunked-prefill --batch 4 \
+      --reduced --continuous --batch 4 \
       --n-requests 16 --deadline-ms 400 --chunk-size 4
 
 ``--model-parallel N`` serves tensor-parallel over a host mesh
@@ -160,7 +159,7 @@ def drive_trace(sched: Scheduler, trace, deadline_steps=None):
     return done, order
 
 
-def _build_engine(args, cfg, params, max_len):
+def _build_engine(args, cfg, params, max_len, paged):
     kernel = getattr(args, "decode_kernel", "gather")
     mesh = None
     if getattr(args, "model_parallel", 1) > 1:
@@ -168,7 +167,7 @@ def _build_engine(args, cfg, params, max_len):
         mesh = make_host_mesh(args.model_parallel)
     return Engine(cfg, params, max_len=max_len,
                   temperature=args.temperature, seed=args.seed,
-                  paged=args.paged, block_size=args.block_size,
+                  paged=paged, block_size=args.block_size,
                   n_blocks=args.n_blocks,
                   decode_kernel=None if kernel == "gather" else kernel,
                   mesh=mesh)
@@ -180,11 +179,10 @@ def run_continuous(args, cfg, params):
     # chunk of frontier headroom (overshoot before retirement)
     max_len = args.max_len or (args.prompt_len + args.gen - 1 +
                                args.chunk_size)
-    engine = _build_engine(args, cfg, params, max_len)
+    engine = _build_engine(args, cfg, params, max_len, paged=True)
     sched = Scheduler(engine, n_slots=args.batch,
                       chunk_size=args.chunk_size,
-                      prefix_cache=args.prefix_cache,
-                      chunked_prefill=args.chunked_prefill)
+                      prefix_cache=args.prefix_cache)
     deadline_steps = None
     if args.deadline_ms > 0:
         deadline_steps = max(1, int(np.ceil(args.deadline_ms
@@ -217,12 +215,11 @@ def run_continuous(args, cfg, params):
     print(f"  cache: {rep['bytes']:,} bytes of {rep['f32_bytes']:,} "
           f"f32-equiv ({rep['ratio']:.2f}x, kv_posit={cfg.kv_posit}, "
           f"max_len={max_len})")
-    if args.paged:
-        print(f"  paged: {sched.n_blocks} arena blocks x "
-              f"{sched.block_size} slots (dense worst case "
-              f"{args.batch * sched.table_width}); peak in use "
-              f"{sched.pool.peak_in_use}, peak committed "
-              f"{sched.peak_committed}")
+    print(f"  paged: {sched.n_blocks} arena blocks x "
+          f"{sched.block_size} slots (dense worst case "
+          f"{args.batch * sched.table_width}); peak in use "
+          f"{sched.pool.peak_in_use}, peak committed "
+          f"{sched.peak_committed}")
     if engine.mesh is not None:
         mp = engine.mesh.shape.get("model", 1)
         print(f"  sharded: mesh {dict(engine.mesh.shape)}; KV per "
@@ -230,11 +227,10 @@ def run_continuous(args, cfg, params):
               f"bytes (model_parallel={mp}); step wall p50 "
               f"{sched.stats['step_wall_p50_ms']:.1f} ms p99 "
               f"{sched.stats['step_wall_p99_ms']:.1f} ms")
-    if sched.chunked:
-        print(f"  chunked prefill: {sched.prefill_tokens} prompt tokens "
-              f"through the decode lane in {args.chunk_size}-token "
-              f"chunks; {engine.n_compiles} compiled programs "
-              f"(flat across prompt lengths)")
+    print(f"  chunked prefill: {sched.prefill_tokens} prompt tokens "
+          f"through the decode lane in {args.chunk_size}-token "
+          f"chunks; {engine.n_compiles} compiled programs "
+          f"(flat across prompt lengths)")
     if deadline_steps is not None:
         missed = sum(1 for c in done.values()
                      if c.finished_step > c.arrival_step + deadline_steps)
@@ -314,16 +310,17 @@ def main(argv=None):
                     help="decode steps between scheduling rounds "
                          "(with --continuous)")
     ap.add_argument("--paged", action="store_true",
-                    help="paged block-table KV cache (transformer "
-                         "family only); omit for the dense layout")
+                    help="one-shot path: paged block-table KV cache "
+                         "(transformer family only); omit for the dense "
+                         "layout.  --continuous is always paged")
     ap.add_argument("--block-size", type=int, default=16,
-                    help="cache slots per arena block (with --paged)")
+                    help="cache slots per arena block (paged)")
     ap.add_argument("--n-blocks", type=int, default=0,
-                    help="arena size in blocks (with --paged; "
+                    help="arena size in blocks (paged; "
                          "0 = worst case, never out of blocks)")
     ap.add_argument("--decode-kernel", choices=["gather", "fused"],
                     default="gather",
-                    help="paged decode attention path (with --paged): "
+                    help="paged decode attention path: "
                          "'gather' materializes per-row KV via "
                          "paged_gather then attends in jnp; 'fused' "
                          "walks the block table inside one Pallas "
@@ -332,17 +329,10 @@ def main(argv=None):
                          "decode KV bytes")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="content-addressed prefix sharing with "
-                         "copy-on-write block tables (with --continuous "
-                         "--paged): admissions borrow already-resident "
+                         "copy-on-write block tables (with --continuous): "
+                         "admissions borrow already-resident "
                          "prompt blocks and prefill only the unmatched "
                          "suffix; greedy token streams are unchanged")
-    ap.add_argument("--chunked-prefill", action="store_true",
-                    help="feed prompts through the decode lane in fixed "
-                         "chunk-size-token chunks (with --continuous "
-                         "--paged): one compiled dispatch shape serves "
-                         "every request, so the engine never "
-                         "jit-specializes on a prompt length "
-                         "(implied by --prefix-cache)")
     ap.add_argument("--deadline-ms", type=float, default=0.0,
                     help="with --continuous: per-request completion "
                          "deadline in milliseconds, converted to the "
@@ -365,14 +355,14 @@ def main(argv=None):
                          "independent Poisson prompts); the share ratio "
                          "bounds the possible prefix-cache win")
     args = ap.parse_args(argv)
-    if args.prefix_cache and not (args.continuous and args.paged):
-        ap.error("--prefix-cache requires --continuous --paged")
-    if args.chunked_prefill and not (args.continuous and args.paged):
-        ap.error("--chunked-prefill requires --continuous --paged")
+    if args.prefix_cache and not args.continuous:
+        ap.error("--prefix-cache requires --continuous")
     if args.deadline_ms > 0 and not args.continuous:
         ap.error("--deadline-ms requires --continuous")
-    if args.decode_kernel == "fused" and not args.paged:
-        ap.error("--decode-kernel fused requires --paged")
+    if args.decode_kernel == "fused" and not (args.paged or
+                                              args.continuous):
+        ap.error("--decode-kernel fused requires a paged cache "
+                 "(--paged or --continuous)")
 
     cfg = configs.get_config(args.arch)
     if args.reduced:
@@ -407,7 +397,7 @@ def main(argv=None):
             (args.batch, cfg.n_visual_tokens, cfg.d_model)), jnp.float32)
 
     max_len = args.max_len or (args.prompt_len + args.gen)
-    engine = _build_engine(args, cfg, params, max_len)
+    engine = _build_engine(args, cfg, params, max_len, paged=args.paged)
 
     t0 = time.time()
     cache, logits, lens = engine.prefill(

@@ -425,37 +425,6 @@ def guarded_cache_update(arr, upd, idx, axis: int):
     return jnp.where(idx < arr.shape[axis], new, arr)
 
 
-def roll_cache_time(kv, shift):
-    """Circularly shift a stacked-layer KV time axis (L, B, T, ...) by
-    ``shift`` slots (traced shifts allowed).
-
-    This is the one primitive behind cache *compaction* and *admission*
-    in the continuous-batching scheduler, and it is correct for BOTH
-    cache layouts:
-
-    * linear caches: content occupying padded slots ``[len - l, len)``
-      moves to ``[len + shift - l, len + shift)``; slots vacated at
-      either end hold stale data that the per-row ``lens`` masks already
-      exclude (and that a later admission overwrites wholesale);
-    * ring buffers (capacity T, writes at ``pos % T``): a frontier move
-      of ``shift`` relabels slot ``q % T`` to ``(q + shift) % T`` — the
-      circular roll IS that relabelling, no second case needed.
-    """
-    return jnp.roll(kv, shift, axis=2)
-
-
-def reset_cache_rows(kv, row_mask, batch_axis: int = 1):
-    """Zero the given batch rows of a stacked cache leaf.
-
-    ``row_mask``: (B,) bool, True = clear.  Retired serving slots are
-    wiped so a freed row never leaks a previous request's KV into
-    reports or debugging dumps (attention already masks it out).
-    """
-    shape = [1] * kv.ndim
-    shape[batch_axis] = row_mask.shape[0]
-    return jnp.where(row_mask.reshape(shape), jnp.zeros_like(kv), kv)
-
-
 def pad_cache_time(kv, t: int):
     """Zero-pad the stacked-layer KV time axis (L,B,S,...) up to ``t`` —
     how prefill turns exactly-prompt-sized KV into a cache with decode
@@ -782,16 +751,15 @@ def paged_cache_update(arena, upd, tables, pos, ok, *, window: int = 0,
 
 
 def paged_pack(arena, kvs, tables, lens, *, window: int = 0,
-               src_shift=None, src_ring: bool = False):
+               src_shift=None):
     """Pack prompt KV (L, B, S, ...) into arena blocks (L, nb, bs, ...).
 
     Row b's content positions ``0..lens[b]-1`` land in the blocks named
     by ``tables[b]`` (sentinel entries drop their scatter — unallocated
     table tails carry garbage that never reaches the arena).  ``src_shift``
     (B,) gives the time-axis index of each row's content start in ``kvs``
-    (``S - lens`` for the engine's LEFT-padded prompt batches; default 0
-    for batch-1 right-padded caches); ``src_ring`` instead reads a
-    ring-layout source at ``pos % S``.  Window-lane tables pack only the
+    (``S - lens`` for the engine's LEFT-padded ragged prompt batches;
+    default 0, for batches whose prompts fill the time axis).  Window-lane tables pack only the
     ring's block span; slots whose positions precede the prompt (or fall
     out of the window) receive garbage that the attention masks exclude,
     exactly as the linear ring does.
@@ -805,9 +773,7 @@ def paged_pack(arena, kvs, tables, lens, *, window: int = 0,
     # the block-ring relabelling, so prefill and decode cannot skew)
     cpos = paged_positions(jnp.maximum(lens - 1, 0), w, bs,
                            window=window).reshape(b, w, bs)
-    if src_ring:
-        tpos = lax.rem(cpos, s)
-    elif src_shift is not None:
+    if src_shift is not None:
         tpos = cpos + jnp.asarray(src_shift, jnp.int32)[:, None, None]
     else:
         tpos = cpos

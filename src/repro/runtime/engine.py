@@ -178,11 +178,11 @@ class Engine:
     def n_compiles(self) -> int:
         """Distinct lowered programs this engine has compiled: the sum
         of every cached jit callable's trace-cache size, so per-shape
-        retraces INSIDE one callable count too (the unchunked prefill
-        path retraces per padded prompt length without ever missing the
-        engine's own jit cache).  The scheduler surfaces it in
-        ``Scheduler.stats``; chunked-prefill mode pins it flat after
-        warmup no matter how ragged the admitted prompt lengths are
+        retraces INSIDE one callable count too (``prefill`` retraces per
+        padded prompt length without ever missing the engine's own jit
+        cache).  The scheduler surfaces it in ``Scheduler.stats``; its
+        one ``mixed_step`` program keeps it flat after warmup no matter
+        how ragged the admitted prompt lengths are
         (tests/test_scheduler.py)."""
         n = 0
         for store in (self._prefill_jit, self._decode_jit):
@@ -262,23 +262,15 @@ class Engine:
         return tables, pool
 
     def prefill(self, prompts, *, frames=None, visual=None,
-                reserve_tokens: int = 0, paged=None):
+                reserve_tokens: int = 0):
         """Run the (possibly ragged) prompt batch; returns
         (cache, last-position logits (B,V), lens (B,)).
 
         On a paged engine, each row gets arena blocks covering its
         prompt plus ``reserve_tokens`` decode writes (``generate``
         reserves its whole budget up front so the one-scan decode never
-        needs new blocks); ``paged=False`` forces the dense linear
-        layout — the scheduler's admission path prefills rows linearly
-        and packs them into the shared pool arena itself.
+        needs new blocks).
         """
-        use_paged = self.paged if paged is None else bool(paged)
-        if use_paged and not self.paged:
-            raise ValueError(
-                "prefill(paged=True) needs an engine constructed with "
-                "Engine(..., paged=True): only that sizes the block "
-                "tables and arena")
         tokens, lens = self.pack_prompts(prompts)
         b, s = tokens.shape
         if s > self.max_len:
@@ -301,7 +293,7 @@ class Engine:
               if v is not None}
         args = [kw[k] for k in sorted(kw)]
         nb = 0
-        if use_paged:
+        if self.paged:
             nb = self.n_blocks or b * self.table_width
             tables, self.pool = self._alloc_tables(
                 lens, int(reserve_tokens), nb)
@@ -312,7 +304,7 @@ class Engine:
                                ragged, tuple(sorted(kw)), n_blocks=nb))
         cache, logits = self._dispatch(
             fn, self.params, jnp.asarray(tokens), jnp.asarray(lens), *args)
-        if use_paged:
+        if self.paged:
             cache = self.shard_cache(cache)
         return cache, logits, lens
 
@@ -325,8 +317,8 @@ class Engine:
         (rows with ``n_valid == 0`` are exact no-ops) followed by
         ``n_steps`` masked decode steps.  The shapes depend only on
         (batch, chunk width, n_steps) — never on any prompt length — so
-        a scheduler running in chunked mode compiles this ONCE and
-        serves every request with it.
+        the scheduler compiles this ONCE and serves every request with
+        it.
 
         Its module is ``jit_run``, a name no other engine program takes,
         so a profile finds the serving round by it; ``jax.named_scope``
@@ -490,79 +482,6 @@ class Engine:
             return cache, out.T, key
 
         return jax.jit(decode_scan)
-
-    def _chunk_fn(self, n_steps: int):
-        """Fixed-size decode chunk: ``n_steps`` masked decode steps in ONE
-        ``lax.scan`` — the continuous-batching quantum.  Jitted once per
-        chunk size, so admissions/retirements between chunks never
-        recompile anything."""
-        cfg, fam, temp = self.cfg, self.fam, self.temperature
-        masked = cfg.family in ("transformer", "hymba")
-
-        def chunk_scan(params, cache, tok, key, active):
-            def step(carry, _):
-                cache, tok, key = carry
-                if masked:
-                    logits, cache = fam.decode_step(params, cache, tok,
-                                                    cfg, active=active)
-                else:
-                    logits, cache = fam.decode_step(params, cache, tok, cfg)
-                nxt, key = sample_token(logits, key, temp)
-                return (cache, nxt, key), nxt
-
-            (cache, _, key), toks = lax.scan(
-                step, (cache, tok, key), length=n_steps)
-            return cache, toks.T, key                     # (B, n_steps)
-
-        return jax.jit(chunk_scan)
-
-    def decode_chunk(self, cache, tokens, n_steps: int, *, active=None):
-        """Advance every slot by ``n_steps`` decode steps in one compiled
-        dispatch; returns (cache, (B, n_steps) int32 sampled tokens).
-
-        ``tokens``: (B,) the last sampled token per row (admission seeds
-        this from the prefill logits).  ``active``: (B,) bool — inactive
-        (empty / already-finished) rows still run through the batched
-        model but their ``lens`` metadata stays frozen and their sampled
-        tokens are garbage the scheduler discards.
-
-        Raises if the chunk would run the write frontier past ``max_len``
-        — the frontier is concrete between dispatches, so the guard is
-        free, and without it the traced in-chunk writes would be silently
-        DROPPED (the no-clamp guarantee), corrupting the tokens.  Callers
-        (the scheduler) compact the cache first instead.
-        """
-        from repro.core.tracing import is_tracer
-        if "block_tables" in cache:
-            lens = cache["lens"]
-            if not is_tracer(lens):
-                act = np.ones((np.asarray(lens).shape[0],), bool) \
-                    if active is None else np.asarray(active, bool)
-                if act.any():
-                    hi = int(np.asarray(lens)[act].max())
-                    if hi + int(n_steps) > self.max_len:
-                        raise ValueError(
-                            f"decode_chunk: paged row frontier {hi} + "
-                            f"{int(n_steps)} steps exceeds engine "
-                            f"max_len {self.max_len}; retire rows first")
-        elif not is_tracer(cache["len"]) and \
-                int(cache["len"]) + int(n_steps) > self.max_len:
-            raise ValueError(
-                f"decode_chunk: frontier {int(cache['len'])} + "
-                f"{int(n_steps)} steps exceeds engine max_len "
-                f"{self.max_len}; compact the cache (kvcache.compact) "
-                "or retire rows first")
-        tokens = jnp.asarray(tokens, jnp.int32)
-        b = tokens.shape[0]
-        active = jnp.ones((b,), bool) if active is None \
-            else jnp.asarray(active, bool)
-        fn = self._get_jit(self._decode_jit, ("chunk", int(n_steps)),
-                           lambda: self._chunk_fn(int(n_steps)))
-        cache, toks, self._key = self._dispatch(
-            fn, self.params, cache, tokens, self._key, active)
-        if "block_tables" in cache:
-            cache = self.shard_cache(cache)
-        return cache, toks
 
     def _check_fits(self, padded_len: int, max_new_tokens: int):
         need = padded_len + max_new_tokens - 1        # last token not cached

@@ -1,125 +1,88 @@
-"""Continuous-batching request scheduler over the serving engine.
+"""Continuous-batching request scheduler over the paged serving engine.
 
-The engine (PR 3) decodes a *fixed* batch: every request prefills
-together and decodes together, so one long generation pins the whole
-batch and finished rows burn decode steps producing garbage.  Under
-ragged traffic that is exactly the goodput collapse continuous batching
-(Orca-style iteration-level scheduling) fixes: treat the preallocated
-cache's batch dimension as a SLOT POOL, retire rows the moment they
-finish, and prefill queued prompts into the freed rows between decode
-chunks.
+The engine's batch dimension is a SLOT POOL over one paged KV arena:
+rows retire the moment they finish and queued prompts take their slots
+between rounds (Orca-style iteration-level scheduling), so one long
+generation never pins the batch and finished rows burn no decode steps.
 
-Mechanics
----------
-* ``submit()`` enqueues a request (prompt + per-request ``max_new_tokens``
-  / ``eos_id``); ``step()`` runs one scheduling round:
+Round
+-----
+``submit()`` enqueues a request (prompt + per-request ``max_new_tokens``
+/ ``eos_id``); ``step()`` runs one scheduling round:
 
-      retire finished slots  ->  admit queued prompts into free slots
-      ->  ONE fixed-size decode chunk (a single compiled ``lax.scan``
-      dispatch whose shapes never change, so the DECODE path never
-      recompiles; unchunked admission prefill is jit-specialized per
-      prompt length — ``chunked_prefill=True`` deletes that
-      specialization entirely by feeding prompts through the decode
-      lane in fixed-size chunks)
+    admit (allocation only)  ->  extend / COW / sanitize the round's
+    write spans  ->  ONE ``Engine.mixed_step`` dispatch  ->  advance
+    prompt cursors, emit decode tokens, sample first tokens  ->  retire
 
-* admission prefills the prompt alone (batch 1 — byte-identical to what
-  an isolated ``Engine.generate`` would compute), samples the first
-  token from the prefill logits, then grafts the row into the pool with
-  ``kvcache.adopt_row``; the pool keeps ONE shared padded write frontier
-  (``cache['len']``) and per-row valid counts (``lens``), so each row's
-  RoPE positions and attention masks stay content-relative — a row
-  admitted at frontier 40 generates exactly the tokens it would have
-  generated alone (see ``tests/test_scheduler.py``).
-* retirement is ``kvcache.reset_slots`` (lens -> 0 + content wipe); the
-  shared frontier is pulled back by ``kvcache.compact`` whenever the next
-  chunk would not fit, so slot reuse never exhausts ``max_len``.
-* inactive rows ride along in the batched decode with frozen ``lens``
-  (``decode_step(active=...)``) and their sampled tokens are discarded.
+Admission by allocation: admitting a request only ALLOCATES its row
+(block table + ``lens`` cursor) from the host-side ``BlockPool``; no
+model dispatch happens there.  Each request's worst-case block demand
+is RESERVED at admission, so lazy per-round table extension can never
+find the pool empty; when a reservation does not fit, admission defers
+until retirements free blocks.  Retirement frees the row's blocks and
+sentinels its table (``kvcache.paged_release_rows``).  Peak cache
+memory is the blocks actually resident, not ``slots x max_len``.
 
-Paged mode (``Engine(paged=True)``) swaps the dense pool for the
-block-table layout and DELETES compaction from this loop entirely:
-addressing is row-local, so admission packs the prompt's KV into
-freshly allocated arena blocks (``kvcache.paged_adopt_row``) without
-touching any other row, retirement frees the row's blocks back to the
-host-side ``BlockPool``, and live rows lazily extend their tables
-between chunks.  Each request's worst-case block demand is RESERVED at
-admission, so extension can never find the pool empty; when a
-reservation does not fit, admission defers (FIFO) until retirements
-free blocks.  Peak cache memory is the blocks actually resident
-(``Σ tokens`` rounded up) instead of ``slots x max_len``, and the
-token streams are identical to the compaction scheduler's
-(``tests/test_paged.py``).
-
-Chunked prefill (``chunked_prefill=True``, paged only) deletes the
-whole-prompt prefill specialization path: admission only ALLOCATES a
-row (block table + ``lens = 0``), and the prompt then flows through the
-decode lane in fixed ``chunk_size``-token chunks — every scheduling
-round issues ONE ``Engine.mixed_step`` dispatch that runs a prefill
-chunk for every prefilling row (``T.prefill_chunk``, a no-op for rows
-with nothing to prefill) followed by the usual masked decode quantum
-for every decoding row.  The compiled shape depends only on
-``(n_slots, chunk_size)`` — never on any prompt length — so the engine
-compiles the serving loop ONCE and ``Engine.n_compiles`` stays flat no
-matter how ragged the admitted prompt lengths are (the
-recompile-per-prompt-length bug class, pinned in
-``tests/test_scheduler.py``).  A row that completes its prompt mid-
+Chunked prefill: the prompt flows through the decode lane in fixed
+``chunk_size``-token chunks.  Every round issues ONE ``mixed_step``
+dispatch that runs a prefill chunk for every prefilling row
+(``T.prefill_chunk``, a no-op for rows with nothing to prefill) followed
+by the masked decode quantum for every decoding row.  The compiled
+shape depends only on ``(n_slots, chunk_size)`` — never on a prompt
+length — so the engine compiles the serving loop ONCE and
+``Engine.n_compiles`` stays flat however ragged the prompts are
+(``tests/test_scheduler.py``).  A row that completes its prompt mid-
 round samples its first token from that chunk's last-valid-position
-logits and starts decoding the following round.  Greedy token streams
-are bitwise-identical to the unchunked scheduler's: ``prefill_chunk``
+logits and decodes from the next round on.  Greedy streams are
+bitwise-identical to isolated ``Engine.generate``: ``prefill_chunk``
 pads the KV length to fixed ``attn_chunk_kv`` blocks so the online-
-softmax reduction groups identically for every split of the same
-prompt (see ``models/layers.py``).
+softmax reduction groups identically for every split of a prompt (see
+``models/layers.py``).  Inactive rows ride along in the batched decode
+with frozen ``lens`` and their sampled tokens are discarded.
 
-Policy layer: ``submit(..., deadline=...)`` attaches an absolute
+EDF with preemption: ``submit(..., deadline=...)`` attaches an absolute
 sim-step deadline; admission is earliest-deadline-first (deadline-less
-requests sort last, FIFO among equals — with no deadlines in the queue
-the order is plain FIFO, keeping the PR 6 traces schedule-identical).
-When the EDF head cannot be admitted for lack of blocks, the scheduler
-PREEMPTS the active row with the LATEST deadline — only if strictly
-later than the candidate's, so best-effort never preempts best-effort
-and livelock is impossible — by releasing its blocks (refcount-safe:
-owned blocks are freed, borrowed prefix blocks decref'd, the prefix
-index keeps registered blocks resident) and requeueing the request
-from scratch.  Greedy decode makes the restart token-identical to an
-uninterrupted run (``tests/test_scheduler.py``); under ``sanitize``
-the released blocks are poisoned and the leak gauge stays zero.
+requests sort last, FIFO among equals, so with no deadlines the order
+is plain FIFO).  When the EDF head cannot be admitted for lack of
+blocks, the scheduler PREEMPTS the active row with the LATEST deadline
+— only if strictly later than the candidate's, so best-effort never
+preempts best-effort and livelock is impossible — by releasing its
+blocks (owned blocks are freed, borrowed prefix blocks decref'd, the
+prefix index keeps registered blocks resident) and requeueing the
+request from scratch.  Greedy decode makes the restart token-identical
+to an uninterrupted run; under ``sanitize`` the released blocks are
+poisoned and the leak gauge stays zero.
 
-Prefix caching (``prefix_cache=True``, implies chunked prefill)
-deduplicates shared prompt prefixes across requests: every
-fully-written prompt block is content-addressed in a
-:class:`kvcache.PrefixIndex` (rolling hash of its token ids, chained
-so a hash identifies the whole prefix up to that block), and admission
+Prefix caching (``prefix_cache=True``) deduplicates shared prompt
+prefixes: every fully-written prompt block is content-addressed in a
+:class:`kvcache.PrefixIndex` (rolling hash of its token ids, chained so
+a hash identifies the whole prefix up to that block), and admission
 first walks the index — matched leading blocks are BORROWED
-(``BlockPool.share``) instead of recomputed, and the chunk cursor
-starts AFTER them (``min(matched * block_size, plen - 1)``: matched
-blocks skip their chunks entirely; at least the last prompt token
-reruns because its logits seed the first sampled token).  Writes never
-land in a shared block: admission copy-on-writes the matched blocks
-the remaining chunks overlap, the per-round write tables sentinel
-every still-borrowed entry, and a pre-round pass COWs window-lane ring
-slots about to recycle a shared block.  The index holds one pool
-reference per registered block so prefixes survive their owner's
-retirement; index-only blocks (refcount 1) are evicted LRU-first when
-admission needs physical capacity.  Worst-case reservation stays
-sound: a sharer's debt is ``worst - owned`` minus the dense-lane
-borrowed blocks append-only writes can never touch, and window rows
-pre-reserve one COW per ring slot they may register (reserved at
-admission, settled when the fully-written prompt registers).  Greedy
-token streams are identical to the non-sharing paged path
-(``tests/test_prefix.py``) when the KV storage dtype is the compute
-dtype; with a posit KV codec the borrowed prefix is read back through
-the codec (exactly what decode reads), so logits past the prefix can
-differ in the last ulp from a from-scratch prefill's.
+(``BlockPool.share``) and the chunk cursor starts after them
+(``min(matched * block_size, plen - 1)``: at least the last prompt
+token reruns because its logits seed the first sampled token).  Writes
+never land in a shared block: admission copy-on-writes the matched
+blocks the remaining chunks overlap, the per-round write tables
+sentinel every still-borrowed entry, and a pre-round pass COWs
+window-lane ring slots about to recycle a shared block.  The index
+holds one pool reference per registered block so prefixes outlive
+their owner; index-only blocks (refcount 1) are evicted LRU-first when
+admission needs capacity.  A sharer's reservation is ``worst - owned``
+minus the dense-lane borrowed blocks append-only writes never touch;
+window rows pre-reserve one COW per ring slot they may register.
+Greedy streams match the non-sharing path (``tests/test_prefix.py``)
+when the KV storage dtype is the compute dtype; with a posit KV codec
+the borrowed prefix is read back through the codec, so logits past the
+prefix can differ in the last ulp from a from-scratch prefill's.
 
 Sampling: greedy decoding is deterministic and token-identical to
-isolated generation.  With ``temperature > 0`` the scheduler is still
-deterministic for a fixed seed, but the PRNG stream interleaves rows
-differently than isolated calls would, so per-request identity only
-holds for greedy.
+isolated generation.  With ``temperature > 0`` the PRNG stream
+interleaves rows differently than isolated calls would, so per-request
+identity only holds for greedy.
 
-Time is measured in *decode steps* (the simulation clock): wall-clock
-per step is constant for a fixed pool, so step-latency and goodput
-ratios transfer to hardware.
+Time is measured in *decode steps* (the simulation clock, ``steps_run``):
+deadlines and request latencies are in steps; real per-round wall time
+is in the ``sched.round`` spans.
 """
 from __future__ import annotations
 
@@ -134,8 +97,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.compress import kvcache as kvc
-from repro.models import get_family
 from repro.models import layers as L
+from repro.models import transformer as T
 from . import spans
 from .engine import Engine, sample_token
 
@@ -160,8 +123,8 @@ class Completion:
     arrival_step: int              # when the request was submitted
     admitted_step: int             # decode-step clock at admission
     finished_step: int             # decode-step clock when retired
-    # chunked-prefill mode: the (V,) f32 logits the first token was
-    # sampled from, what a one-shot prefill of the prompt is compared to
+    # the (V,) f32 logits the first token was sampled from, what a
+    # one-shot prefill of the prompt is compared to
     first_logits: Optional[np.ndarray] = None
 
     @property
@@ -179,8 +142,8 @@ class _Slot:
     emitted: list
     admitted_step: int
     done: bool = False
-    # chunked-prefill cursor: prompt positions already cached, or None
-    # once the whole prompt is in (always None in unchunked mode)
+    # prompt positions already cached, or None once the whole prompt
+    # is in
     cursor: Optional[int] = None
     first_logits: Optional[np.ndarray] = None
 
@@ -195,30 +158,35 @@ class _Slot:
 
 
 class Scheduler:
-    """Iteration-level (continuous) batching over an :class:`Engine`.
+    """Iteration-level (continuous) batching over a paged :class:`Engine`.
 
-    ``n_slots`` is the pool width (the compiled batch size), ``chunk_size``
-    the number of decode steps between scheduling decisions — and, in
-    chunked mode, also the prefill chunk width.  Larger chunks amortize
-    host work; smaller chunks admit/retire sooner.
-    ``chunked_prefill=True`` (paged engines only) routes prompts through
-    the decode lane in fixed-size chunks so ONE compiled dispatch shape
-    serves every request; ``prefix_cache=True`` (implies chunked
-    prefill) switches on content-addressed prefix sharing with
-    copy-on-write block tables — see the module docstring for the full
-    contract.
+    ``n_slots`` is the pool width (the compiled batch size),
+    ``chunk_size`` both the decode steps between scheduling decisions
+    and the prefill chunk width.  Larger chunks amortize host work;
+    smaller chunks admit/retire sooner.  ``prefix_cache=True`` switches
+    on content-addressed prefix sharing with copy-on-write block tables
+    — see the module docstring for the full contract.
     """
 
     def __init__(self, engine: Engine, *, n_slots: int,
                  chunk_size: int = 8, eos_id: Optional[int] = None,
                  prefix_cache: bool = False,
-                 chunked_prefill: bool = False):
+                 chunked_prefill: bool = True):
         if engine.cfg.family != "transformer":
             raise ValueError(
                 "continuous batching needs per-row decode positions, "
                 "which only the transformer family provides (got family="
                 f"{engine.cfg.family!r}); hymba/rwkv/whisper decode at a "
                 "shared absolute position")
+        if not engine.paged:
+            raise ValueError(
+                "the scheduler serves from a paged block arena: construct "
+                "the engine with Engine(paged=True)")
+        # accepted only for bench/program.py; goes with its next change
+        if not chunked_prefill:
+            raise ValueError(
+                "chunked_prefill=False: the scheduler has one mode, "
+                "chunked prefill through Engine.mixed_step")
         if chunk_size < 1:
             raise ValueError(f"chunk_size must be >= 1, got {chunk_size}")
         if n_slots < 1:
@@ -227,69 +195,45 @@ class Scheduler:
         self.n_slots = int(n_slots)
         self.chunk_size = int(chunk_size)
         self.eos_id = eos_id
-        self.paged = bool(getattr(engine, "paged", False))
         self.prefix_cache = bool(prefix_cache)
-        # prefix borrows are expressed as chunk-cursor skips, so sharing
-        # rides on the chunked machinery
-        self.chunked = bool(chunked_prefill) or self.prefix_cache
         # arena sanitizer: inherited from the engine so one flag arms
         # both halves (host-side BlockPool checks + device poisoning)
-        self.sanitize = bool(getattr(engine, "sanitize", False))
-        if self.prefix_cache and not self.paged:
-            raise ValueError(
-                "prefix_cache=True needs Engine(paged=True): sharing "
-                "is expressed through block-table entries")
-        if self.chunked and not self.paged:
-            raise ValueError(
-                "chunked_prefill=True needs Engine(paged=True): chunks "
-                "write through per-row block tables")
-        fam = get_family(engine.cfg)
-        if self.paged:
-            from repro.models import transformer as T
-            self.block_size = engine.block_size
-            self.table_width = engine.table_width
-            self.n_blocks = engine.n_blocks or \
-                self.n_slots * self.table_width
-            self.pool = kvc.BlockPool(self.n_blocks,
-                                      sanitize=self.sanitize)
-            # tensor-parallel engines place the arena head-sharded over
-            # 'model' here; one logical block id names one slice per
-            # shard, so the host-side pool/table bookkeeping below is
-            # identical with or without a mesh
-            self.cache = engine.shard_cache(T.init_paged_cache(
-                engine.cfg, self.n_slots, engine.max_len,
-                self.block_size, self.n_blocks))
-            self._window = T._paged_window(engine.cfg)
-            self._tables = np.full(
-                (self.n_slots, self.table_width), self.n_blocks, np.int32)
-            self._row_blocks: list = [[] for _ in range(self.n_slots)]
-            # borrowed table entries: slot index -> shared block id; the
-            # row holds one pool reference per entry but must COW before
-            # ever writing through it (empty unless prefix_cache)
-            self._row_borrowed: list = [{} for _ in range(self.n_slots)]
-            self._row_used = [0] * self.n_slots   # populated table slots
-            self._worst = [0] * self.n_slots
-            self._outstanding = 0      # reserved-but-unallocated blocks
-            # high-water mark of PHYSICAL allocated + reserved blocks:
-            # an arena of this size replays the same trace with zero
-            # deferrals (the benchmark's capacity-planning number).
-            # peak_logical is the same mark counting every reference —
-            # what a non-sharing pool would have needed; the gap is the
-            # prefix-dedup win.
-            self.peak_committed = 0
-            self.peak_logical = 0
-            # window+prefix rows reserve their registration COW head at
-            # admission; settled when the fully-written prompt registers
-            self._head_reserved = [0] * self.n_slots
-            if self.prefix_cache:
-                self.index = kvc.PrefixIndex()
-            self._adopt_paged = jax.jit(
-                kvc.paged_adopt_row,
-                static_argnames=("window", "src_ring"))
-            self._release = jax.jit(kvc.paged_release_rows)
-        else:
-            self.cache = fam.init_cache(engine.cfg, self.n_slots,
-                                        engine.max_len)
+        self.sanitize = engine.sanitize
+        self.block_size = engine.block_size
+        self.table_width = engine.table_width
+        self.n_blocks = engine.n_blocks or self.n_slots * self.table_width
+        self.pool = kvc.BlockPool(self.n_blocks, sanitize=self.sanitize)
+        # tensor-parallel engines place the arena head-sharded over
+        # 'model' here; one logical block id names one slice per shard,
+        # so the host-side pool/table bookkeeping below is identical
+        # with or without a mesh
+        self.cache = engine.shard_cache(T.init_paged_cache(
+            engine.cfg, self.n_slots, engine.max_len, self.block_size,
+            self.n_blocks))
+        self._window = T._paged_window(engine.cfg)
+        self._tables = np.full(
+            (self.n_slots, self.table_width), self.n_blocks, np.int32)
+        self._row_blocks: list = [[] for _ in range(self.n_slots)]
+        # borrowed table entries: slot index -> shared block id; the row
+        # holds one pool reference per entry but must COW before ever
+        # writing through it (empty unless prefix_cache)
+        self._row_borrowed: list = [{} for _ in range(self.n_slots)]
+        self._row_used = [0] * self.n_slots   # populated table slots
+        self._worst = [0] * self.n_slots
+        self._outstanding = 0          # reserved-but-unallocated blocks
+        # high-water mark of PHYSICAL allocated + reserved blocks: an
+        # arena of this size replays the same trace with zero deferrals
+        # (the capacity-planning number).  peak_logical is the same mark
+        # counting every reference — what a non-sharing pool would have
+        # needed; the gap is the prefix-dedup win.
+        self.peak_committed = 0
+        self.peak_logical = 0
+        # window+prefix rows reserve their registration COW head at
+        # admission; settled when the fully-written prompt registers
+        self._head_reserved = [0] * self.n_slots
+        if self.prefix_cache:
+            self.index = kvc.PrefixIndex()
+        self._release = jax.jit(kvc.paged_release_rows)
         # prefix-caching observability (stay zero without prefix_cache)
         self.prefill_tokens = 0        # tokens actually run through prefill
         self.prefix_hits = 0           # admissions that borrowed blocks
@@ -303,7 +247,6 @@ class Scheduler:
         self._slots: list = [None] * self.n_slots
         self._queue: deque = deque()
         self._cur_tok = np.zeros((self.n_slots,), np.int32)
-        self._frontier = 0             # host mirror of cache["len"]
         self._next_rid = 0
         self.steps_run = 0             # decode steps executed (sim clock)
         # spans (runtime/spans.py): this instance's id, the round
@@ -315,10 +258,6 @@ class Scheduler:
         self.n_admitted = 0
         self.n_retired = 0
         self.n_preempted = 0           # rows evicted for an earlier deadline
-        # cache-surgery ops, jitted once (shapes are fixed by the pool)
-        self._reset = jax.jit(kvc.reset_slots)
-        self._compact = jax.jit(lambda c, t: kvc.compact(c, t))
-        self._adopt = jax.jit(kvc.adopt_row)
 
     # ------------------------------------------------------------------
     # queue
@@ -354,17 +293,16 @@ class Scheduler:
                 f"{len(prompt)} + {max_new_tokens} new + chunk "
                 f"{self.chunk_size} headroom) > engine max_len "
                 f"{self.engine.max_len}")
-        if self.paged:
-            worst = self._worst_blocks(len(prompt), max_new_tokens)
-            if self.prefix_cache and self.engine.window_lane and \
-                    self._share_cap(len(prompt)):
-                # registered ring blocks each pre-reserve one COW copy
-                worst += len(prompt) // self.block_size
-            if worst > self.n_blocks:
-                raise ValueError(
-                    f"request needs up to {worst} cache blocks > block "
-                    f"pool capacity {self.n_blocks} (block_size "
-                    f"{self.block_size})")
+        worst = self._worst_blocks(len(prompt), max_new_tokens)
+        if self.prefix_cache and self.engine.window_lane and \
+                self._share_cap(len(prompt)):
+            # registered ring blocks each pre-reserve one COW copy
+            worst += len(prompt) // self.block_size
+        if worst > self.n_blocks:
+            raise ValueError(
+                f"request needs up to {worst} cache blocks > block "
+                f"pool capacity {self.n_blocks} (block_size "
+                f"{self.block_size})")
         rid = self._next_rid
         self._next_rid += 1
         t = spans.event("request.submit", sched=self.sched_id,
@@ -401,8 +339,8 @@ class Scheduler:
     @property
     def stats(self) -> dict:
         """Counters for one serving run — notably ``n_compiles``, the
-        engine's distinct-lowered-program count: flat after warmup in
-        chunked mode, growing with every new prompt length otherwise.
+        engine's distinct-lowered-program count, flat after warmup
+        whatever the prompt lengths.
         ``step_wall_p50_ms``/``step_wall_p99_ms`` are REAL per-round
         wall times (0.0 before the first round), read from this
         scheduler's ``sched.round`` spans still in the recorder's ring;
@@ -411,7 +349,7 @@ class Scheduler:
             [s.end_ns - s.start_ns for s in spans.snapshot()
              if s.name == "sched.round"
              and s.ids["sched"] == self.sched_id], np.float64) * 1e-6
-        d = dict(
+        return dict(
             n_admitted=self.n_admitted, n_retired=self.n_retired,
             n_preempted=self.n_preempted, n_chunks=self.n_chunks,
             steps_run=self.steps_run,
@@ -424,20 +362,13 @@ class Scheduler:
             prefix_matched_tokens=self.prefix_matched_tokens,
             n_cow=self.n_cow, n_evicted=self.n_evicted,
             n_leaked=self.n_leaked,
-            n_compiles=self.engine.n_compiles)
-        if self.paged:
-            d.update(peak_committed=self.peak_committed,
-                     peak_logical=self.peak_logical)
-        return d
+            n_compiles=self.engine.n_compiles,
+            peak_committed=self.peak_committed,
+            peak_logical=self.peak_logical)
 
     # ------------------------------------------------------------------
     # scheduling round
     # ------------------------------------------------------------------
-
-    def _set_frontier(self, target: int):
-        if target != self._frontier:
-            self.cache = self._compact(self.cache, jnp.int32(target))
-            self._frontier = int(target)
 
     # -- paged block accounting ----------------------------------------
 
@@ -549,47 +480,8 @@ class Scheduler:
             self.peak_logical,
             self.pool.logical_in_use + self._outstanding)
 
-    def _admit_paged(self, req: Request, row: int):
-        """Unchunked paged admission: whole-prompt linear prefill +
-        block adoption (prefix caching never reaches here — it implies
-        chunked mode)."""
-        plen = len(req.prompt)
-        worst = self._worst_blocks(plen, req.max_new_tokens)
-        # reservation check: extension draws must never find the pool
-        # empty
-        if self.pool.n_free - self._outstanding < worst:
-            return False               # wait for retirements' blocks
-        # batch-1 LINEAR prefill: the same jitted path (and therefore
-        # the same KV values) an isolated Engine.generate would run;
-        # the prompt is then packed into freshly allocated blocks —
-        # admission never moves other rows (nothing to compact)
-        row_cache, logits, _ = self.engine.prefill([req.prompt],
-                                                   paged=False)
-        now = self.table_width if self.engine.window_lane else \
-            -(-plen // self.block_size)
-        ids = self.pool.alloc(now)
-        block_ids = np.full((self.table_width,), self.n_blocks, np.int32)
-        block_ids[:now] = ids
-        cap = min(self.engine.max_len, self._window) if self._window \
-            else self.engine.max_len
-        self.cache = self.engine.shard_cache(self._adopt_paged(
-            self.cache, row_cache, jnp.int32(row),
-            jnp.asarray(block_ids), window=self._window,
-            src_ring=plen > cap))
-        self._tables[row] = block_ids
-        self._row_blocks[row] = ids
-        self._row_borrowed[row] = {}
-        self._row_used[row] = now
-        self._worst[row] = worst
-        self._outstanding += worst - now
-        self.prefill_tokens += plen
-        self._note_peaks()
-        tok0, self.engine._key = sample_token(
-            logits, self.engine._key, self.engine.temperature)
-        return int(np.asarray(tok0)[0])
-
-    def _admit_chunked(self, req: Request, row: int):
-        """Chunked admission: ALLOCATE only — block table, ``lens``
+    def _admit_row(self, req: Request, row: int):
+        """Admission: ALLOCATE only — block table, ``lens``
         cursor, prefix borrows.  No model dispatch happens here; the
         prompt flows through ``mixed_step`` chunks in subsequent
         rounds.  Returns the starting chunk cursor (0, or past the
@@ -800,6 +692,18 @@ class Scheduler:
         self._slots[i] = None
         self.n_preempted += 1
         self._phase(slot.req.rid, "request.queued", preempted=1)
+        reclaimed = self._drop_row(i)
+        mask = np.zeros((self.n_slots,), bool)
+        mask[i] = True
+        self._release_rows(mask, reclaimed)
+        self._queue.append(slot.req)   # original arrival_step preserved
+
+    def _drop_row(self, i: int) -> list:
+        """Drop row ``i``'s block references: owned blocks physically
+        reclaim unless the prefix index still holds them, borrowed
+        blocks just decref back to their other owners.  Returns the
+        reclaimed ids; :meth:`_release_rows` then releases the device
+        row."""
         self._outstanding -= self._row_debt(i)
         reclaimed = self.pool.free(self._row_blocks[i])
         if self._row_borrowed[i]:
@@ -811,16 +715,22 @@ class Scheduler:
         self._worst[i] = 0
         self._head_reserved[i] = 0
         self._tables[i] = self.n_blocks          # sentinel
-        mask = np.zeros((self.n_slots,), bool)
-        mask[i] = True
+        return reclaimed
+
+    def _release_rows(self, rows, reclaimed: list):
+        """Device half of dropping ``rows`` ((n_slots,) bool): ``lens``
+        -> 0 and sentinel tables.  Freed arena blocks are overwritten
+        wholesale on reuse, nothing to wipe — except under the
+        sanitizer, which poisons ``reclaimed`` so a stale table entry
+        detonates instead of silently serving freed KV, and refreshes
+        the leak gauge."""
         self.cache = self.engine.shard_cache(
-            self._release(self.cache, jnp.asarray(mask)))
+            self._release(self.cache, jnp.asarray(rows)))
         if self.sanitize:
             if reclaimed:
                 self.cache = self.engine.poison_blocks(
                     self.cache, reclaimed)
             self.n_leaked = len(self.leak_report())
-        self._queue.append(slot.req)   # original arrival_step preserved
 
     def _try_preempt(self, req: Request) -> bool:
         """Preemption-by-block-release: when the EDF head cannot be
@@ -828,8 +738,6 @@ class Scheduler:
         if strictly later than the candidate's (best-effort rows count
         as latest), so best-effort never preempts best-effort and the
         loop cannot livelock."""
-        if not self.paged:
-            return False
         cd = float("inf") if req.deadline is None else req.deadline
         victim, vd_max = None, cd
         for i, s in enumerate(self._slots):
@@ -861,59 +769,21 @@ class Scheduler:
         while self._queue and free:
             req = self._queue[0]
             row = free[0]
-            if self.chunked:
-                cursor = self._admit_chunked(req, row)
-                if cursor is None:     # pool cannot cover the request yet
-                    if self._try_preempt(req):
-                        self._order_queue()
-                        free = [i for i, s in enumerate(self._slots)
-                                if s is None]
-                        continue
-                    break              # EDF: do not admit around the head
-                self._queue.popleft()
-                free.remove(row)
-                self._slots[row] = _Slot(
-                    req=req, emitted=[],
-                    admitted_step=self.steps_run, cursor=cursor)
-                self.n_admitted += 1
-                self._phase(req.rid, "request.prefill")
-                continue
-            t_admit = spans.now_ns()
-            if self.paged:
-                tok0 = self._admit_paged(req, row)
-                if tok0 is False:      # pool cannot cover the request yet
-                    if self._try_preempt(req):
-                        self._order_queue()
-                        free = [i for i, s in enumerate(self._slots)
-                                if s is None]
-                        continue
-                    break              # EDF: do not admit around the head
-            else:
-                plen = len(req.prompt)
-                # batch-1 prefill: the same jitted path (and therefore
-                # the same KV bytes) an isolated Engine.generate would
-                # run
-                row_cache, logits, _ = self.engine.prefill([req.prompt])
-                tok0, self.engine._key = sample_token(
-                    logits, self.engine._key, self.engine.temperature)
-                tok0 = int(np.asarray(tok0)[0])
-                if plen > self._frontier:  # long prompt: raise frontier
-                    self._set_frontier(plen)
-                self.cache = self._adopt(self.cache, row_cache,
-                                         jnp.int32(row))
+            cursor = self._admit_row(req, row)
+            if cursor is None:         # pool cannot cover the request yet
+                if self._try_preempt(req):
+                    self._order_queue()
+                    free = [i for i, s in enumerate(self._slots)
+                            if s is None]
+                    continue
+                break                  # EDF: do not admit around the head
             self._queue.popleft()
             free.remove(row)
-            # the whole prompt prefilled and tok0 sampled just now
-            self._phase(req.rid, "request.prefill", at=t_admit)
-            self._phase(req.rid, "request.decode", prefill_rounds=1)
-            slot = _Slot(req=req, emitted=[tok0],
-                         admitted_step=self.steps_run)
-            # a request can finish on its very first (prefill) token
-            if tok0 == req.eos_id or req.max_new_tokens == 1:
-                slot.done = True
-            self._slots[row] = slot
-            self._cur_tok[row] = tok0
+            self._slots[row] = _Slot(
+                req=req, emitted=[],
+                admitted_step=self.steps_run, cursor=cursor)
             self.n_admitted += 1
+            self._phase(req.rid, "request.prefill")
 
     def leak_report(self) -> set:
         """Sanitizer leak accounting: allocated block ids unreachable
@@ -922,8 +792,6 @@ class Scheduler:
         without ``free``/``release`` — those blocks can never be
         reclaimed.  Valid to call any time; ``_retire`` refreshes the
         ``n_leaked`` gauge from it."""
-        if not self.paged:
-            return set()
         held: set = set()
         for ids in self._row_blocks:
             held.update(int(b) for b in ids)
@@ -952,38 +820,9 @@ class Scheduler:
             self._slots[i] = None
             self.n_retired += 1
             self._phase(req.rid, None, tokens=len(slot.emitted))
-            if self.paged:
-                # drop the row's references: owned blocks physically
-                # reclaim unless the prefix index still holds them;
-                # borrowed blocks just decref back to their other owners
-                self._outstanding -= self._row_debt(i)
-                reclaimed += self.pool.free(self._row_blocks[i])
-                if self._row_borrowed[i]:
-                    reclaimed += self.pool.release(
-                        list(self._row_borrowed[i].values()))
-                self._row_blocks[i] = []
-                self._row_borrowed[i] = {}
-                self._row_used[i] = 0
-                self._worst[i] = 0
-                self._head_reserved[i] = 0
-                self._tables[i] = self.n_blocks          # sentinel
+            reclaimed += self._drop_row(i)
         if done_mask.any():
-            if self.paged:
-                # lens -> 0 + sentinel tables; freed arena blocks are
-                # overwritten wholesale on reuse, nothing to wipe —
-                # except under the sanitizer, which poisons them so a
-                # stale table entry detonates instead of silently
-                # serving freed KV
-                self.cache = self.engine.shard_cache(
-                    self._release(self.cache, jnp.asarray(done_mask)))
-                if self.sanitize:
-                    if reclaimed:
-                        self.cache = self.engine.poison_blocks(
-                            self.cache, reclaimed)
-                    self.n_leaked = len(self.leak_report())
-            else:
-                self.cache = self._reset(self.cache,
-                                         jnp.asarray(done_mask))
+            self._release_rows(done_mask, reclaimed)
         return completions
 
     def _decode_reads(self, decode_active) -> dict:
@@ -1015,8 +854,8 @@ class Scheduler:
         return dict(decode_steps=n, kv_live_positions=int(live.sum()),
                     kv_read_positions=self.n_slots * int(width.sum()))
 
-    def _step_chunked(self, rnd):
-        """One chunked scheduling round: admit (allocation only) ->
+    def _step(self, rnd):
+        """One scheduling round's body: admit (allocation only) ->
         extend/COW/sanitize for the combined prefill+decode write spans
         -> ONE ``mixed_step`` dispatch (a prefill chunk for every
         prefilling row, the decode quantum for every decoding row —
@@ -1121,16 +960,15 @@ class Scheduler:
         """One scheduling round; returns the requests completed in it.
 
         Order: admit queued prompts into free slots (EDF over any
-        deadlines, FIFO otherwise; a paged admission defers until
+        deadlines, FIFO otherwise; an admission defers until
         ``n_free + evictable - outstanding`` covers its worst-case
         block demand, preempting a strictly-later-deadline row if that
         unblocks the head) -> extend live dense rows' tables / COW
         window-lane ring slots about to recycle a shared block -> ONE
-        fixed-size decode chunk (single compiled dispatch, shapes never
-        change; in chunked mode the dispatch also carries every
-        prefilling row's prompt chunk) -> retire finished rows (decref
-        their blocks; prefix-registered blocks stay resident under the
-        index's reference).  Invariants pinned by tests: greedy token
+        ``mixed_step`` dispatch (shapes never change: every prefilling
+        row's prompt chunk, then the decode quantum) -> retire finished
+        rows (decref their blocks; prefix-registered blocks stay
+        resident under the index's reference).  Invariants pinned by tests: greedy token
         streams identical to isolated generation and to the
         non-sharing paged path; writes reach a block only while its
         refcount is 1; reservation never lets extension or COW find
@@ -1142,7 +980,7 @@ class Scheduler:
         ``sched.emit``, ``sched.first_tokens``, ``sched.retire``) and
         whose counters say what it did; ``compiled`` is the engine's
         new compiles in the round, non-zero only on a round that
-        compiled.  A chunked round also counts its decode attention's
+        compiled.  Every round also counts its decode attention's
         work (``decode_steps``, ``kv_live_positions``,
         ``kv_read_positions``; :meth:`_decode_reads`).  ``stats`` reads
         the round spans' p50/p99."""
@@ -1152,8 +990,7 @@ class Scheduler:
                       self.engine.n_compiles)
             rnd.set(round=self._round, prefill_rows=0, decode_rows=0,
                     tokens_emitted=0, first_tokens=0)
-            done = self._step_chunked(rnd) if self.chunked \
-                else self._step_unchunked(rnd)
+            done = self._step(rnd)
             rnd.set(admitted=self.n_admitted - before[0],
                     retired=self.n_retired - before[1],
                     prefill_tokens=self.prefill_tokens - before[2],
@@ -1161,63 +998,6 @@ class Scheduler:
                     compiled=self.engine.n_compiles - before[3])
         self._round += 1
         return done
-
-    def _step_unchunked(self, rnd):
-        n0 = self.n_admitted
-        with spans.span("sched.admit"):
-            self._admit()
-        # every admission prefilled its whole prompt and sampled tok0
-        admitted = self.n_admitted - n0
-        rnd.set(prefill_rows=admitted, first_tokens=admitted,
-                tokens_emitted=admitted)
-        active = np.array(
-            [s is not None and not s.done for s in self._slots], bool)
-        if not active.any():
-            # admissions can complete instantly (EOS on the prefill
-            # token); surface those without burning a decode chunk
-            with spans.span("sched.retire"):
-                return self._retire()
-
-        with spans.span("sched.prepare"):
-            if self.paged:
-                # no shared frontier: rows extend their own block tables
-                self._ensure_blocks()
-                if self.sanitize:
-                    self._sanitize_check_chunk()
-            elif self._frontier + self.chunk_size > self.engine.max_len:
-                # reclaim headroom freed by retirements / short rows
-                target = max(s.lens for s in self._slots
-                             if s is not None and not s.done)
-                self._set_frontier(target)
-        with spans.span("engine.dispatch"):
-            self.cache, toks = self.engine.decode_chunk(
-                self.cache, self._cur_tok, self.chunk_size,
-                active=jnp.asarray(active))
-        with spans.span("sched.device_wait"):
-            toks = np.asarray(toks)
-        if not self.paged:
-            self._frontier += self.chunk_size  # mirror of cache["len"]
-        self.steps_run += self.chunk_size
-        self.n_chunks += 1
-
-        emitted = 0
-        with spans.span("sched.emit"):
-            for i in np.nonzero(active)[0]:
-                slot = self._slots[i]
-                req = slot.req
-                n = len(slot.emitted)
-                for t in toks[i]:
-                    slot.emitted.append(int(t))
-                    if int(t) == req.eos_id or \
-                            len(slot.emitted) >= req.max_new_tokens:
-                        slot.done = True
-                        break
-                emitted += len(slot.emitted) - n
-                self._cur_tok[i] = toks[i, -1]
-        rnd.set(decode_rows=int(active.sum()),
-                tokens_emitted=admitted + emitted)
-        with spans.span("sched.retire"):
-            return self._retire()
 
     def run(self, max_rounds: Optional[int] = None):
         """Drain queue + slots; returns ``{rid: Completion}``."""

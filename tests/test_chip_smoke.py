@@ -14,6 +14,8 @@ import sys
 
 import pytest
 
+import jax
+
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 sys.path.insert(0, ROOT)
 
@@ -27,8 +29,14 @@ TINY = dict(arch="minicpm3-4b", layers=0, reduced=True, slots=2,
 @pytest.fixture
 def no_persistent_cache(monkeypatch, tmp_path):
     """``serve.main`` enables the persistent compilation cache; point the
-    variable it honours elsewhere so this test process keeps none."""
+    variable it honours elsewhere so this test process keeps none, and
+    restore the location flag ``enable()`` turns off, which would
+    otherwise drop the named scopes from later tests' op metadata."""
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    flag = "jax_include_full_tracebacks_in_locations"
+    was = getattr(jax.config, flag)
+    yield
+    jax.config.update(flag, was)
 
 
 def test_refuses_to_run_without_a_tpu(capsys):

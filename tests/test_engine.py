@@ -299,56 +299,83 @@ def test_scan_decode_64_steps_matches_stepwise_in_one_compiled_call():
     assert step_calls["n"] == 63, step_calls
 
 
-def test_decode_chunk_concatenation_matches_one_scan():
-    """Two 4-step decode chunks seeded from the prefill token must emit
-    exactly what generate's single 9-token scan emits — the equivalence
-    the continuous-batching scheduler is built on."""
-    cfg = _dense_cfg()
+def _idle_round(b, c=4):
+    """``mixed_step`` inputs for a round in which no row prefills."""
+    return np.zeros((b, c), np.int32), np.zeros((b,), np.int32)
+
+
+@pytest.mark.parametrize("lane", ["dense", "mla"])
+def test_mixed_step_decode_rounds_match_one_scan(lane):
+    """Two ``mixed_step`` rounds with no prefilling row, seeded from the
+    prefill token, must emit exactly what generate's single 9-token scan
+    emits — the equivalence the scheduler's decode quantum rests on."""
+    cfg = _dense_cfg() if lane == "dense" else _mla_cfg()
     params = _params(cfg, seed=11)
     rng = np.random.default_rng(11)
     prompts = rng.integers(1, cfg.vocab, (2, 6))
+    ref = Engine(cfg, params, max_len=24, seed=0).generate(prompts, 9).tokens
 
-    eng = Engine(cfg, params, max_len=24, seed=0)
-    ref = eng.generate(prompts, 9).tokens                # tok0 + 8 decoded
-
-    eng2 = Engine(cfg, params, max_len=24, seed=0)
-    cache, logits, _ = eng2.prefill(prompts)
+    eng = Engine(cfg, params, max_len=24, seed=0, paged=True, block_size=4)
+    cache, logits, _ = eng.prefill(prompts, reserve_tokens=8)
     tok0 = np.asarray(jnp.argmax(logits, -1), np.int32)
-    cache, c1 = eng2.decode_chunk(cache, tok0, 4)
-    cache, c2 = eng2.decode_chunk(cache, np.asarray(c1)[:, -1], 4)
+    chunk, nv = _idle_round(2)
+    act = np.ones((2,), bool)
+    cache, _, c1 = eng.mixed_step(cache, chunk, nv, tok0, 4,
+                                  decode_active=act)
+    cache, _, c2 = eng.mixed_step(cache, chunk, nv, np.asarray(c1)[:, -1],
+                                  4, decode_active=act)
     got = np.concatenate([tok0[:, None], np.asarray(c1),
                           np.asarray(c2)], axis=1)
     np.testing.assert_array_equal(got, ref)
 
 
-def test_decode_chunk_active_mask_freezes_inactive_lens():
-    """Inactive rows ride along in the batch but their ``lens`` metadata
-    must not advance (otherwise empty slots pin the compaction frontier)."""
+def test_mixed_step_decode_active_freezes_inactive_lens():
+    """Rows outside ``decode_active`` ride along in the batch but their
+    ``lens`` must not advance (their writes are dropped)."""
     cfg = _dense_cfg()
     params = _params(cfg, seed=12)
     rng = np.random.default_rng(12)
-    prompts = rng.integers(1, cfg.vocab, (2, 5))
-    eng = Engine(cfg, params, max_len=24, seed=0)
-    cache, logits, _ = eng.prefill(prompts)
+    eng = Engine(cfg, params, max_len=24, seed=0, paged=True, block_size=4)
+    cache, logits, _ = eng.prefill(rng.integers(1, cfg.vocab, (2, 5)),
+                                   reserve_tokens=3)
     tok0 = np.asarray(jnp.argmax(logits, -1), np.int32)
-    cache, _ = eng.decode_chunk(cache, tok0, 3,
-                                active=np.array([True, False]))
+    chunk, nv = _idle_round(2)
+    cache, _, _ = eng.mixed_step(cache, chunk, nv, tok0, 3,
+                                 decode_active=np.array([True, False]))
     assert np.asarray(cache["lens"]).tolist() == [8, 5]
-    assert int(cache["len"]) == 8        # the shared frontier still moves
 
 
-def test_decode_chunk_refuses_to_run_past_max_len():
-    """A chunk that would push the frontier past max_len must raise —
-    the traced in-chunk writes would otherwise be silently dropped."""
+def test_mixed_step_refuses_a_prefill_chunk_past_max_len():
+    """A prefill chunk that would write past max_len must raise before
+    dispatch — the traced writes would otherwise be silently dropped."""
     cfg = _dense_cfg()
     params = _params(cfg, seed=13)
     rng = np.random.default_rng(13)
-    eng = Engine(cfg, params, max_len=12, seed=0)
-    cache, logits, _ = eng.prefill(rng.integers(1, cfg.vocab, (1, 6)))
+    eng = Engine(cfg, params, max_len=12, seed=0, paged=True, block_size=4)
+    cache, _, _ = eng.prefill(rng.integers(1, cfg.vocab, (1, 9)))
+    chunk = rng.integers(1, cfg.vocab, (1, 4)).astype(np.int32)
+    tok = np.zeros((1,), np.int32)
+    eng.mixed_step(cache, chunk, np.array([3]), tok, 1)   # 9 + 3 = 12 fits
+    with pytest.raises(ValueError, match="prefill chunk frontier 13"):
+        eng.mixed_step(cache, chunk, np.array([4]), tok, 1)
+
+
+def test_mixed_step_refuses_decode_steps_past_max_len():
+    """A decode quantum that would run a decoding row past max_len must
+    raise before dispatch."""
+    cfg = _dense_cfg()
+    params = _params(cfg, seed=13)
+    rng = np.random.default_rng(13)
+    eng = Engine(cfg, params, max_len=12, seed=0, paged=True, block_size=4)
+    cache, logits, _ = eng.prefill(rng.integers(1, cfg.vocab, (1, 6)),
+                                   reserve_tokens=6)
     tok0 = np.asarray(jnp.argmax(logits, -1), np.int32)
-    cache, _ = eng.decode_chunk(cache, tok0, 6)       # 6 + 6 = 12 fits
-    with pytest.raises(ValueError, match="max_len"):
-        eng.decode_chunk(cache, tok0, 1)              # 13 > 12
+    chunk, nv = _idle_round(1)
+    act = np.ones((1,), bool)
+    cache, _, _ = eng.mixed_step(cache, chunk, nv, tok0, 6,
+                                 decode_active=act)       # 6 + 6 = 12 fits
+    with pytest.raises(ValueError, match="decode frontier 12"):
+        eng.mixed_step(cache, chunk, nv, tok0, 1, decode_active=act)
 
 
 def test_ragged_batch_matches_singleton_generations():

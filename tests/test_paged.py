@@ -6,10 +6,10 @@ be invisible to the tokens.  Pinned here on all three transformer
 attention lanes (dense, MLA, sliding-window — where the ring buffer
 becomes block recycling), through ``Engine.generate`` (scan AND the
 per-step reference loop) and through the continuous-batching scheduler,
-which must reproduce the compaction scheduler's streams token-for-token
-and schedule-for-schedule while the arena reports strictly fewer bytes
-than ``slots x max_len``.  Plus the ``BlockPool`` allocator invariants
-(no leak / double-alloc / over-capacity on random traces) and the
+which must reproduce each request's isolated stream token-for-token
+while admissions defer on a tight arena and released rows hand their
+slots on.  Plus the ``BlockPool`` allocator invariants (no leak /
+double-alloc / over-capacity on random traces), row release, and the
 explicit pattern/metadata leaf tagging.
 """
 import dataclasses
@@ -264,53 +264,13 @@ def test_paged_generate_token_identity_posit_kv():
 
 
 # ---------------------------------------------------------------------------
-# paged scheduler == compaction scheduler, with fewer cache bytes
+# the scheduler on a paged arena
 # ---------------------------------------------------------------------------
 
 def _run_sched(sched, prompts, gens):
     rids = [sched.submit(p, g) for p, g in zip(prompts, gens)]
     done = sched.run(max_rounds=200)
     return rids, done
-
-
-@pytest.mark.parametrize("lane", ["dense", "window"])
-def test_paged_scheduler_matches_compaction_scheduler(lane):
-    """Same submissions through a two-slot pool: the paged scheduler
-    (no ``compact`` anywhere) must match the PR 4 compaction scheduler
-    token-for-token AND step-for-step, while its arena — sized below
-    ``slots x table_width`` — reports strictly fewer cache bytes than
-    the dense pool."""
-    cfg = _cfg(lane)
-    params = _params(cfg)
-    rng = np.random.default_rng(3)
-    plens = [5, 9, 3, 7, 4, 6]
-    gens = [4, 8, 4, 8, 4, 8]
-    prompts = [rng.integers(1, cfg.vocab, n).tolist() for n in plens]
-
-    lin = Scheduler(Engine(cfg, params, max_len=32, seed=0),
-                    n_slots=2, chunk_size=4)
-    rids_l, done_l = _run_sched(lin, prompts, gens)
-
-    nb = 10 if lane == "dense" else 0    # dense: strictly below 2*8 worst
-    # sanitize=True: the full arena sanitizer (pre-chunk check_read/
-    # check_write gates + poisoning of reclaimed blocks) must be
-    # invisible to the tokens AND report a leak-free trace
-    pag = Scheduler(Engine(cfg, params, max_len=32, seed=0, paged=True,
-                           block_size=4, n_blocks=nb, sanitize=True),
-                    n_slots=2, chunk_size=4)
-    rids_p, done_p = _run_sched(pag, prompts, gens)
-
-    for a, b in zip(rids_l, rids_p):
-        np.testing.assert_array_equal(done_p[b].tokens, done_l[a].tokens)
-        assert done_p[b].admitted_step == done_l[a].admitted_step
-        assert done_p[b].finished_step == done_l[a].finished_step
-    if lane == "dense":
-        assert kvc.cache_report(pag.cache)["bytes"] < \
-            kvc.cache_report(lin.cache)["bytes"]
-    # no block leaked once everything retired
-    assert pag.pool.in_use == 0 and pag._outstanding == 0
-    assert pag.n_leaked == 0 and not pag.leak_report()
-    assert pag.pool.n_sanitizer_checks > 0    # the gates actually ran
 
 
 def test_paged_scheduler_defers_admission_when_pool_is_tight():
@@ -335,6 +295,51 @@ def test_paged_scheduler_defers_admission_when_pool_is_tight():
     for rid, ref in zip(rids, refs):
         np.testing.assert_array_equal(done[rid].tokens, ref)
     assert sched.pool.peak_in_use <= 5
+
+
+def test_stats_always_carry_the_committed_peak():
+    """``peak_committed`` (allocated + reserved blocks at the high-water
+    mark) is in every scheduler's stats, never above the arena, and
+    without prefix sharing equals the logical peak."""
+    cfg = _cfg("dense")
+    params = _params(cfg)
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(1, cfg.vocab, n).tolist() for n in (5, 9, 3)]
+    sched = Scheduler(Engine(cfg, params, max_len=32, seed=0, paged=True,
+                             block_size=4, n_blocks=8),
+                      n_slots=2, chunk_size=4)
+    assert sched.stats["peak_committed"] == 0
+    _run_sched(sched, prompts, [4, 8, 4])
+    st = sched.stats
+    assert 0 < st["peak_committed"] <= sched.n_blocks == 8
+    assert st["peak_logical"] == st["peak_committed"]
+    assert st["peak_committed"] >= sched.pool.peak_in_use
+
+
+def test_slot_reuse_reads_none_of_the_previous_rows_kv():
+    """One slot, two requests, sanitizer armed, and an arena of exactly
+    the first request's worst case: the second is admitted into the
+    slot and onto the blocks the first just left, whose reclaimed
+    blocks were poisoned.  It must reproduce its isolated first-token
+    logits and stream — any read of the old row's KV would show — and
+    no block leaks."""
+    cfg = _cfg("dense")
+    params = _params(cfg)
+    rng = np.random.default_rng(13)
+    p_a = rng.integers(1, cfg.vocab, 9).tolist()
+    p_b = rng.integers(1, cfg.vocab, 5).tolist()
+    ref = Engine(cfg, params, max_len=32, seed=0).generate([p_b], 6)
+
+    sched = Scheduler(Engine(cfg, params, max_len=32, seed=0, paged=True,
+                             block_size=4, n_blocks=5, sanitize=True),
+                      n_slots=1, chunk_size=4)
+    (ra, rb), done = _run_sched(sched, [p_a, p_b], [8, 6])
+    assert done[rb].admitted_step >= done[ra].finished_step
+    np.testing.assert_allclose(done[rb].first_logits,
+                               ref.prefill_logits[0], rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(done[rb].tokens, ref.tokens[0])
+    assert sched.n_leaked == 0 and not sched.leak_report()
+    assert sched.pool.n_sanitizer_checks > 0
 
 
 def test_paged_scheduler_rejects_request_larger_than_pool():
@@ -384,6 +389,29 @@ def test_paged_sentinel_tables_drop_writes():
     assert int(after["lens"][0]) == 0        # frozen, not advanced
 
 
+def test_release_rows_touches_only_the_released_rows():
+    """``paged_release_rows`` zeroes ``lens`` and sentinels the tables of
+    the masked rows only; the other rows and the arena content (not
+    wiped: reclaimed blocks are overwritten on reuse) are untouched."""
+    cfg = _cfg("dense")
+    params = _params(cfg)
+    rng = np.random.default_rng(14)
+    eng = Engine(cfg, params, max_len=16, seed=0, paged=True, block_size=4)
+    cache, _, lens = eng.prefill(
+        [rng.integers(1, cfg.vocab, n).tolist() for n in (5, 7, 3)],
+        reserve_tokens=4)
+    out = kvc.paged_release_rows(cache, jnp.asarray([True, False, True]))
+    tables, before = np.asarray(out["block_tables"]), \
+        np.asarray(cache["block_tables"])
+    sentinel = cache["k"].shape[1]
+    assert np.asarray(out["lens"]).tolist() == [0, int(lens[1]), 0]
+    assert (tables[[0, 2]] == sentinel).all()
+    np.testing.assert_array_equal(tables[1], before[1])
+    assert (before[[0, 2]] != sentinel).any()
+    np.testing.assert_array_equal(np.asarray(out["k"]),
+                                  np.asarray(cache["k"]))
+
+
 # ---------------------------------------------------------------------------
 # explicit pattern/metadata leaf tagging
 # ---------------------------------------------------------------------------
@@ -419,26 +447,6 @@ def test_unknown_unsigned_leaf_raises_instead_of_guessing():
                            "posit16")
 
 
-def test_prefill_paged_override_needs_paged_engine():
-    cfg = _cfg("dense")
-    params = _params(cfg)
-    eng = Engine(cfg, params, max_len=16, seed=0)     # dense engine
-    with pytest.raises(ValueError, match="paged=True"):
-        eng.prefill([[1, 2, 3]], paged=True)
-
-
-def test_linear_surgery_ops_reject_paged_caches():
-    cfg = _cfg("dense")
-    params = _params(cfg)
-    rng = np.random.default_rng(12)
-    eng = Engine(cfg, params, max_len=16, seed=0, paged=True, block_size=4)
-    cache, _, _ = eng.prefill([rng.integers(1, cfg.vocab, 5).tolist()])
-    with pytest.raises(ValueError, match="paged"):
-        kvc.compact(cache, target_len=8)
-    with pytest.raises(ValueError, match="paged"):
-        kvc.reset_slots(cache, jnp.asarray([True]))
-
-
 # ---------------------------------------------------------------------------
 # full ragged-trace comparison (slow, main lane)
 # ---------------------------------------------------------------------------
@@ -446,9 +454,9 @@ def test_linear_surgery_ops_reject_paged_caches():
 @pytest.mark.slow
 @pytest.mark.parametrize("lane", LANES)
 def test_paged_trace_identity_all_lanes(lane):
-    """A full Poisson trace through both schedulers: identical
-    completions on every lane, plus the MLA lane's scheduler identity
-    (the fast test covers dense/window)."""
+    """A full Poisson trace through the scheduler: every completion
+    matches its request's isolated generation, on every lane, and the
+    arena drains."""
     from repro.launch.serve import drive_trace, poisson_trace
     cfg = _cfg(lane)
     params = _params(cfg)
@@ -456,17 +464,15 @@ def test_paged_trace_identity_all_lanes(lane):
                           cfg.vocab, 10, 8)
     max_len = 10 + 8 - 1 + 4
 
-    lin = Scheduler(Engine(cfg, params, max_len=max_len, seed=0),
-                    n_slots=2, chunk_size=4)
-    done_l, _ = drive_trace(lin, trace)
     pag = Scheduler(Engine(cfg, params, max_len=max_len, seed=0,
                            paged=True, block_size=4),
                     n_slots=2, chunk_size=4)
-    done_p, _ = drive_trace(pag, trace)
+    done, order = drive_trace(pag, trace)
 
-    assert done_l.keys() == done_p.keys()
-    for rid in done_l:
-        np.testing.assert_array_equal(done_p[rid].tokens,
-                                      done_l[rid].tokens)
-        assert done_p[rid].finished_step == done_l[rid].finished_step
+    ref_eng = Engine(cfg, params, max_len=max_len, seed=0)
+    assert len(done) == len(trace)
+    for rid, i in order.items():
+        _, prompt, gen = trace[i]
+        np.testing.assert_array_equal(
+            done[rid].tokens, ref_eng.generate([prompt], gen).tokens[0])
     assert pag.pool.in_use == 0

@@ -279,7 +279,7 @@ def test_fused_survives_preemption_restart():
     # deadline submission MUST preempt the best-effort one
     eng = Engine(cfg, params, max_len=32, paged=True, block_size=4,
                  n_blocks=6, sanitize=True, decode_kernel="fused")
-    sched = Scheduler(eng, n_slots=2, chunk_size=4, chunked_prefill=True)
+    sched = Scheduler(eng, n_slots=2, chunk_size=4)
     ra = sched.submit(p_a, 8)
     sched.step()
     rb = sched.submit(p_b, 8, deadline=20)

@@ -57,8 +57,6 @@ def test_mixed_step_alone_compiles_as_jit_run():
     lowered = {
         "prefill": prefill.lower(p, tokens, lens),
         "decode": eng._decode_fn(3).lower(p, row_cache, logits, key),
-        "decode_chunk": eng._chunk_fn(2).lower(
-            p, pool, i32, key, jnp.ones((2,), bool)),
         "mixed_step": _lower_mixed(eng, pool),
     }
     eng.copy_blocks(pool, [0], [1])

@@ -1,19 +1,18 @@
 """Continuous-batching scheduler regression tests.
 
 The load-bearing invariant: a request scheduled into a slot pool —
-admitted mid-stream at an arbitrary shared frontier, compacted around,
-and retired early — must emit the BYTE-IDENTICAL token stream it would
-emit alone through ``Engine.generate``.  Pinned on all three transformer
-attention lanes (dense, MLA, sliding-window ring buffer), plus the cache
-surgery ops (``reset_slots`` / ``compact`` / ``adopt_row``) and the
-one-dispatch-per-chunk property that keeps admissions recompile-free.
-
-PR 8 adds the chunked-prefill lane and the policy layer: prompts fed
-through the decode lane in fixed-size chunks must stay byte-identical
-with a FLAT engine compile count across arbitrarily ragged prompt
-lengths, EDF admission must honor deadlines, and preemption-by-block-
-release must restart a request token-identically without leaking a
-single block under the sanitizer.
+admitted mid-stream, its prompt fed through the decode lane in
+fixed-size chunks, retired early, its slot and blocks handed to the
+next request — must emit the BYTE-IDENTICAL token stream it would emit
+alone through ``Engine.generate``.  Pinned on all three transformer
+attention lanes (dense, MLA, sliding-window ring buffer), together with
+the one-dispatch-per-round property that keeps admissions
+recompile-free: the engine's compile count stays FLAT across
+arbitrarily ragged prompt lengths.  The policy layer: EDF admission
+must honor deadlines, and preemption-by-block-release must restart a
+request token-identically without leaking a single block under the
+sanitizer.  The scheduler has one mode and refuses an engine without a
+paged arena.
 """
 import dataclasses
 
@@ -21,10 +20,8 @@ import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 
 from repro import configs
-from repro.compress import kvcache as kvc
 from repro.models import get_family
 from repro.models import transformer as T
 from repro.runtime.engine import Engine
@@ -44,6 +41,11 @@ def _cfg(lane):
 
 def _params(cfg, seed=0):
     return get_family(cfg).init_params(jax.random.PRNGKey(seed), cfg)
+
+
+def _paged(cfg, params, max_len, **kw):
+    return Engine(cfg, params, max_len=max_len, seed=0, paged=True,
+                  block_size=4, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -66,8 +68,7 @@ def test_token_identity_with_midstream_admissions(lane):
     refs = [ref_eng.generate([p], g).tokens[0]
             for p, g in zip(prompts, gens)]
 
-    sched = Scheduler(Engine(cfg, params, max_len=32, seed=0),
-                      n_slots=2, chunk_size=4)
+    sched = Scheduler(_paged(cfg, params, 32), n_slots=2, chunk_size=4)
     rids = [sched.submit(p, g) for p, g in zip(prompts, gens)]
     done = sched.run(max_rounds=100)
 
@@ -78,11 +79,13 @@ def test_token_identity_with_midstream_admissions(lane):
         np.testing.assert_array_equal(got, ref)
 
 
-def test_token_identity_under_forced_compaction():
-    """A max_len tight enough that the shared frontier must be pulled
-    back between chunks (retired long rows unpin it) — identity must
-    survive the cache rolls."""
-    cfg = _cfg("dense")
+@pytest.mark.parametrize("lane", ["dense", "mla", "window"])
+def test_token_identity_when_a_tight_pool_refills_slots(lane):
+    """An arena of about two requests' worst case under three slots:
+    admissions defer until retirements return blocks, so rows retire
+    and their slots and blocks refill mid-trace — and every stream
+    still matches its isolated generation, with no block leaked."""
+    cfg = _cfg(lane)
     params = _params(cfg)
     rng = np.random.default_rng(4)
     plens = [5, 9, 3, 7, 4, 6]
@@ -92,12 +95,27 @@ def test_token_identity_under_forced_compaction():
     refs = [ref_eng.generate([p], g).tokens[0]
             for p, g in zip(prompts, gens)]
 
-    sched = Scheduler(Engine(cfg, params, max_len=24, seed=0),
-                      n_slots=3, chunk_size=4)
+    n_blocks = 2 * T.paged_table_width(cfg, 4, 24)
+    eng = _paged(cfg, params, 24, n_blocks=n_blocks, sanitize=True)
+    sched = Scheduler(eng, n_slots=3, chunk_size=4)
+    deferred = []
+    admit = sched._admit
+
+    def watched():
+        admit()
+        deferred.append(bool(sched._queue) and None in sched._slots)
+
+    sched._admit = watched
     rids = [sched.submit(p, g) for p, g in zip(prompts, gens)]
-    done = sched.run(max_rounds=100)
+    done = sched.run(max_rounds=200)
     for rid, ref in zip(rids, refs):
         np.testing.assert_array_equal(done[rid].tokens, ref)
+    # the pool, not the slots, held requests back, and freed slots refilled
+    assert any(deferred)
+    first_out = min(c.finished_step for c in done.values())
+    assert max(c.admitted_step for c in done.values()) >= first_out > 0
+    assert sched.pool.peak_in_use <= n_blocks
+    assert sched.pool.in_use == 0 and sched.n_leaked == 0
 
 
 def test_eos_stops_early_and_frees_the_slot():
@@ -122,8 +140,7 @@ def test_eos_stops_early_and_frees_the_slot():
     assert cut is not None, "no prompt with a usable EOS token"
     eos = stream[cut]
 
-    sched = Scheduler(Engine(cfg, params, max_len=32, seed=0),
-                      n_slots=1, chunk_size=4)
+    sched = Scheduler(_paged(cfg, params, 32), n_slots=1, chunk_size=4)
     rid = sched.submit(prompt, 8, eos_id=eos)
     rid2 = sched.submit(prompt, 8)            # queued behind the 1-slot pool
     done = sched.run(max_rounds=50)
@@ -133,35 +150,37 @@ def test_eos_stops_early_and_frees_the_slot():
 
 
 # ---------------------------------------------------------------------------
-# one compiled dispatch per decode chunk
+# one compiled dispatch per round
 # ---------------------------------------------------------------------------
 
 def test_each_chunk_is_one_compiled_dispatch():
-    """Admissions and retirements between chunks must never change the
-    compiled computation: the whole run reuses ONE chunk callable, called
-    exactly once per scheduling round that decodes."""
+    """Admissions and retirements between rounds must never change the
+    compiled computation: the whole run reuses ONE ``mixed_step``
+    callable, called exactly once per scheduling round that dispatches,
+    and it compiles once."""
     cfg = _cfg("dense")
     params = _params(cfg)
     rng = np.random.default_rng(6)
     prompts = [rng.integers(1, cfg.vocab, n).tolist() for n in [4, 6, 5]]
 
-    eng = Engine(cfg, params, max_len=32, seed=0)
+    eng = _paged(cfg, params, 32)
     calls = {"n": 0}
-    real = eng._chunk_fn(4)
+    real = eng._mixed_fn(4)
 
     def counted(*a):
         calls["n"] += 1
         return real(*a)
 
-    eng._decode_jit[("chunk", 4)] = counted
+    eng._decode_jit[("mixed", 4, 4)] = counted
     sched = Scheduler(eng, n_slots=2, chunk_size=4)
     for p in prompts:
         sched.submit(p, 6)
     sched.run(max_rounds=50)
     assert calls["n"] == sched.n_chunks > 0
-    assert ("chunk", 4) in eng._decode_jit and \
-        eng._decode_jit[("chunk", 4)] is counted, \
-        "scheduler must reuse the cached chunk callable across admissions"
+    assert list(eng._decode_jit) == [("mixed", 4, 4)] and \
+        eng._decode_jit[("mixed", 4, 4)] is counted, \
+        "scheduler must reuse the cached mixed_step callable"
+    assert real._cache_size() == 1
 
 
 # ---------------------------------------------------------------------------
@@ -185,7 +204,7 @@ def test_chunked_prefill_token_identity(lane):
 
     eng = Engine(cfg, params, max_len=32, paged=True, block_size=4,
                  n_blocks=64, sanitize=True)
-    sched = Scheduler(eng, n_slots=2, chunk_size=4, chunked_prefill=True)
+    sched = Scheduler(eng, n_slots=2, chunk_size=4)
     rids = [sched.submit(p, g) for p, g in zip(prompts, gens)]
     done = sched.run(max_rounds=200)
     for rid, ref in zip(rids, refs):
@@ -194,17 +213,15 @@ def test_chunked_prefill_token_identity(lane):
 
 
 def test_compile_count_flat_across_ragged_admissions():
-    """Eight distinct prompt lengths through the chunked scheduler add
+    """Eight distinct prompt lengths through the scheduler add
     ZERO lowered programs after warmup — the mixed dispatch shape
-    depends only on (n_slots, chunk_size), never on a prompt length.
-    (The unchunked admission path compiles one prefill per length;
-    that specialization family no longer exists in chunked mode.)"""
+    depends only on (n_slots, chunk_size), never on a prompt length."""
     cfg = _cfg("dense")
     params = _params(cfg)
     rng = np.random.default_rng(13)
     eng = Engine(cfg, params, max_len=48, paged=True, block_size=4,
                  n_blocks=96)
-    sched = Scheduler(eng, n_slots=2, chunk_size=4, chunked_prefill=True)
+    sched = Scheduler(eng, n_slots=2, chunk_size=4)
     for n in (3, 11):
         sched.submit(rng.integers(1, cfg.vocab, n).tolist(), 4)
     sched.run(max_rounds=200)
@@ -230,7 +247,7 @@ def test_edf_admission_order():
     prompts = [rng.integers(1, cfg.vocab, 5).tolist() for _ in range(3)]
     eng = Engine(cfg, params, max_len=32, paged=True, block_size=4,
                  n_blocks=32)
-    sched = Scheduler(eng, n_slots=1, chunk_size=4, chunked_prefill=True)
+    sched = Scheduler(eng, n_slots=1, chunk_size=4)
     r_be = sched.submit(prompts[0], 4)               # best-effort, first in
     r_late = sched.submit(prompts[1], 4, deadline=100)
     r_soon = sched.submit(prompts[2], 4, deadline=50)
@@ -258,7 +275,7 @@ def test_preemption_restores_token_identity_and_leaks_nothing():
     # never be resident together — the deadline MUST preempt
     eng = Engine(cfg, params, max_len=32, paged=True, block_size=4,
                  n_blocks=6, sanitize=True)
-    sched = Scheduler(eng, n_slots=2, chunk_size=4, chunked_prefill=True)
+    sched = Scheduler(eng, n_slots=2, chunk_size=4)
     ra = sched.submit(p_a, 8)                    # best-effort
     sched.step()                                 # admitted, prefilling
     rb = sched.submit(p_b, 8, deadline=20)       # urgent, pool is full
@@ -280,7 +297,7 @@ def test_best_effort_never_preempts_best_effort():
     p_b = rng.integers(1, cfg.vocab, 8).tolist()
     eng = Engine(cfg, params, max_len=32, paged=True, block_size=4,
                  n_blocks=6, sanitize=True)
-    sched = Scheduler(eng, n_slots=2, chunk_size=4, chunked_prefill=True)
+    sched = Scheduler(eng, n_slots=2, chunk_size=4)
     ra = sched.submit(p_a, 8)
     sched.step()
     rb = sched.submit(p_b, 8)                    # also best-effort
@@ -291,85 +308,13 @@ def test_best_effort_never_preempts_best_effort():
 
 
 # ---------------------------------------------------------------------------
-# cache surgery ops
-# ---------------------------------------------------------------------------
-
-def _prefill_cache(cfg, params, rng, b, s, ml):
-    tokens = jnp.asarray(rng.integers(1, cfg.vocab, (b, s)), jnp.int32)
-    cache, logits = T.prefill(params, tokens, cfg, max_len=ml)
-    return cache, logits
-
-
-@pytest.mark.parametrize("lane", ["dense", "window"])
-def test_compact_preserves_decode_logits(lane):
-    """Rolling the frontier back and forth must not change what decode
-    sees: logits after compaction == logits without it (both layouts)."""
-    cfg = _cfg(lane)
-    params = _params(cfg, seed=1)
-    rng = np.random.default_rng(7)
-    cache, logits = _prefill_cache(cfg, params, rng, 2, 10, 24)
-    tok = jnp.argmax(logits, -1).astype(jnp.int32)
-
-    ref_logits, _ = T.decode_step(params, cache, tok, cfg)
-
-    grown = kvc.compact(cache, target_len=17)     # push frontier up
-    assert int(grown["len"]) == 17
-    back = kvc.compact(grown)                     # default: max(lens) = 10
-    assert int(back["len"]) == 10
-    got_logits, _ = T.decode_step(params, back, tok, cfg)
-    np.testing.assert_allclose(np.asarray(got_logits),
-                               np.asarray(ref_logits), rtol=1e-5,
-                               atol=1e-5)
-
-
-def test_compact_rejects_target_beyond_max_len():
-    cfg = _cfg("dense")
-    params = _params(cfg, seed=1)
-    cache, _ = _prefill_cache(cfg, params, np.random.default_rng(8),
-                              1, 6, 16)
-    with pytest.raises(ValueError, match="max_len"):
-        kvc.compact(cache, target_len=17)
-
-
-def test_reset_slots_zeroes_rows_and_lens():
-    cfg = _cfg("dense")
-    params = _params(cfg, seed=1)
-    cache, _ = _prefill_cache(cfg, params, np.random.default_rng(9),
-                              3, 8, 16)
-    out = kvc.reset_slots(cache, jnp.asarray([True, False, True]))
-    assert np.asarray(out["lens"]).tolist() == [0, 8, 0]
-    assert int(np.abs(np.asarray(out["k"][:, 0])).sum()) == 0
-    assert int(np.abs(np.asarray(out["k"][:, 2])).sum()) == 0
-    # the surviving row and the shared metadata are untouched
-    np.testing.assert_array_equal(np.asarray(out["k"][:, 1]),
-                                  np.asarray(cache["k"][:, 1]))
-    assert int(out["len"]) == int(cache["len"])
-    assert int(out["max_len"]) == int(cache["max_len"])
-
-
-def test_adopt_row_requires_frontier_headroom():
-    cfg = _cfg("dense")
-    params = _params(cfg, seed=1)
-    rng = np.random.default_rng(10)
-    pool, _ = _prefill_cache(cfg, params, rng, 2, 4, 16)
-    row, _ = _prefill_cache(cfg, params, rng, 1, 7, 16)
-    with pytest.raises(ValueError, match="frontier"):
-        kvc.adopt_row(pool, row, 0)             # 7 > pool frontier 4
-    pool = kvc.compact(pool, target_len=7)
-    out = kvc.adopt_row(pool, row, 0)
-    assert np.asarray(out["lens"]).tolist() == [7, 4]
-    assert int(out["len"]) == 7
-
-
-# ---------------------------------------------------------------------------
 # guard rails
 # ---------------------------------------------------------------------------
 
 def test_scheduler_rejects_unservable_request():
     cfg = _cfg("dense")
     params = _params(cfg)
-    sched = Scheduler(Engine(cfg, params, max_len=16, seed=0),
-                      n_slots=1, chunk_size=4)
+    sched = Scheduler(_paged(cfg, params, 16), n_slots=1, chunk_size=4)
     sched.submit([1, 2, 3], 10)                 # 3 + 10 - 1 + 4 = 16 fits
     with pytest.raises(ValueError, match="max_len"):
         sched.submit([1, 2, 3], 11)             # 17 > 16
@@ -382,3 +327,18 @@ def test_scheduler_rejects_non_transformer_family():
     params = _params(cfg)
     with pytest.raises(ValueError, match="transformer"):
         Scheduler(Engine(cfg, params, max_len=16), n_slots=2)
+
+
+def test_scheduler_refuses_an_engine_without_a_paged_arena():
+    cfg = _cfg("dense")
+    params = _params(cfg)
+    with pytest.raises(ValueError, match=r"Engine\(paged=True\)"):
+        Scheduler(Engine(cfg, params, max_len=16), n_slots=2)
+
+
+def test_scheduler_has_one_mode():
+    cfg = _cfg("dense")
+    params = _params(cfg)
+    with pytest.raises(ValueError, match="one mode"):
+        Scheduler(_paged(cfg, params, 16), n_slots=2,
+                  chunked_prefill=False)

@@ -107,7 +107,7 @@ def run(mesh, kernel=None):
     eng = Engine(cfg, params, max_len=64, paged=True, block_size=8,
                  n_blocks=10, sanitize=True, mesh=mesh,
                  decode_kernel=kernel)
-    sched = Scheduler(eng, n_slots=3, chunk_size=4, chunked_prefill=True)
+    sched = Scheduler(eng, n_slots=3, chunk_size=4)
     sched.submit(prompts[0], 16)              # best-effort, long
     sched.submit(prompts[1], 16)              # best-effort, long
     for _ in range(2):

@@ -4,15 +4,16 @@ and what the scheduler and engine record into it.
 A round is a ``sched.round`` span whose children are its phases; a
 request's life is ``request.queued`` -> ``request.prefill`` ->
 ``request.decode``, tiled with no gaps.  The round counters must agree
-with ``Scheduler.stats`` and with the tokens returned, ``compiled``
-must mark exactly the rounds that compiled, the ring must stay bounded,
+with ``Scheduler.stats`` and with the tokens returned, on the MLA and the dense-GQA lane;
+``compiled`` must mark exactly the rounds that compiled, the ring must stay bounded,
 and under ``jax.profiler`` the same spans must land in the trace's host
 plane with the same nesting.  ``Scheduler.progress`` must agree with
-the benchmark's stopgap reading of the slot state.  A chunked round's
+the benchmark's stopgap reading of the slot state.  A round's
 decode-read counters must equal a hand count on each attention lane,
 and rows crossing a width step of the bounded decode gather must not
 compile anything.
 """
+import functools
 import importlib.util
 import types
 from pathlib import Path
@@ -35,16 +36,22 @@ PHASES = ("sched.admit", "sched.prepare", "engine.dispatch",
 LIFE = ("request.queued", "request.prefill", "request.decode")
 
 
-@pytest.fixture(scope="module")
-def model():
-    cfg = configs.get_config("minicpm3-4b").reduced(compute_dtype="float32")
+@functools.lru_cache(maxsize=None)
+def _model(lane):
+    arch = "minicpm3-4b" if lane == "mla" else "phi3-medium-14b"
+    cfg = configs.get_config(arch).reduced(compute_dtype="float32")
     return cfg, get_family(cfg).init_params(jax.random.PRNGKey(0), cfg)
 
 
-def _scheduler(model, chunked=True):
+@pytest.fixture(scope="module")
+def model():
+    return _model("mla")
+
+
+def _scheduler(model):
     cfg, params = model
     eng = Engine(cfg, params, max_len=64, paged=True, block_size=4)
-    return Scheduler(eng, n_slots=2, chunk_size=4, chunked_prefill=chunked)
+    return Scheduler(eng, n_slots=2, chunk_size=4)
 
 
 def _submit(sched, n=5, seed=0):
@@ -59,18 +66,17 @@ def _mine(sched):
     return [s for s in spans.snapshot() if s.ids.get("sched") == sched.sched_id]
 
 
-def _serve(model, chunked=True):
-    sched = _scheduler(model, chunked)
+def _serve(model):
+    sched = _scheduler(model)
     rids = _submit(sched)
     done = sched.run(max_rounds=200)
     assert sorted(done) == sorted(rids)
     return sched, done, _mine(sched)
 
 
-@pytest.mark.parametrize("chunked", [True, False],
-                         ids=["chunked", "unchunked"])
-def test_round_phases_nest_inside_their_round(model, chunked):
-    sched, _, mine = _serve(model, chunked)
+@pytest.mark.parametrize("lane", ["mla", "dense"])
+def test_round_phases_nest_inside_their_round(lane):
+    sched, _, mine = _serve(_model(lane))
     rounds = {s.sid: s for s in mine if s.name == "sched.round"}
     assert sorted(s.ids["round"] for s in rounds.values()) == \
         list(range(len(rounds)))
@@ -92,10 +98,9 @@ def test_round_phases_nest_inside_their_round(model, chunked):
             assert PHASES.index(a.name) < PHASES.index(b.name)
 
 
-@pytest.mark.parametrize("chunked", [True, False],
-                         ids=["chunked", "unchunked"])
-def test_request_spans_tile_each_life(model, chunked):
-    sched, done, mine = _serve(model, chunked)
+@pytest.mark.parametrize("lane", ["mla", "dense"])
+def test_request_spans_tile_each_life(lane):
+    sched, done, mine = _serve(_model(lane))
     for rid in done:
         life = sorted((s for s in mine if s.ids.get("rid") == rid
                        and s.name.startswith("request.")),
@@ -110,10 +115,9 @@ def test_request_spans_tile_each_life(model, chunked):
         assert phases[1].counters["prefill_rounds"] >= 1
 
 
-@pytest.mark.parametrize("chunked", [True, False],
-                         ids=["chunked", "unchunked"])
-def test_round_counters_match_stats_and_tokens(model, chunked):
-    sched, done, mine = _serve(model, chunked)
+@pytest.mark.parametrize("lane", ["mla", "dense"])
+def test_round_counters_match_stats_and_tokens(lane):
+    sched, done, mine = _serve(_model(lane))
     rounds = sorted((s for s in mine if s.name == "sched.round"),
                     key=lambda s: s.ids["round"])
     st = sched.stats
@@ -130,18 +134,17 @@ def test_round_counters_match_stats_and_tokens(model, chunked):
     assert total("compiled") == st["n_compiles"]
     assert rounds[-1].counters["queue_depth"] == 0
     assert all(r.counters["decode_rows"] <= sched.n_slots for r in rounds)
-    if chunked:
-        firsts = [s for s in mine if s.name == "sched.first_tokens"]
-        assert sum(s.counters["syncs"] for s in firsts) == len(done)
+    firsts = [s for s in mine if s.name == "sched.first_tokens"]
+    assert sum(s.counters["syncs"] for s in firsts) == len(done)
     wall = [r.seconds * 1e3 for r in rounds]
     assert st["step_wall_p50_ms"] == pytest.approx(np.percentile(wall, 50))
     assert st["step_wall_p99_ms"] == pytest.approx(np.percentile(wall, 99))
 
 
 def test_compiled_marks_only_the_round_that_compiles(model):
-    """Chunked mode: the first round compiles ``mixed_step``; every later
-    round, whatever prompt lengths it admits, compiles nothing."""
-    sched, _, mine = _serve(model, chunked=True)
+    """The first round compiles ``mixed_step``; every later round,
+    whatever prompt lengths it admits, compiles nothing."""
+    sched, _, mine = _serve(model)
     rounds = sorted((s for s in mine if s.name == "sched.round"),
                     key=lambda s: s.ids["round"])
     assert rounds[0].counters["compiled"] > 0
@@ -156,14 +159,14 @@ def test_compiled_marks_only_the_round_that_compiles(model):
 
 
 def test_one_program_serves_every_read_width(model):
-    """Chunked mode at a table of two width steps (1024 positions of
+    """A table of two width steps (1024 positions of
     blocks of 16): rows cross the first step (512 positions) in the
     middle of a round, and rounds read one step and two, yet no round
     after the first compiles: the gather's width is chosen on the
     device, inside the one ``mixed_step`` program."""
     cfg, params = model
     eng = Engine(cfg, params, max_len=1024, paged=True, block_size=16)
-    sched = Scheduler(eng, n_slots=2, chunk_size=64, chunked_prefill=True)
+    sched = Scheduler(eng, n_slots=2, chunk_size=64)
     rng = np.random.default_rng(3)
     for plen, gen in ((480, 40), (40, 100), (500, 30)):
         sched.submit(rng.integers(1, 256, plen).tolist(), gen)
@@ -294,7 +297,7 @@ DECODE_READS = {
 @pytest.mark.parametrize("lane", sorted(DECODE_READS))
 def test_decode_read_counters_match_a_hand_count(lane):
     """``decode_steps``, ``kv_live_positions`` and ``kv_read_positions``
-    on a chunked round, on each attention lane: every step the device
+    on every round, on each attention lane: every step the device
     runs counts, a row's finished steps included, and the gather reads
     every row's table up to the width steps that the step's longest
     decoding row reaches (the whole table when it is one step)."""
@@ -304,7 +307,7 @@ def test_decode_read_counters_match_a_hand_count(lane):
                                            sliding_window=window or None)
     params = get_family(cfg).init_params(jax.random.PRNGKey(0), cfg)
     eng = Engine(cfg, params, max_len=max_len, paged=True, block_size=bs)
-    sched = Scheduler(eng, n_slots=2, chunk_size=chunk, chunked_prefill=True)
+    sched = Scheduler(eng, n_slots=2, chunk_size=chunk)
     rng = np.random.default_rng(0)
     for plen, gen in reqs:
         sched.submit(rng.integers(1, 256, plen).tolist(), gen)

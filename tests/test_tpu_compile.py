@@ -163,16 +163,19 @@ def _loop_ops(hlo: str):
 @pytest.mark.parametrize("lane", ["mla", "dense"])
 def test_paged_decode_scan_keeps_the_arena_in_place(one_chip, monkeypatch,
                                                     lane):
-    """A decode scan at the served arena (16 rows x 2048, block 16,
+    """The serving round (``Engine._mixed_fn``: a prefill chunk, then a
+    4-step decode scan) at the served arena (16 rows x 2048, block 16,
     posit16) and published widths, two layers.  No loop copies, slices
     or update-slices the stacked arena, nor update-slices one layer of
     it: the writes scatter into the carried arena in place.  No loop
     gathers straight off the stacked arena either: on a v5e that
     two-index gather ran up to 20x slower than slicing the layer's
-    arena out (read only) and gathering from the slice.  The compiled
-    temporaries are at least one arena below the per-layer
-    ``xs``/``ys`` formulation's (``paged_reference``), which copies the
-    stacked arena every step and restacks every layer."""
+    arena out (read only) and gathering from the slice.  The decode
+    scan's compiled temporaries are at least one arena below the
+    per-layer ``xs``/``ys`` formulation's (``paged_reference``), which
+    copies the stacked arena every step and restacks every layer; the
+    scan is compiled alone for that, since the round's peak is the
+    prefill chunk's and would hide it."""
     from paged_reference import decode_step_xs_ys
     arch = "minicpm3-4b" if lane == "mla" else "phi3-medium-14b"
     cfg = dataclasses.replace(configs.get_config(arch), n_layers=2,
@@ -189,23 +192,37 @@ def test_paged_decode_scan_keeps_the_arena_in_place(one_chip, monkeypatch,
         jax.random.PRNGKey(0), cfg)))
     pool = on_chip(jax.eval_shape(lambda: T.init_paged_cache(
         cfg, b, max_len, bs, b * eng.table_width)))
-    args = on_chip((jax.ShapeDtypeStruct((b,), jnp.int32),
+    i32 = jax.ShapeDtypeStruct((b,), jnp.int32)
+    args = on_chip((jax.ShapeDtypeStruct((b, 4), jnp.int32), i32, i32,
                     jax.ShapeDtypeStruct((2,), jnp.uint32),
-                    jax.ShapeDtypeStruct((b,), jnp.bool_)))
+                    jax.ShapeDtypeStruct((b,), jnp.bool_),
+                    jax.ShapeDtypeStruct((b, eng.table_width), jnp.int32)))
     leaves = [pool[k] for k in (("c_kv", "k_rope") if cfg.mla
                                 else ("k", "v"))]
     stacked = {leaf.shape for leaf in leaves}
     layer = {leaf.shape[1:] for leaf in leaves}
 
     def compile_scan():
-        compiled = eng._chunk_fn(4).lower(params, pool, *args).compile()
+        def decode_scan(params, cache, tok, active):    # traced anew
+            def step(carry, _):
+                cache, tok = carry
+                logits, cache = T.decode_step(params, cache, tok, cfg,
+                                              active=active)
+                return (cache, jnp.argmax(logits, -1).astype(jnp.int32)), \
+                    None
+
+            return jax.lax.scan(step, (cache, tok), length=4)[0]
+
+        compiled = eng._mixed_fn(4).lower(params, pool, *args).compile()
         bad = [(op, dims) for op, dt, dims, src in
                _loop_ops(compiled.as_text()) if dt == "u16" and (
                    op in ("copy", "dynamic-slice", "dynamic-update-slice")
                    and dims in stacked
                    or op == "dynamic-update-slice" and dims in layer
                    or op == "gather" and src in stacked)]
-        return compiled.memory_analysis().temp_size_in_bytes, bad
+        scan = jax.jit(decode_scan).lower(
+            params, pool, args[2], args[4]).compile()
+        return scan.memory_analysis().temp_size_in_bytes, bad
 
     temp, bad = compile_scan()
     monkeypatch.setattr(T, "_decode_step_paged", decode_step_xs_ys)
