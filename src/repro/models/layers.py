@@ -545,6 +545,47 @@ def paged_gather(arena, tables):
         g.reshape((b, w * bs) + arena.shape[2:]))
 
 
+# lanes of a v5e vector tile: an arena's minor axis fills whole tiles
+PAGED_LANE_TILE = 128
+
+
+def paged_reads_flat(minor: int, kernel: str = "gather") -> bool:
+    """The read rule for one layer of a stacked ``(L, n_blocks, bs, ...)``
+    arena whose last axis is ``minor`` wide: True where the gather reads
+    the layer's blocks straight off the arena, flattened to
+    ``(L*n_blocks, bs, ...)`` (a bitcast, no copy), False where the layer
+    is first sliced out (a copy of its whole arena per layer-step).
+
+    Lane-dense arenas (dense K/V ``(..., G, 128)``, the MLA latent)
+    read flat.  A narrower one (MLA's 32-wide RoPE arena) keeps the
+    slice: its default v5e layout puts the block axis minor, so a flat
+    view of it compiles to a relayout of the whole stacked arena, and a
+    two-index gather at ``(layer, table)`` ran 20x slower than the slice
+    on the chip.  The fused kernel walks a layer's arena: always sliced.
+    The one rule for the device's read (:func:`paged_layer_view`) and
+    the scheduler's ``kv_layer_slices``."""
+    return kernel == "gather" and minor % PAGED_LANE_TILE == 0
+
+
+def paged_layer_view(arena, layer, *, kernel: str = "gather"):
+    """``(view, to_view)``: what a decode step's layer ``layer`` reads of
+    the stacked arena, and the map from the layer's table entries to
+    the view's blocks (:func:`paged_reads_flat`).  Flat: live entries
+    shift by ``layer * n_blocks``, sentinels (``>= n_blocks``) go to the
+    flat sentinel ``L * n_blocks``.  ``layer`` None: ``arena`` is one
+    layer's arena, read as it is."""
+    def same(t):
+        return t
+
+    if layer is None:
+        return arena, same
+    n_layers, nb = arena.shape[0], arena.shape[1]
+    if not paged_reads_flat(arena.shape[-1], kernel):
+        return lax.dynamic_index_in_dim(arena, layer, keepdims=False), same
+    flat = arena.reshape((n_layers * nb,) + arena.shape[2:])
+    return flat, lambda t: jnp.where(t < nb, t + layer * nb, n_layers * nb)
+
+
 def paged_apos(tables, lens, block_size: int, n_blocks: int, *,
                window: int = 0):
     """Per-slot absolute positions of the virtual paged cache, with dead
@@ -618,12 +659,14 @@ def decode_attention_paged(q, k_arena, v_arena, tables, lens, *,
                            cfg: ModelConfig, kv_posit: Optional[str] = None,
                            window: int = 0, kernel: str = "gather",
                            interpret: Optional[bool] = None,
-                           read_steps=None):
+                           read_steps=None, layer=None):
     """Paged decode attention straight off the block tables.
 
     q: (B, 1, H, D); arenas (n_blocks, bs, G, D[v]) posit patterns or
-    floats; tables (B, W) int32; lens (B,) int32 row frontiers (the
-    step's token is already written at ``lens[b]``).
+    floats, or with ``layer`` (a scalar) the stacked
+    ``(L, n_blocks, ...)`` arenas, of which layer ``layer`` is read
+    (:func:`paged_layer_view`); tables (B, W) int32; lens (B,) int32
+    row frontiers (the step's token is already written at ``lens[b]``).
 
     ``kernel="fused"`` walks the tables inside one Pallas kernel
     (``kernels/posit_paged_attn.py``): posit decode on the VPU, online
@@ -641,8 +684,10 @@ def decode_attention_paged(q, k_arena, v_arena, tables, lens, *,
     reads the whole table; the fused kernel always walks it whole.
     """
     b, _, h, d = q.shape
-    nb, bs, g = k_arena.shape[0], k_arena.shape[1], k_arena.shape[2]
+    nb, bs, g = k_arena.shape[-4:-1]
     apos = paged_apos(tables, lens, bs, nb, window=window)
+    k_arena, k_map = paged_layer_view(k_arena, layer, kernel=kernel)
+    v_arena, v_map = paged_layer_view(v_arena, layer, kernel=kernel)
     if kernel == "fused":
         from repro.kernels import posit_paged_attn as K  # lazy: pallas
         qg = (q.reshape(b, g, h // g, d) * d ** -0.5).astype(jnp.float32)
@@ -656,7 +701,8 @@ def decode_attention_paged(q, k_arena, v_arena, tables, lens, *,
 
     def attend(t, a):
         return decode_attention(
-            q, paged_gather(k_arena, t), paged_gather(v_arena, t),
+            q, paged_gather(k_arena, k_map(t)),
+            paged_gather(v_arena, v_map(t)),
             lens + 1, cfg=cfg, kv_posit=kv_posit, window=window, apos=a)
 
     return _paged_read_switch(attend, tables, apos, read_steps, bs, window)
@@ -667,13 +713,14 @@ def decode_attention_paged_mla(q_lat_eff, q_rope, c_arena, r_arena, tables,
                                kv_posit: Optional[str] = None,
                                kernel: str = "gather",
                                interpret: Optional[bool] = None,
-                               read_steps=None):
+                               read_steps=None, layer=None):
     """Absorbed-matrix MLA paged decode: latent-space attention off the
     block tables; returns the latent context (B, H, rank) f32 (the
     caller applies ``wuv``).
 
-    Same kernel dispatch contract, and the same ``read_steps`` bound on
-    the gather path, as :func:`decode_attention_paged`; the fused kernel
+    Same kernel dispatch contract, the same ``read_steps`` bound on the
+    gather path and the same stacked-arena ``layer`` read, as
+    :func:`decode_attention_paged`; the fused kernel
     concatenates the latent (``c``) and decoupled-RoPE (``r``) blocks in
     VMEM and uses the latent block as V.  The gather fallback carries
     the same all-masked guard as :func:`decode_attention`: a
@@ -681,9 +728,11 @@ def decode_attention_paged_mla(q_lat_eff, q_rope, c_arena, r_arena, tables,
     ``jax.nn.softmax`` would produce.
     """
     b, h, _ = q_lat_eff.shape
-    nb, bs = c_arena.shape[0], c_arena.shape[1]
+    nb, bs = c_arena.shape[-3:-1]
     scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
     apos = paged_apos(tables, lens, bs, nb)
+    c_arena, c_map = paged_layer_view(c_arena, layer, kernel=kernel)
+    r_arena, r_map = paged_layer_view(r_arena, layer, kernel=kernel)
     if kernel == "fused":
         from repro.kernels import posit_paged_attn as K  # lazy: pallas
         return K.paged_decode_attention_mla(
@@ -695,8 +744,8 @@ def decode_attention_paged_mla(q_lat_eff, q_rope, c_arena, r_arena, tables,
         raise ValueError(f"unknown paged decode kernel {kernel!r}")
 
     def attend(t, a):
-        c = paged_gather(c_arena, t)                  # (B, W*bs, rank)
-        r = paged_gather(r_arena, t)
+        c = paged_gather(c_arena, c_map(t))           # (B, W*bs, rank)
+        r = paged_gather(r_arena, r_map(t))
         if kv_posit:
             with jax.named_scope("kv_decode"):
                 c = posit_to_f32(c, pcfg(kv_posit))

@@ -733,18 +733,6 @@ def _decode_attn_mla(p, x, c_cache, r_cache, pos, lens, cfg: ModelConfig):
     return L.dense(p["wo"], out, cfg), c_cache, r_cache
 
 
-def _layer_arena(arena, layer):
-    """Layer ``layer``'s arena, sliced out of the stacked arena to be
-    read (``arena`` itself when ``layer`` is None).  Decode reads it so,
-    not by a gather at ``(layer, table)`` off the stacked arena: on a
-    v5e that gather ran 1.8x (latent arena) to 20x (RoPE arena, whose
-    default device layout puts the block axis minor) slower than this
-    slice and a one-index gather from it."""
-    if layer is None:
-        return arena
-    return lax.dynamic_index_in_dim(arena, layer, keepdims=False)
-
-
 def _decode_attn_dense_paged(p, x, k_arena, v_arena, layer, tables, lens,
                              ok, cfg: ModelConfig, read_steps=None):
     """Paged dense/GQA decode: per-row write position ``lens[b]`` into the
@@ -772,9 +760,9 @@ def _decode_attn_dense_paged(p, x, k_arena, v_arena, layer, tables, lens,
         v_arena, _maybe_quant_kv(v, cfg)[:, 0], tables, lens, ok,
         window=window, layer=layer)
     out = L.decode_attention_paged(
-        q, _layer_arena(k_arena, layer), _layer_arena(v_arena, layer),
-        tables, lens, cfg=cfg, kv_posit=cfg.kv_posit, window=window,
-        kernel=cfg.paged_attn_kernel, read_steps=read_steps)
+        q, k_arena, v_arena, tables, lens, cfg=cfg, kv_posit=cfg.kv_posit,
+        window=window, kernel=cfg.paged_attn_kernel, read_steps=read_steps,
+        layer=layer)
     out = out.reshape(b, 1, cfg.n_heads * cfg.head_dim)
     return L.dense(p["wo"], out, cfg), k_arena, v_arena
 
@@ -809,10 +797,9 @@ def _decode_attn_mla_paged(p, x, c_arena, r_arena, layer, tables, lens, ok,
         cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_dim)
     q_lat_eff = jnp.einsum("bhd,rhd->bhr", q_nope.astype(jnp.float32), wuk)
     ctx_lat = L.decode_attention_paged_mla(
-        q_lat_eff, q_rope, _layer_arena(c_arena, layer),
-        _layer_arena(r_arena, layer), tables, lens, cfg=cfg,
+        q_lat_eff, q_rope, c_arena, r_arena, tables, lens, cfg=cfg,
         kv_posit=cfg.kv_posit, kernel=cfg.paged_attn_kernel,
-        read_steps=read_steps)
+        read_steps=read_steps, layer=layer)
     wuv = L.maybe_dequant(p["wuv"]["w"], cfg).reshape(
         cfg.kv_lora_rank, cfg.n_heads, cfg.v_head_dim)
     out = jnp.einsum("bhr,rhv->bhv", ctx_lat, wuv)
@@ -828,12 +815,18 @@ def _decode_step_paged(params, cache, token, cfg: ModelConfig, active):
 
     The layer scan carries the two stacked arenas whole: each layer
     writes its new KV with one scatter at ``(layer, block, offset)``,
-    so the arenas are updated in place, and reads its blocks from its
-    own arena slice (``_layer_arena``).  Passing the arenas through the
+    so the arenas are updated in place.  Passing the arenas through the
     scan as ``xs``/``ys`` instead rewrites each layer's slice around
     the scatter and restacks it into a fresh arena, which the caller's
     step scan then copies back: a whole-arena copy every decode
-    step.
+    step.  Each layer reads its blocks by the one read rule
+    (``layers.paged_reads_flat``): a lane-dense arena (dense K/V, the
+    MLA latent) with one one-index gather straight off the carried
+    arena, flattened to ``(L*n_blocks, ...)`` with the layer's table
+    entries offset by ``layer * n_blocks``; the 32-wide RoPE arena from
+    a slice of its layer, since its block-minor layout would relayout
+    the whole stacked arena for a flat read, and a two-index gather at
+    ``(layer, table)`` ran 20x slower than the slice on a v5e.
 
     Every layer's gather reads only the width steps that cover the
     decoding rows' longest content (``layers.paged_read_positions`` of

@@ -837,7 +837,11 @@ class Scheduler:
         ``layers.paged_read_positions`` gives for the step's extent (the
         decoding rows' largest ``lens + 1``): the rule the device
         branches on.  The fused table walk reads every row's whole table
-        (``table_width x block_size``) each step."""
+        (``table_width x block_size``) each step.  ``kv_layer_slices``,
+        the whole-layer arena slices those steps copy before they read:
+        one per layer-step for each arena that
+        ``layers.paged_reads_flat`` does not read straight off the
+        stacked arena."""
         n = self.chunk_size if decode_active.any() else 0
         lens = np.array([s.lens for s, a in zip(self._slots, decode_active)
                          if a], np.int64)
@@ -846,13 +850,20 @@ class Scheduler:
         if self._window:
             live = np.minimum(live, self._window)
         w, bs = self.table_width, self.block_size
-        if self.engine.cfg.paged_attn_kernel == "fused" or not n:
+        cfg = self.engine.cfg
+        if cfg.paged_attn_kernel == "fused" or not n:
             width = np.full(n, w * bs)
         else:
             width = L.paged_read_positions(lens.max() + 1 + steps, w, bs,
                                            window=self._window)
+        arenas = [self.cache[k] for k in
+                  (("c_kv", "k_rope") if cfg.mla else ("k", "v"))]
+        sliced = sum(not L.paged_reads_flat(a.shape[-1],
+                                            cfg.paged_attn_kernel)
+                     for a in arenas)
         return dict(decode_steps=n, kv_live_positions=int(live.sum()),
-                    kv_read_positions=self.n_slots * int(width.sum()))
+                    kv_read_positions=self.n_slots * int(width.sum()),
+                    kv_layer_slices=n * arenas[0].shape[0] * sliced)
 
     def _step(self, rnd):
         """One scheduling round's body: admit (allocation only) ->
@@ -982,7 +993,8 @@ class Scheduler:
         new compiles in the round, non-zero only on a round that
         compiled.  Every round also counts its decode attention's
         work (``decode_steps``, ``kv_live_positions``,
-        ``kv_read_positions``; :meth:`_decode_reads`).  ``stats`` reads
+        ``kv_read_positions``, ``kv_layer_slices``;
+        :meth:`_decode_reads`).  ``stats`` reads
         the round spans' p50/p99."""
         with spans.span("sched.round", sched=self.sched_id,
                         round=self._round) as rnd:

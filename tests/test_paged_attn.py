@@ -476,3 +476,169 @@ def test_bounded_gather_token_identity_across_a_width_step(lane):
     paged = Engine(cfg, params, max_len=640, seed=0, paged=True,
                    block_size=16).generate(prompts, 16).tokens
     np.testing.assert_array_equal(paged, linear)
+
+
+# ---------------------------------------------------------------------------
+# the stacked arena's read rule: lane-dense arenas read flat, no layer slice
+# ---------------------------------------------------------------------------
+
+def _lane_dense_cfg(lane, **kw):
+    """The lane at its arenas' served minor widths: dense K/V heads of
+    128, MLA's 256-wide latent beside its 32-wide RoPE part."""
+    if lane == "mla":
+        return _cfg(lane, kv_lora_rank=256, qk_rope_dim=32, **kw)
+    return _cfg(lane, head_dim=128, **kw)
+
+
+@pytest.mark.parametrize("minor, kernel, flat", [
+    (128, "gather", True), (256, "gather", True), (384, "gather", True),
+    (32, "gather", False), (16, "gather", False), (96, "gather", False),
+    (128, "fused", False), (256, "fused", False)])
+def test_read_rule_reads_lane_dense_arenas_flat(minor, kernel, flat):
+    """Flat where the gather path reads an arena whose last axis fills
+    whole 128-lane tiles; a whole-layer slice for a narrower arena and
+    on the fused kernel.  The flat view is the stacked arena with its
+    two leading axes merged, and maps layer ``l``'s live entries to
+    ``l * n_blocks + entry`` and every sentinel to ``L * n_blocks``."""
+    assert L.paged_reads_flat(minor, kernel) is flat
+    n_layers, nb = 3, 5
+    arena = jnp.arange(n_layers * nb * 2 * minor, dtype=jnp.float32
+                       ).reshape(n_layers, nb, 2, minor)
+    tables = jnp.asarray([[4, 0, nb], [2, nb + 3, 1]], jnp.int32)
+    view, to_view = L.paged_layer_view(arena, jnp.int32(1), kernel=kernel)
+    if flat:
+        assert view.shape == (n_layers * nb, 2, minor)
+        np.testing.assert_array_equal(
+            to_view(tables), [[nb + 4, nb, 3 * nb], [nb + 2, 3 * nb, nb + 1]])
+    else:
+        assert view.shape == (nb, 2, minor)
+        np.testing.assert_array_equal(to_view(tables), tables)
+    live = np.asarray(tables) < nb
+    got = np.asarray(L.paged_gather(view, to_view(tables)))
+    want = np.asarray(L.paged_gather(arena[1], tables))
+    np.testing.assert_array_equal(got.reshape(2, 3, 2, minor)[live],
+                                  want.reshape(2, 3, 2, minor)[live])
+
+
+def _paged_engine_case(cfg, seed):
+    """A paged cache mid-decode with every dropped write of the in-place
+    guard: row 1 inactive, row 2's write position under a sentinel
+    entry, row 3 at ``max_len``."""
+    eng = Engine(cfg, _params(cfg), max_len=16, seed=0, paged=True,
+                 block_size=4)
+    rng = np.random.default_rng(seed)
+    cache, _, _ = eng.prefill(
+        [rng.integers(1, cfg.vocab, n).tolist() for n in (5, 7, 6, 9)],
+        reserve_tokens=4)
+    nb = cache["k_rope" if cfg.mla else "k"].shape[1]
+    lens = np.asarray(cache["lens"]).copy()
+    tables = np.asarray(cache["block_tables"]).copy()
+    tables[2, (lens[2] // 4) % tables.shape[1]] = nb
+    lens[3] = 16
+    cache = dict(cache, lens=jnp.asarray(lens),
+                 block_tables=jnp.asarray(tables))
+    toks = rng.integers(1, cfg.vocab, (3, 4))
+    return eng.params, cache, jnp.asarray([True, False, True, True]), toks
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_flat_read_matches_per_layer_slices(lane):
+    """At lane-dense widths the decode step reads the dense K/V and the
+    MLA latent straight off the stacked arena; logits, both arenas and
+    ``lens`` still equal the per-layer ``xs``/``ys`` step bit for bit
+    over three steps, every dropped write included."""
+    from paged_reference import decode_step_xs_ys
+    cfg = _lane_dense_cfg(lane, kv_posit="posit16")
+    params, cache, active, toks = _paged_engine_case(cfg, 23)
+    step = jax.jit(lambda c, t: T.decode_step(params, c, t, cfg,
+                                              active=active))
+    ref_step = jax.jit(lambda c, t: decode_step_xs_ys(params, c, t, cfg,
+                                                      active))
+    got, ref = cache, cache
+    for tok in toks:
+        tok = jnp.asarray(tok, jnp.int32)
+        logits, got = step(got, tok)
+        ref_logits, ref = ref_step(ref, tok)
+        np.testing.assert_array_equal(np.asarray(logits),
+                                      np.asarray(ref_logits))
+    for key in got:
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(ref[key]), err_msg=key)
+
+
+@pytest.mark.parametrize("lane", ["dense", "mla"])
+def test_flat_read_is_the_sliced_read_at_every_width(lane, monkeypatch):
+    """The bounded gather's branches at the wide table (a dead row, a
+    longer non-decoding row): the flat read gives the logits and arenas
+    of the whole-layer slice read, bit for bit, at one, two and three
+    width steps."""
+    cfg = _lane_dense_cfg(lane, kv_posit="posit16")
+    params = _params(cfg)
+    rng = np.random.default_rng(24)
+    cache = _wide_cache(cfg, rng)
+    tok = jnp.asarray(rng.integers(1, cfg.vocab, len(WIDE_LENS)), jnp.int32)
+
+    actives = [jnp.asarray(np.isin(np.arange(len(WIDE_LENS)), rows))
+               for rows in ([0, 1], [0, 1, 2], [0, 4])]
+
+    def run():                          # traced anew: reads the rule
+        step = jax.jit(lambda c, t, a: T.decode_step(params, c, t, cfg,
+                                                     active=a))
+        return [jax.device_get(step(cache, tok, a)) for a in actives]
+
+    flat = run()
+    monkeypatch.setattr(L, "paged_reads_flat", lambda minor, kernel: False)
+    for got, want in zip(flat, run()):
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(g, w)
+
+
+def _layer_slices(jaxpr, layer_shapes):
+    """Whole-layer ``dynamic_slice`` ops of the stacked arenas in a
+    jaxpr, its scan, switch and call bodies included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dynamic_slice" and \
+                eqn.outvars[0].aval.shape in layer_shapes:
+            n += 1
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _layer_slices(sub, layer_shapes)
+    return n
+
+
+# lane, arenas at served minor widths, arenas a layer-step slices
+LAYER_SLICES = [("dense", False, 2), ("dense", True, 0), ("mla", False, 2),
+                ("mla", True, 1), ("window", False, 2), ("window", True, 0)]
+
+
+@pytest.mark.parametrize("lane, lane_dense, want", LAYER_SLICES)
+def test_kv_layer_slices_counts_the_steps_slices(lane, lane_dense, want):
+    """``kv_layer_slices`` on every ``sched.round`` is ``decode_steps x
+    n_layers x`` the arenas a layer-step slices, and the decode step
+    traces exactly that many whole-layer slices: none on the dense
+    lanes at heads of 128, the RoPE arena's alone at MLA's served
+    widths, both arenas below a lane tile."""
+    from repro.runtime import spans
+    cfg = (_lane_dense_cfg if lane_dense else _cfg)(lane)
+    eng = Engine(cfg, _params(cfg), max_len=64, paged=True, block_size=4)
+    sched = Scheduler(eng, n_slots=2, chunk_size=4)
+    arenas = [sched.cache[k] for k in
+              (("c_kv", "k_rope") if cfg.mla else ("k", "v"))]
+    jaxpr = jax.make_jaxpr(lambda c, t: T.decode_step(
+        eng.params, c, t, cfg, active=jnp.ones((2,), bool)))(
+            sched.cache, jnp.ones((2,), jnp.int32))
+    assert _layer_slices(jaxpr.jaxpr, {(1,) + a.shape[1:]
+                                       for a in arenas}) == want
+    rng = np.random.default_rng(0)
+    for plen, gen in ((3, 2), (5, 9), (4, 3)):
+        sched.submit(rng.integers(1, cfg.vocab, plen).tolist(), gen)
+    sched.run(max_rounds=20)
+    rounds = [s for s in spans.snapshot() if s.name == "sched.round"
+              and s.ids.get("sched") == sched.sched_id]
+    assert any(r.counters["decode_steps"] for r in rounds)
+    for r in rounds:
+        assert r.counters["kv_layer_slices"] == \
+            r.counters["decode_steps"] * cfg.n_layers * want

@@ -31,6 +31,7 @@ from repro import configs
 from repro.compress.kvcache import cache_report
 from repro.launch.mesh import make_host_mesh
 from repro.models import get_family
+from repro.runtime import spans
 from repro.runtime.engine import Engine
 from repro.runtime.scheduler import Scheduler
 
@@ -70,6 +71,10 @@ print(json.dumps({
     "per_device_sharded": rep2["per_device_bytes"],
     "wall_p50_ms": s2.stats["step_wall_p50_ms"],
     "wall_p99_ms": s2.stats["step_wall_p99_ms"],
+    "layer_slices": sum(sp.counters.get("kv_layer_slices", 0)
+                        for sp in spans.snapshot()
+                        if sp.name == "sched.round"
+                        and sp.ids.get("sched") == s2.sched_id),
 }))
 """
 
@@ -167,3 +172,17 @@ def test_sharded_preemption_and_fused_kernel_match():
     assert r["tokens_match"] and r["schedule_match"], r
     assert r["fused_tokens_match"] and r["fused_schedule_match"], r
     assert r["n_leaked"] == 0, r
+
+
+def test_sharded_serving_reads_the_stacked_arena_flat():
+    """The same at KV heads of 128, where the decode gather reads the
+    head-sharded stacked arena flattened to ``(L*n_blocks, ...)``
+    (``layers.paged_reads_flat``) and slices no layer: still token and
+    step identical to one device, with the arena head-sharded."""
+    r = _run(_SCRIPT_IDENTITY.replace(
+        "n_kv_heads=4)", "n_kv_heads=4, head_dim=128)"))
+    assert r["tokens_match"] and r["schedule_match"], r
+    assert r["prefix_hits_sharded"] == r["prefix_hits_single"] > 0, r
+    assert r["n_leaked"] == 0 and r["layer_slices"] == 0, r
+    assert "model" in r["arena_spec"], r
+    assert r["per_device_sharded"] < r["bytes"] / 2, r

@@ -127,8 +127,8 @@ def test_kernel_body_does_not_depend_on_the_call_path(one_chip, monkeypatch,
 def _loop_ops(hlo: str):
     """(opcode, dtype, dims, first operand's dims) of every instruction
     that a while loop's body runs, in it or in a computation it calls
-    (fusions, nested loops), from optimized HLO text; size-1 dims
-    dropped."""
+    (fusions, nested loops, conditional branches), from optimized HLO
+    text; size-1 dims dropped."""
     comps, cur = {}, None
     for line in hlo.splitlines():
         head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
@@ -140,7 +140,8 @@ def _loop_ops(hlo: str):
             cur.append(line)
     inst = re.compile(
         r"%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(%?([\w.\-]*)")
-    calls = re.compile(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)")
+    calls = re.compile(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)"
+                       r"|(?<=[{ ])%([\w.\-]+)(?=[,}])")
     todo = [m.group(1) for lines in comps.values() for line in lines
             for m in re.finditer(r"body=%([\w.\-]+)", line)]
     seen = set()
@@ -151,7 +152,7 @@ def _loop_ops(hlo: str):
         seen.add(name)
         dims = {}
         for line in comps[name]:
-            todo += calls.findall(line)
+            todo += [a or b for a, b in calls.findall(line)]
             op = inst.search(line)
             if op:
                 dims[op.group(1)] = tuple(
@@ -231,3 +232,68 @@ def test_paged_decode_scan_keeps_the_arena_in_place(one_chip, monkeypatch,
     assert ref_bad, "the per-layer formulation's arena moves went unseen"
     one_arena = max(leaf.size * leaf.dtype.itemsize for leaf in leaves)
     assert ref_temp - temp >= one_arena, (ref_temp, temp, one_arena)
+
+
+@pytest.mark.parametrize("lane", ["dense", "mla"])
+def test_paged_decode_reads_lane_dense_arenas_without_a_layer_slice(
+        one_chip, monkeypatch, lane):
+    """The decode step in a 4-step scan at published widths, two layers:
+    phi3-medium-14b's 10 KV heads of 128 at the reason cell's arena
+    (6 rows x 4096, block 16) and MiniCPM3's 256-wide latent at the chat
+    cell's (16 x 2048), posit16.  No loop slices one layer out of a
+    lane-dense arena: its gather reads the carried stacked arena, flat
+    (``(2*n_blocks, ...)``).  The 32-wide RoPE arena keeps its slice,
+    which shows the check sees one; on the dense lane, the rule forced
+    to slice every arena shows it."""
+    from repro.models import layers as L
+    arch = "minicpm3-4b" if lane == "mla" else "phi3-medium-14b"
+    cfg = dataclasses.replace(configs.get_config(arch), n_layers=2,
+                              vocab=4096, kv_posit="posit16",
+                              compute_dtype="bfloat16")
+    b, max_len, bs = (16, 2048, 16) if lane == "mla" else (6, 4096, 16)
+    eng = Engine(cfg, None, max_len=max_len, paged=True, block_size=bs)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: get_family(cfg).init_params(
+        jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(lambda: T.init_paged_cache(
+        cfg, b, max_len, bs, b * eng.table_width)))
+    tok = on_chip(jax.ShapeDtypeStruct((b,), jnp.int32))
+    active = on_chip(jax.ShapeDtypeStruct((b,), jnp.bool_))
+    layer = {k: pool[k].shape[1:] for k in (("c_kv", "k_rope") if cfg.mla
+                                            else ("k", "v"))}
+
+    def reads():
+        def decode_scan(params, cache, tok, active):    # traced anew
+            def step(carry, _):
+                cache, tok = carry
+                logits, cache = T.decode_step(params, cache, tok, cfg,
+                                              active=active)
+                return (cache, jnp.argmax(logits, -1).astype(jnp.int32)), \
+                    None
+
+            return jax.lax.scan(step, (cache, tok), length=4)[0]
+
+        hlo = jax.jit(decode_scan).lower(params, pool, tok,
+                                         active).compile().as_text()
+        ops = list(_loop_ops(hlo))
+        sliced = {k for op, dt, dims, _ in ops
+                  if op == "dynamic-slice" and dt == "u16"
+                  for k, shape in layer.items() if dims == shape}
+        flat = {k for op, dt, _, src in ops if op == "gather"
+                for k, shape in layer.items()
+                if src == (2 * shape[0],) + shape[1:]}
+        return sliced, flat
+
+    if lane == "mla":
+        assert layer["c_kv"] == (b * eng.table_width, bs, 256)
+        assert reads() == ({"k_rope"}, {"c_kv"})
+    else:
+        assert layer["k"] == (b * eng.table_width, bs, 10, 128)
+        assert reads() == (set(), {"k", "v"})
+        monkeypatch.setattr(L, "paged_reads_flat",
+                            lambda minor, kernel="gather": False)
+        assert reads() == ({"k", "v"}, set())
