@@ -13,7 +13,7 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-from weights import dims
+import lane
 
 PEAKS = Path(__file__).with_name("peaks.json")
 
@@ -31,20 +31,8 @@ def peaks(device_kind: str) -> dict:
 def per_position(c: dict) -> dict:
     """FLOPs of one position: ``dense`` (all layers' projections),
     ``attn_per_ctx`` (attention per key attended, all layers) and
-    ``head`` (the LM head)."""
-    m = dims(c)
-    d, f, h, n = m["d"], m["f"], m["h"], m["n_layers"]
-    if m["mla"]:
-        qk, vd = m["nope"] + m["rope"], m["vd"]
-        proj = (d * m["q_rank"] + m["q_rank"] * h * qk
-                + d * (m["kv_rank"] + m["rope"])
-                + m["kv_rank"] * h * (m["nope"] + vd) + h * vd * d)
-    else:
-        qk = vd = m["hd"]
-        proj = d * h * qk + 2 * d * m["g"] * qk + h * vd * d
-    return dict(dense=2 * n * (proj + 3 * d * f),
-                attn_per_ctx=2 * n * h * (qk + vd),
-                head=2 * d * m["vocab"])
+    ``head`` (the LM head), as the config's lane counts them."""
+    return lane.load(c).per_position(c)
 
 
 def span(c: dict, lo: int, hi: int, sampled: int) -> float:

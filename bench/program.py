@@ -4,11 +4,15 @@ It uses only this surface of the program (the contract later changes
 keep, or change together with this file in a benchmark change):
 
 * ``repro.configs.get_config(arch)`` and ``dataclasses.replace`` on the
-  result, with the fields mapped from the config file below;
+  result, with the fields mapped from the config file: the common ones
+  below (``FIELDS``) and its lane's (``lanes/<lane>.py`` ``fields``),
+  then the lane's guard (``check``), which refuses a program model that
+  departs from what the lane's reference implements;
 * ``Engine(cfg, params, max_len=, paged=True, block_size=, seed=)``,
   plus ``decode_kernel=`` only where a cell file names one;
-* ``Scheduler(engine, n_slots=, chunk_size=, chunked_prefill=True)``
-  and its ``submit``, ``step``, ``has_work`` and ``stats``;
+* ``Scheduler(engine, n_slots=, chunk_size=)`` (its one mode, the
+  chunked paged round) and its ``submit``, ``step``, ``has_work`` and
+  ``stats``;
 * ``repro.launch.compile_cache.enable()``;
 * the parameter tree layout that ``weights.py`` makes.
 
@@ -30,15 +34,13 @@ from repro.launch import compile_cache  # noqa: E402
 from repro.runtime.engine import Engine  # noqa: E402
 from repro.runtime.scheduler import Scheduler  # noqa: E402
 
-# config-file key -> program ModelConfig field
+# config-file key -> program ModelConfig field, for every lane
 FIELDS = {
     "hidden_size": "d_model", "intermediate_size": "d_ff",
     "num_hidden_layers": "n_layers", "num_attention_heads": "n_heads",
     "num_key_value_heads": "n_kv_heads", "vocab_size": "vocab",
     "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta",
-    "q_lora_rank": "q_lora_rank", "kv_lora_rank": "kv_lora_rank",
-    "qk_nope_head_dim": "qk_nope_dim", "qk_rope_head_dim": "qk_rope_dim",
-    "v_head_dim": "v_head_dim", "tie_word_embeddings": "tie_embeddings",
+    "tie_word_embeddings": "tie_embeddings",
     "torch_dtype": "compute_dtype", "kv_cache_dtype": "kv_posit",
 }
 
@@ -48,19 +50,14 @@ def enable_compile_cache() -> str:
 
 
 def program_config(c: dict):
+    # imported here, not above: the program's own tests load this file
+    # by path for ``Server.progress``, without ``bench/`` on the path
+    import lane
+    ln = lane.load(c)
     fields = {FIELDS[k]: v for k, v in c.items() if k in FIELDS}
-    if "kv_lora_rank" in c:
-        fields["head_dim"] = c["qk_nope_head_dim"] + c["qk_rope_head_dim"]
-    else:
-        fields["head_dim"] = c.get("head_dim", c["hidden_size"]
-                                   // c["num_attention_heads"])
+    fields.update(ln.fields(c))
     cfg = dataclasses.replace(configs.get_config(c["program_arch"]), **fields)
-    if cfg.mla != ("kv_lora_rank" in c) or cfg.sliding_window \
-            or cfg.is_moe or cfg.act != c["hidden_act"] \
-            or cfg.scale_embed or cfg.norm_plus_one:
-        raise ValueError(f"{c['name']}: the program's {c['program_arch']} "
-                         "differs from the config file beyond the mapped "
-                         "fields")
+    ln.check(cfg, c)
     return cfg
 
 
@@ -75,8 +72,7 @@ class Server:
             kw["decode_kernel"] = cell["decode_kernel"]
         self.engine = Engine(self.cfg, params, **kw)
         self.sched = Scheduler(self.engine, n_slots=cell["n_slots"],
-                               chunk_size=cell["chunk_size"],
-                               chunked_prefill=True)
+                               chunk_size=cell["chunk_size"])
 
     def submit(self, prompt, max_new_tokens: int) -> int:
         return self.sched.submit(list(prompt), max_new_tokens)

@@ -3,9 +3,11 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``: a config file
-(``configs/``), a traffic mix (``traffic/``) and the cell's own serving
-sizes (``cells/<cell>.json``); each metric is a reader in ``metrics/``.
-All are found by name, so a cell that reuses a mix is data alone.
+(``configs/``, which names its model lane in ``lanes/``), a traffic mix
+(``traffic/``) and the cell's own serving sizes (``cells/<cell>.json``);
+each metric is a reader in ``metrics/``.  All are found by name, so a
+cell that reuses a mix is data alone, and a new architecture is a lane
+file and a config file.
 
 A run makes the weights on the device from the seed, builds the
 program's engine and scheduler, warms every program the window calls by

@@ -4,6 +4,7 @@
 """
 from __future__ import annotations
 
+import json
 import sys
 from pathlib import Path
 
@@ -11,24 +12,10 @@ BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(BENCH.parent / "src"))
 
-TINY = {
-    "mla": {
-        "name": "tiny-mla", "program_arch": "minicpm3-4b",
-        "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
-        "num_attention_heads": 4, "num_key_value_heads": 4,
-        "q_lora_rank": 32, "kv_lora_rank": 16, "qk_nope_head_dim": 16,
-        "qk_rope_head_dim": 8, "v_head_dim": 16, "vocab_size": 256,
-        "hidden_act": "silu", "rms_norm_eps": 1e-5, "rope_theta": 10000.0,
-        "tie_word_embeddings": False, "torch_dtype": "bfloat16",
-        "kv_cache_dtype": "posit16"},
-    "gqa": {
-        "name": "tiny-gqa", "program_arch": "phi3-medium-14b",
-        "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
-        "num_attention_heads": 4, "num_key_value_heads": 2,
-        "vocab_size": 256, "hidden_act": "silu", "rms_norm_eps": 1e-5,
-        "rope_theta": 10000.0, "tie_word_embeddings": False,
-        "torch_dtype": "bfloat16", "kv_cache_dtype": "posit16"},
-}
+# lane -> its toy-width config (``tiny/<lane>.json``): a new lane brings
+# its own file
+TINY = {p.stem: json.loads(p.read_text())
+        for p in sorted((BENCH / "tests" / "tiny").glob("*.json"))}
 
 TINY_CHAT = {"arrival": "open_loop", "size_seed": 5,
              "prompt_tokens": {"median": 20, "sigma": 0.8, "min": 4,

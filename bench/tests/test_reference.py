@@ -1,6 +1,6 @@
 """The plain reference against the program's one-shot prefill, at toy
-widths on the CPU, both attention lanes, in float32 with no KV codec (so
-the two must agree to float32 rounding)."""
+widths on the CPU, every lane found (``lane.names()``), in float32 with
+no KV codec (so the two must agree to float32 rounding)."""
 import dataclasses
 
 import jax
@@ -12,6 +12,7 @@ import program
 import reference
 import weights
 from conftest import TINY
+from lane import names
 
 
 def _f32(lane):
@@ -20,7 +21,7 @@ def _f32(lane):
     return c, cfg
 
 
-@pytest.mark.parametrize("lane", ["mla", "gqa"])
+@pytest.mark.parametrize("lane", names())
 def test_layout_is_the_programs_tree(lane):
     from repro.models import get_family
     c, cfg = _f32(lane)
@@ -32,7 +33,7 @@ def test_layout_is_the_programs_tree(lane):
         [x.shape for x in jax.tree.leaves(theirs)]
 
 
-@pytest.mark.parametrize("lane", ["mla", "gqa"])
+@pytest.mark.parametrize("lane", names())
 @pytest.mark.parametrize("n", [37, 300])
 def test_reference_matches_one_shot_prefill(lane, n):
     from repro.models import transformer as T
